@@ -198,5 +198,7 @@ def _quant_publish_bytes(steps=8):
 
 
 if __name__ == "__main__":
+    from dlrm_flexflow_tpu import use_compile_cache
+    use_compile_cache()
     publishes = int(os.environ.get("BENCH_FRESHNESS_PUBLISHES", "12"))
     print(json.dumps(measure(publishes=publishes)))

@@ -25,7 +25,7 @@ best-of-N, the same discipline bench.py's headline windows use.
 
 Prints ONE JSON line; ``measure()`` is imported by bench.py when
 BENCH_OBS=1 so obs-overhead regressions show up next to the headline
-throughput. Results recorded in BENCHMARKS.md round 15.
+throughput.
 
 Usage: python benchmarks/bench_obs.py [--steps N]
 """
@@ -214,4 +214,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from dlrm_flexflow_tpu import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

@@ -181,4 +181,6 @@ def main(argv):
 
 
 if __name__ == "__main__":
+    from dlrm_flexflow_tpu import use_compile_cache
+    use_compile_cache()
     sys.exit(main(sys.argv[1:]))

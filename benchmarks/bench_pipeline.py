@@ -155,4 +155,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from dlrm_flexflow_tpu import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

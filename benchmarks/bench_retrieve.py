@@ -207,4 +207,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from dlrm_flexflow_tpu import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

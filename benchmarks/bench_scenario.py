@@ -55,5 +55,7 @@ def measure(steps: int = 48, replicas: int = 2,
 
 
 if __name__ == "__main__":
+    from dlrm_flexflow_tpu import use_compile_cache
+    use_compile_cache()
     import json
     print(json.dumps(measure(), indent=2))

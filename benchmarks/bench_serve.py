@@ -170,6 +170,8 @@ def measure(requests=256, threads=16):
 
 
 if __name__ == "__main__":
+    from dlrm_flexflow_tpu import use_compile_cache
+    use_compile_cache()
     n = 256
     if "--requests" in sys.argv:
         n = int(sys.argv[sys.argv.index("--requests") + 1])

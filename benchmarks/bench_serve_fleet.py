@@ -428,16 +428,11 @@ def _spawn_shard_procs(cache_dir, nshards):
     """One ``shard_server`` OS process per slot; returns
     ``(procs, addresses)`` after every SHARD_SERVER_OK sentinel."""
     import subprocess
-    env = dict(os.environ)
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    procs = [subprocess.Popen(
-        [sys.executable, "-m", "dlrm_flexflow_tpu.serve.shard_server",
-         "--cache-dir", cache_dir, "--nshards", str(nshards),
-         "--slot", str(slot), "--port", "0"],
-        env=env, text=True, stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT) for slot in range(nshards)]
+
+    from dlrm_flexflow_tpu.serve import shard_server
+    procs = [shard_server.spawn(cache_dir, nshards, slot,
+                                stderr=subprocess.STDOUT)
+             for slot in range(nshards)]
     addresses = []
     try:
         for p in procs:
@@ -758,6 +753,8 @@ def measure(requests=256, slo_ms=50.0, replica_counts=(1, 2, 4)):
 
 
 if __name__ == "__main__":
+    from dlrm_flexflow_tpu import use_compile_cache
+    use_compile_cache()
     n = 256
     slo = 50.0
     if "--requests" in sys.argv:

@@ -1,9 +1,9 @@
 #!/usr/bin/env python
 """Fused-superstep benchmark: what does one dispatch per K steps buy?
 
-Round 5 of BENCHMARKS.md pinned a ~0.55 ms per-step dispatch floor that
-dominates small-batch DLRM (`dlrm_random b256` is floor-bound at
-1.65 ms/step — roughly half of every step is dispatch, not math). Fused
+Small-batch DLRM steps are dominated by per-dispatch overhead, not math
+(ROADMAP S1; search/cost_model.py pins a round-5 ~0.55 ms per step).
+Fused
 supersteps (`FFConfig.superstep`, core/model.py `_train_superstep`)
 compile K training steps into ONE executable, so one host→device
 dispatch pays the floor once per K steps.
@@ -171,4 +171,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from dlrm_flexflow_tpu import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

@@ -44,5 +44,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from dlrm_flexflow_tpu import use_compile_cache
+    use_compile_cache()
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     main()

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""MEASURED-MODE SOAP search for the DLRM configs (VERDICT r4 #3).
+"""MEASURED-MODE SOAP search for the DLRM configs.
 
 The reference's whole point is measured-search-found strategies: the
 simulator times real kernels on the device and MCMC searches against
@@ -150,4 +150,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from dlrm_flexflow_tpu import use_compile_cache
+    use_compile_cache()
     main()

@@ -14,6 +14,7 @@ from .config import FFConfig
 from .core.model import AnomalyError, FFModel
 from .utils.checkpoint import (CheckpointManager, restore_checkpoint,
                                save_checkpoint)
+from .utils.compile_cache import use_compile_cache
 from .utils.delta import DeltaPublisher
 from .core.optimizers import AdamOptimizer, Optimizer, SGDOptimizer
 from .core.initializers import (ConstantInitializer, GlorotUniform,
@@ -36,6 +37,7 @@ __version__ = "0.1.0"
 __all__ = [
     "FFConfig", "FFModel", "Tensor", "AnomalyError",
     "CheckpointManager", "save_checkpoint", "restore_checkpoint",
+    "use_compile_cache",
     "DeltaPublisher",
     "Optimizer", "SGDOptimizer", "AdamOptimizer",
     "GlorotUniform", "ZeroInitializer", "UniformInitializer",

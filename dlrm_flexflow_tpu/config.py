@@ -88,8 +88,8 @@ class FFConfig:
     # fused supersteps: compile K training steps into ONE executable (a
     # lax.scan over K pre-staged batches, core/model.py _train_superstep)
     # so a single host→device dispatch trains K steps — amortizing the
-    # ~0.55 ms per-step dispatch floor that dominates small-batch DLRM
-    # (BENCHMARKS.md r5 "floor-bound"). 1 = the exact legacy per-step
+    # per-step dispatch overhead that dominates small-batch DLRM
+    # (ROADMAP S1). 1 = the exact legacy per-step
     # dispatch; "auto" picks K from the megabatch bytes against a
     # staging budget (search/cost_model.py HBM capacity on TPU, a host
     # RAM cap elsewhere). Checkpoints/save_every snap to superstep
@@ -127,8 +127,8 @@ class FFConfig:
     # accuracy tolerance). Set with --emb-update-rule.
     emb_update_rule: str = "master_weight"
     # VMEM-resident pallas LSTM scan kernel (weights pinned in VMEM
-    # across the time loop — the lax.scan cell is weight-stream-bound,
-    # BENCHMARKS.md r4). Disable with --no-pallas-lstm.
+    # across the time loop — round 4 found the lax.scan cell
+    # weight-stream-bound). Disable with --no-pallas-lstm.
     pallas_lstm: bool = True
     # space-to-depth lowering for strided low-channel convs (the MLPerf
     # ResNet-stem reformulation; a 3-channel stem fills 3/128 MXU lanes).

@@ -50,29 +50,26 @@ from ..utils.logging import log_model
 from ..utils import faults
 
 
+# init_layers: an op with at least this many parameter bytes is
+# initialized by the sharded SPMD program (a compile per model, no device
+# ever holds the whole parameter); smaller ones eagerly on one device and
+# then placed (no compile; the whole parameter passes through one device,
+# harmless at this size)
+_SHARDED_INIT_BYTES = 64 << 20
+
+
 def _sharding_mismatch(e: Exception) -> bool:
     """True when a cached AOT executable rejected its inputs because
     GSPMD propagated different shardings than it was compiled with (the
-    recompile-once fallback). The message wording changed across jax
-    releases ("...that disagree..." -> "...does not match...")."""
-    msg = str(e)
-    return "disagree" in msg or ("sharding" in msg
-                                 and "does not match" in msg)
+    recompile-once fallback)."""
+    return "disagree" in str(e)
 
 
 def _to_memory(v, space: str):
     """Stage a traced value into host or device memory for the hetero
-    host-offload path. `jax.memory.Space` moved across jax releases; on
-    versions without it the transfer annotation is
-    `TransferToMemoryKind` with the corresponding memory-kind string."""
-    mem = getattr(jax, "memory", None)
-    if mem is not None and hasattr(mem, "Space"):
-        tgt = mem.Space.Host if space == "host" else mem.Space.Device
-    else:
-        from jax._src.sharding_impls import TransferToMemoryKind
-        tgt = TransferToMemoryKind(
-            "unpinned_host" if space == "host" else "device")
-    return jax.device_put(v, tgt)
+    host-offload path."""
+    return jax.device_put(v, jax.memory.Space.Host if space == "host"
+                          else jax.memory.Space.Device)
 
 
 class AnomalyError(RuntimeError):
@@ -1169,8 +1166,8 @@ class FFModel:
             """K fused steps in ONE executable: lax.scan over the
             stacked [K, ...] megabatch with the train-step body,
             donating the carries. One host→device dispatch then trains
-            K steps — deleting K-1 of every K ~0.55 ms dispatch floors
-            (BENCHMARKS.md r5 "floor-bound"). The per-step RNG fold,
+            K steps — deleting K-1 of every K per-dispatch overheads
+            (ROADMAP S1). The per-step RNG fold,
             on-device sentinel suppression, and metric-sum accumulation
             all run unchanged inside the scan, so K>1 is bit-identical
             to K sequential dispatches of the same batches."""
@@ -1294,7 +1291,8 @@ class FFModel:
         self._pick_conv_s2d()
         seed = self.config.seed if seed is None else seed
         key = jax.random.PRNGKey(seed)
-        params: Dict[str, Dict[str, jnp.ndarray]] = {}
+        params: Dict[str, Any] = {}
+        big_keys: Dict[str, Any] = {}    # ops initialized sharded, below
         op_state: Dict[str, Any] = {}
         hres = getattr(self, "_host_resident_ops", set())
         self.host_params: Dict[str, Dict[str, np.ndarray]] = {}
@@ -1320,13 +1318,16 @@ class FFModel:
                     continue
                 if op.param_defs():
                     key, sub = jax.random.split(key)
-                    p = op.init_params(sub)
-                    p = self._quant_init_device(op, p)
-                    shards = self._param_sharding.get(op.name, {})
-                    rep = NamedSharding(self.mesh, PartitionSpec())
-                    params[op.name] = {
-                        n: put_global(v, shards.get(n) or rep)
-                        for n, v in p.items()}
+                    if op.param_bytes() >= _SHARDED_INIT_BYTES:
+                        big_keys[op.name] = sub
+                        params[op.name] = None     # keeps the op order
+                    else:
+                        p = self._quant_init_device(op, op.init_params(sub))
+                        shards = self._param_sharding.get(op.name, {})
+                        rep = NamedSharding(self.mesh, PartitionSpec())
+                        params[op.name] = {
+                            n: put_global(v, shards.get(n) or rep)
+                            for n, v in p.items()}
                 if hasattr(op, "state_defs"):
                     key, sub = jax.random.split(key)
                     defs = op.state_defs()
@@ -1336,6 +1337,7 @@ class FFModel:
                         n: put_global(d.initializer(k, d.shape, d.dtype),
                                       rep)
                         for (n, d), k in zip(sorted(defs.items()), keys)}
+            params.update(self._init_params_sharded(big_keys))
         self.params = params
         self.op_state = op_state
         # multi-controller: build optimizer state as one SPMD program so
@@ -1348,6 +1350,37 @@ class FFModel:
         self._step_dev = None
         self._msums = None
         return self
+
+    def _init_params_sharded(self, op_keys):
+        """Parameters of the LARGE ops (>= _SHARDED_INIT_BYTES), each born
+        under its compiled sharding: ONE SPMD program runs their
+        initializers and its outputs carry the shardings, so every device
+        draws only its own shard (the random bits do not depend on the
+        partitioning) and no device ever holds a whole table — the eager
+        init on one device followed by a device_put, which small ops
+        keep, peaked at 3x the 2 GB dlrm_random tables on chip 0. Same
+        values either way (tests/test_distribution.py pins it)."""
+        if not op_keys:
+            return {}
+        ops = {op.name: op for op in self.ops}
+        order = {}     # each op's own param order, noted while tracing
+
+        def init(keys):
+            out = {}
+            for name, k in keys.items():
+                out[name] = self._quant_init_device(
+                    ops[name], ops[name].init_params(k))
+                order[name] = list(out[name])
+            return out
+
+        rep = NamedSharding(self.mesh, PartitionSpec())
+        out_sh = {name: {n: self._param_sharding.get(name, {}).get(n) or rep
+                         for n in p}
+                  for name, p in jax.eval_shape(init, op_keys).items()}
+        out = jax.jit(init, out_shardings=out_sh)(op_keys)
+        # a jit returns its dicts key-sorted; give back the ops' own order
+        return {name: {n: out[name][n] for n in order[name]}
+                for name in op_keys}
 
     def _pick_conv_s2d(self):
         """Choose the conv stem lowering per FFConfig.conv_s2d: "on"
@@ -1541,7 +1574,7 @@ class FFModel:
             return lower().compile()
         ckey = cache.exec_key(kind, self, shape_key)
         if not fresh:
-            exec_ = cache.get(ckey)
+            exec_ = cache.get(ckey, self.mesh.devices.flat)
             if exec_ is not None:
                 return exec_
         exec_ = lower().compile()
@@ -3181,8 +3214,8 @@ class FFModel:
                 epoch += 1
                 b0 = 0
             if mets is not None:
-                # dependent readback = true completion (block_until_ready
-                # does not wait on some experimental PJRT backends)
+                # the loss readback waits for the last step, and so for
+                # the whole timed loop
                 float(mets["loss"])
         self._host_drain()   # land the last async host scatter, if any
         if mgr is not None:

@@ -4,49 +4,58 @@ The reference keeps its simulator engine and data loaders in native code
 (reference: src/runtime/simulator.cc, python/flexflow_dataloader.cc); this
 package does the same for the TPU build. Sources live next to this file
 (ffsim.cc, ffloader.cc) and are compiled into one shared library
-`_ffnative.so` at first import; consumers (search/simulator.py,
+`_ffnative-<hash>.so` at first use; consumers (search/simulator.py,
 data/dataloader.py) fall back to pure-Python paths when the toolchain is
 unavailable, so the framework never hard-requires a compiler.
 
-Rebuilds are automatic when a source file is newer than the library.
+The library's name carries a hash of its sources, so a library is only
+ever loaded for the sources it was built from — a copied checkout
+(whose mtimes mean nothing) rebuilds exactly when the sources differ.
 """
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = ["ffsim.cc", "ffloader.cc", "ffemb.cc"]
-_LIB_PATH = os.path.join(_DIR, "_ffnative.so")
 
 _lock = threading.Lock()
 _lib = None
 _load_failed = False
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    return any(
-        os.path.getmtime(os.path.join(_DIR, s)) > lib_mtime for s in _SOURCES)
+def _lib_path() -> str:
+    h = hashlib.sha256()
+    for s in _SOURCES:
+        with open(os.path.join(_DIR, s), "rb") as f:
+            h.update(f.read())
+    return os.path.join(_DIR, f"_ffnative-{h.hexdigest()[:16]}.so")
 
 
-def _build() -> None:
+def _build(lib_path: str) -> None:
     # compile to a per-pid temp file then rename: rename is atomic, so a
     # concurrent process never dlopens a half-written .so
-    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
            "-o", tmp] + [os.path.join(_DIR, s) for s in _SOURCES]
     try:
         subprocess.run(cmd, check=True, capture_output=True, text=True)
-        os.replace(tmp, _LIB_PATH)
+        os.replace(tmp, lib_path)
     finally:
         if os.path.exists(tmp):
             os.remove(tmp)
+    # libraries of other source versions are never loaded again
+    for stale in glob.glob(os.path.join(_DIR, "_ffnative*.so")):
+        if stale != lib_path:
+            with contextlib.suppress(FileNotFoundError):  # lost a race
+                os.remove(stale)
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -87,9 +96,10 @@ def get_lib():
         if _lib is not None or _load_failed:
             return _lib
         try:
-            if _needs_build():
-                _build()
-            _lib = _bind(ctypes.CDLL(_LIB_PATH))
+            lib_path = _lib_path()
+            if not os.path.exists(lib_path):
+                _build(lib_path)
+            _lib = _bind(ctypes.CDLL(lib_path))
         except (OSError, subprocess.CalledProcessError, AttributeError):
             _load_failed = True
     return _lib

@@ -96,10 +96,7 @@ def _flash_gate(model, op_name, q, k) -> bool:
 def ring_attention(q, k, v, axis_name: str, causal: bool):
     """Blockwise ring attention under shard_map: q/k/v are LOCAL blocks
     (b, h, s_local, hd); K/V rotate around `axis_name` via ppermute."""
-    # lax.axis_size is absent on older jax; psum(1) folds to the same
-    # static axis size at trace time
-    p = (lax.axis_size(axis_name) if hasattr(lax, "axis_size")
-         else int(lax.psum(1, axis_name)))
+    p = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, h, sl, hd = q.shape
 
@@ -193,8 +190,8 @@ class MultiHeadAttention(Op):
             fn = partial(ring_attention,
                          axis_name=seq_axes if len(seq_axes) > 1 else seq_axes[0],
                          causal=self.causal)
-            from ..parallel.alltoall import _smap
-            attn = _smap(fn, mesh, in_specs=(spec, spec, spec),
+            from ..parallel.mesh import smap
+            attn = smap(fn, mesh, in_specs=(spec, spec, spec),
                          out_specs=spec)(q, k, v)
         elif _flash_gate(self.model, self.name, q, k):
             from jax.experimental.pallas.ops.tpu.flash_attention import (

@@ -1659,10 +1659,10 @@ class EmbeddingBagStacked(Op):
 
     def apply_with_fwd(self, params, xs, *, rng=None):
         """apply() plus forward-gather residuals (global unpacked rows +
-        packed tiles): random HBM rows are latency-bound (~0.3 µs each,
-        BENCHMARKS.md), so keeping the 1 MB of gathered tiles lets the
-        sparse update WRITE new rows without re-reading them — halving
-        the update's random accesses vs the RMW kernel. Returns
+        packed tiles): random HBM rows are latency-bound, so keeping the
+        1 MB of gathered tiles lets the sparse update WRITE new rows
+        without re-reading them — halving the update's random accesses
+        vs the RMW kernel. Returns
         (outs, fwd|None); None = caller should treat as plain apply."""
         if not self._fwd_residual_ok():
             return self.apply(params, xs, training=True, rng=rng), None

@@ -174,112 +174,6 @@ def _bwd(aggr, interpret, res, g):
 embedding_bag.defvjp(_fwd, _bwd)
 
 
-# ---- quantized-storage gather (quant/: int8/fp8 rows, row-wise scales) ----
-# The table lives in HBM at the STORAGE dtype (1 B/elem) and is
-# dequantized INSIDE the kernel: each row chunk streams into VMEM as a
-# quantized (1, 128) tile and is scaled during accumulation, so HBM
-# moves 1/4 the bytes of the fp32 gather. The fp32 row scales ride
-# beside the row tiles via scalar prefetch (SMEM — one scalar read per
-# accumulated row; VMEM-blocking the scales would need a second DMA
-# pipeline for 4 B payloads). Policy-driven: ops route here when their
-# QuantPolicy stores int8/fp8 (quant.effective_policy), exactly like the
-# fp32 kernel routes via _pallas_ok.
-
-
-def _bag_kernel_quant(bag: int, k: int, idx_ref, scale_ref, table_ref,
-                      out_ref, row_buf, sems):
-    """Quantized twin of _bag_kernel: same deep DMA pipeline over the
-    (rows*k, 128) chunk view, but row_buf holds STORAGE-dtype tiles and
-    the accumulate applies the row's scale (dequant-in-VMEM)."""
-    tb = out_ref.shape[0]
-    total = tb * bag * k
-    base = pl.program_id(0) * tb * bag
-
-    def dma(j, slot):
-        s_c, b = j // bag, j % bag
-        s, c = s_c // k, s_c % k
-        view_row = idx_ref[base + s * bag + b] * k + c
-        return pltpu.make_async_copy(
-            table_ref.at[pl.ds(view_row, 1), :], row_buf.at[slot],
-            sems.at[slot])
-
-    depth = min(_SLOTS - 1, total)
-    for j in range(depth):
-        dma(j, j % _SLOTS).start()
-    for s in range(tb):                # static unroll: all bounds small
-        for c in range(k):
-            acc = jnp.zeros((1, _LANES), jnp.float32)
-            for b in range(bag):
-                j = (s * k + c) * bag + b
-                if j + depth < total:
-                    dma(j + depth, (j + depth) % _SLOTS).start()
-                dma(j, j % _SLOTS).wait()
-                scale = scale_ref[idx_ref[base + s * bag + b]]
-                acc = acc + row_buf[j % _SLOTS].astype(jnp.float32) * scale
-            out_ref[pl.ds(s, 1), c * _LANES:(c + 1) * _LANES] = \
-                acc.astype(out_ref.dtype)
-
-
-def embedding_bag_quant(q_table: jax.Array, scales: jax.Array,
-                        indices: jax.Array, aggr: str = "sum",
-                        interpret: bool = False) -> jax.Array:
-    """Embedding bag over a QUANTIZED table with in-kernel dequant.
-
-    q_table : (rows, dim) int8 / float8_e4m3fn, dim % 128 == 0
-    scales  : (rows,) fp32 row scales (symmetric codec, quant/codec.py)
-    indices : (batch, bag) int
-    returns : (batch, dim) fp32, sum or mean over the bag —
-              bit-identical to gathering the DEQUANTIZED rows
-              (``embedding_bag_quant_reference``, the test oracle).
-    """
-    batch, bag = indices.shape
-    rows, dim = q_table.shape
-    if not supports(dim):
-        raise ValueError(f"pallas embedding_bag_quant needs dim % "
-                         f"{_LANES} == 0, got {dim}; use "
-                         f"embedding_bag_quant_reference")
-    k = dim // _LANES
-    padded = ((batch + _TILE_B - 1) // _TILE_B) * _TILE_B
-    idx_flat = jnp.zeros((padded * bag,), jnp.int32)
-    idx_flat = idx_flat.at[: batch * bag].set(
-        indices.astype(jnp.int32).reshape(-1))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(padded // _TILE_B,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((_TILE_B, dim), lambda i, idx, scl: (i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((_SLOTS, 1, _LANES), q_table.dtype),
-            pltpu.SemaphoreType.DMA((_SLOTS,)),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_bag_kernel_quant, bag, k),
-        out_shape=jax.ShapeDtypeStruct((padded, dim), jnp.float32),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(idx_flat, scales.astype(jnp.float32),
-      q_table.reshape(rows * k, _LANES))
-    out = out[:batch]
-    if aggr == "avg":
-        out = out / bag
-    return out
-
-
-def embedding_bag_quant_reference(q_table, scales, indices,
-                                  aggr: str = "sum"):
-    """Plain-XLA oracle/fallback: dequantize the gathered rows, then the
-    bag reduce — the contract embedding_bag_quant must match bitwise
-    (fp32 accumulate in both)."""
-    idx = indices.astype(jnp.int32)
-    rows = (jnp.take(q_table, idx, axis=0).astype(jnp.float32)
-            * jnp.take(scales.astype(jnp.float32), idx, axis=0)[..., None])
-    if aggr == "avg":
-        return jnp.mean(rows, axis=-2)
-    return jnp.sum(rows, axis=-2)
-
-
 def scatter_supports(dim: int) -> bool:
     """Row widths the scatter-add kernel handles: a whole number of lane
     tiles, or an exact divisor of one tile."""
@@ -595,21 +489,9 @@ def sharded_scatter_add_packed(mesh, row_axes, view, indices, updates,
     indices : (n,) int32 in UNPACKED row space, replicated
     updates : (n, dim), replicated
     """
-    import inspect
-
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map as _shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _shard_map
-    # the replication-check kwarg was renamed check_rep -> check_vma
-    _params = inspect.signature(_shard_map).parameters
-    _ckw = {"check_vma": False} if "check_vma" in _params else \
-        {"check_rep": False}
 
-    def smap(f, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **_ckw)
+    from ...parallel.mesh import smap
 
     r_per_tile = _LANES // dim
     vrows = view.shape[0]
@@ -620,10 +502,9 @@ def sharded_scatter_add_packed(mesh, row_axes, view, indices, updates,
 
     def local_update(tbl_shard, idx, upd):
         # linear shard index over the row axes
-        import jax as _jax
         sid = jnp.zeros((), jnp.int32)
         for a in row_axes:
-            sid = sid * mesh.shape[a] + _jax.lax.axis_index(a)
+            sid = sid * mesh.shape[a] + jax.lax.axis_index(a)
         lo = sid * block * r_per_tile          # unpacked-row lower bound
         hi = lo + block * r_per_tile
         local = idx - lo
@@ -633,7 +514,7 @@ def sharded_scatter_add_packed(mesh, row_axes, view, indices, updates,
                                        interpret=interpret)
 
     return smap(
-        local_update,
+        local_update, mesh,
         in_specs=(P(tuple(row_axes)), P(), P()),
         out_specs=P(tuple(row_axes)),
     )(view, indices.astype(jnp.int32), updates)

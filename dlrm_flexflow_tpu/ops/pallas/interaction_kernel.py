@@ -26,11 +26,6 @@ Structure per grid step (_TILE_B samples):
   of the dense weight (`scatter_tril_weight`), so the tril select becomes
   part of the matmul instead of a gather.
 
-The quantized twin (`fused_interaction_quant`) mirrors
-embedding_bag_quant: the table lives in HBM at int8/fp8 storage width and
-rows are dequantized during the X-buffer accumulate (row scales via
-scalar prefetch), so the gather moves 1/4 the bytes.
-
 `fused_interaction` carries a custom_vjp whose backward is plain XLA
 (the backward pass re-materializes g_Z — fusing it is out of scope; the
 FLX515 audit targets the forward/serving lowering). On non-TPU backends
@@ -311,138 +306,3 @@ def _fused_bwd(relu, interpret, res, g):
 
 
 fused_interaction.defvjp(_fused_fwd, _fused_bwd)
-
-
-# ---- quantized-storage twin (int8/fp8 table, row-wise scales) ----------
-# Same contract as embedding_bag_quant: the table lives in HBM at the
-# STORAGE dtype, each (1, 128) chunk is dequantized during the X-buffer
-# accumulate (scale via scalar prefetch), and the math from X on is
-# identical to the fp32 kernel. Serving-path only — no vjp, matching
-# embedding_bag_quant.
-
-
-def _interaction_kernel_quant(T: int, bag: int, k: int, F: int, relu: bool,
-                              idx_ref, scale_ref, table_ref, bottom_ref,
-                              wbot_ref, m_ref, bias_ref, out_ref, xbuf,
-                              row_buf, sems):
-    tb = out_ref.shape[0]
-    Fp = xbuf.shape[0]
-    d = xbuf.shape[1]
-    total = tb * T * k * bag
-    base = pl.program_id(0) * tb * T * bag
-
-    def dma(j, slot):
-        stc, b = j // bag, j % bag
-        st, c = stc // k, stc % k
-        view_row = idx_ref[base + st * bag + b] * k + c
-        return pltpu.make_async_copy(
-            table_ref.at[pl.ds(view_row, 1), :], row_buf.at[slot],
-            sems.at[slot])
-
-    depth = min(_SLOTS - 1, total)
-    for j in range(depth):
-        dma(j, j % _SLOTS).start()
-    for s in range(tb):
-        xbuf[pl.ds(0, 1), :] = bottom_ref[pl.ds(s, 1), :]
-        for t in range(T):
-            for c in range(k):
-                acc = jnp.zeros((1, _LANES), jnp.float32)
-                for b in range(bag):
-                    j = ((s * T + t) * k + c) * bag + b
-                    if j + depth < total:
-                        dma(j + depth, (j + depth) % _SLOTS).start()
-                    dma(j, j % _SLOTS).wait()
-                    scale = scale_ref[idx_ref[base + (s * T + t) * bag + b]]
-                    acc = acc + row_buf[j % _SLOTS].astype(jnp.float32) \
-                        * scale
-                xbuf[pl.ds(1 + t, 1), c * _LANES:(c + 1) * _LANES] = acc
-        if Fp > F:
-            xbuf[pl.ds(F, Fp - F), :] = jnp.zeros((Fp - F, d), jnp.float32)
-        x = xbuf[:]
-        z = lax.dot_general(x, x, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        y = jnp.dot(bottom_ref[pl.ds(s, 1), :], wbot_ref[:],
-                    preferred_element_type=jnp.float32)
-        for f in range(1, F):
-            y = y + jnp.dot(z[f:f + 1, :],
-                            m_ref[f * Fp:(f + 1) * Fp, :],
-                            preferred_element_type=jnp.float32)
-        y = y + bias_ref[:]
-        if relu:
-            y = jnp.maximum(y, 0.0)
-        out_ref[pl.ds(s, 1), :] = y
-
-
-def fused_interaction_quant(q_table, scales, indices, bottom, w, bias,
-                            relu: bool = True, interpret: bool = False):
-    """fused_interaction over a QUANTIZED table with in-kernel dequant.
-
-    q_table : (rows, d) int8 / float8_e4m3fn, d % 128 == 0
-    scales  : (rows,) fp32 row scales (symmetric codec, quant/codec.py)
-    Everything else as fused_interaction; matches
-    ``fused_interaction_quant_reference`` (dequantize-then-interact).
-    """
-    batch = bottom.shape[0]
-    rows, d = q_table.shape
-    if not supports(d):
-        raise ValueError(f"pallas fused_interaction_quant needs dim % "
-                         f"{_LANES} == 0, got {d}; use "
-                         f"fused_interaction_quant_reference")
-    F = indices.shape[1] + 1
-    Fp = _pad_features(F)
-    k = d // _LANES
-    H = w.shape[1]
-    idx_flat, bot, w_bot, m, padded, T, bag = _prep_inputs(
-        indices, bottom, w, d, F)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(padded // _TILE_B,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((_TILE_B, d), lambda i, idx, scl: (i, 0)),
-            pl.BlockSpec((d, H), lambda i, idx, scl: (0, 0)),
-            pl.BlockSpec((Fp * Fp, H), lambda i, idx, scl: (0, 0)),
-            pl.BlockSpec((1, H), lambda i, idx, scl: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((_TILE_B, H), lambda i, idx, scl: (i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Fp, d), jnp.float32),
-            pltpu.VMEM((_SLOTS, 1, _LANES), q_table.dtype),
-            pltpu.SemaphoreType.DMA((_SLOTS,)),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_interaction_kernel_quant, T, bag, k, F, relu),
-        out_shape=jax.ShapeDtypeStruct((padded, H), jnp.float32),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(idx_flat, scales.astype(jnp.float32),
-      q_table.reshape(rows * k, _LANES), bot, w_bot, m,
-      bias.astype(jnp.float32).reshape(1, H))
-    return out[:batch]
-
-
-def fused_interaction_quant_reference(q_table, scales, indices, bottom,
-                                      w, bias, relu: bool = True):
-    """Oracle: dequantize the gathered rows, then the unfused
-    composition — the contract fused_interaction_quant must match."""
-    idx = indices.astype(jnp.int32)
-    if idx.ndim == 2:
-        idx = idx[:, :, None]
-    deq = (jnp.take(q_table, idx, axis=0).astype(jnp.float32)
-           * jnp.take(scales.astype(jnp.float32), idx, axis=0)[..., None])
-    emb = jnp.sum(deq, axis=2)
-    batch, T = idx.shape[0], idx.shape[1]
-    F = T + 1
-    x = jnp.concatenate(
-        [bottom.astype(jnp.float32)[:, None, :], emb], axis=1)
-    z = lax.dot_general(x, x, (((2,), (2,)), ((0,), (0,))),
-                        preferred_element_type=jnp.float32)
-    sel = np.array([i * F + j for i, j in tril_pairs(F)], dtype=np.int32)
-    zt = z.reshape(batch, F * F)[:, sel]
-    cat = jnp.concatenate([bottom.astype(jnp.float32), zt], axis=1)
-    y = (jnp.dot(cat, w.astype(jnp.float32),
-                 preferred_element_type=jnp.float32)
-         + bias.astype(jnp.float32))
-    return jnp.maximum(y, 0.0) if relu else y

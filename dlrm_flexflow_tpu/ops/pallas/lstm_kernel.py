@@ -3,7 +3,7 @@
 Round-4 calibration found the LSTM cell WEIGHT-STREAM-BOUND: of the
 ~32 us/iteration an NMT-sized cell (b64, h1024, bf16) costs under
 lax.scan, ~27 us is re-streaming the (h, 4h) recurrent matrix from HBM —
-XLA does not keep scan weights resident in VMEM (BENCHMARKS.md r4).
+XLA does not keep scan weights resident in VMEM.
 This kernel pins them: the grid iterates the time dimension (TPU grid
 steps run in order), the recurrent weights use a CONSTANT index_map so
 pallas keeps their block in VMEM across all steps, and the (b, h)
@@ -62,6 +62,33 @@ def _fwd_kernel(xp_ref, wh_ref, ys_ref, cs_ref, h_s, c_s):
         cs_ref[0, :, :] = c
 
 
+# share of VMEM the kernels may plan for; the rest is Mosaic's own temps
+_VMEM_SHARE = 0.6
+
+
+def _scan_vmem_need(batch: int, hidden: int, w_itemsize: int) -> int:
+    """Bytes the BACKWARD kernel (the larger of the two) holds in VMEM,
+    counted the way Pallas allocates them: every BlockSpec'd operand is
+    double-buffered, the constant-index weights included."""
+    resident = 2 * (2 * hidden * 4 * hidden * w_itemsize)  # wh + whT
+    # per-step fp32 blocks: xp in, dz out (b, 4h); dy/hprev/cprev/c (b, h)
+    blocks = 2 * (2 * batch * 4 * hidden + 4 * batch * hidden) * 4
+    carries = 2 * batch * hidden * 4
+    temps = 3 * batch * 4 * hidden * 4          # gates, dz, concat
+    return resident + blocks + carries + temps
+
+
+def _compiler_params(batch: int, hidden: int, w_itemsize: int):
+    """Ask for the need plus the headroom the eligibility gate
+    (`scan_shape_fits`) reserved. Under the compiler's default scoped
+    limit the NMT shape (h=1024, bf16, b64) still compiles on v5e, but
+    h=1280 and h=1408, which the gate admits, run out of VMEM; with this
+    limit all three compile. Small shapes keep a 16 MiB allowance."""
+    need = _scan_vmem_need(batch, hidden, w_itemsize)
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=max(int(need / _VMEM_SHARE), 16 << 20))
+
+
 def _run_fwd(xproj, wh, interpret, with_residuals=True):
     # TIME-MAJOR (T, b, 4h): TPU blocks must keep the last two dims
     # (sublane, lane) aligned — the time dim rides the grid as dim 0.
@@ -87,6 +114,7 @@ def _run_fwd(xproj, wh, interpret, with_residuals=True):
             pltpu.VMEM((b, h), jnp.float32),
             pltpu.VMEM((b, h), jnp.float32),
         ],
+        compiler_params=_compiler_params(b, h, wh.dtype.itemsize),
         interpret=interpret,
     )(xproj, wh)
     return out if with_residuals else (out, None)
@@ -143,6 +171,7 @@ def _run_bwd(xproj, wh, hs_prev, cs_prev, cs, dys, interpret):
             pltpu.VMEM((b, h), jnp.float32),
             pltpu.VMEM((b, h), jnp.float32),
         ],
+        compiler_params=_compiler_params(b, h, wh.dtype.itemsize),
         interpret=interpret,
     )(xproj, wh, whT, dys, hs_prev, cs_prev, cs)
     return dzs
@@ -179,35 +208,13 @@ def _vjp_bwd(interpret, res, dys):
 lstm_scan.defvjp(_vjp_fwd, _vjp_bwd)
 
 
-def _device_vmem_bytes() -> int:
-    """VMEM capacity of the attached TPU core. Known generations by
-    device_kind; a conservative 16 MiB floor otherwise (the guide's
-    generic per-core figure) so an eligibility decision made for an
-    unknown chip under-claims rather than failing Mosaic compilation
-    with a VMEM OOM."""
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return 16 * 1024 * 1024
-    for tag in ("v4", "v5", "v6", "v7"):
-        if tag in kind:
-            return 128 * 1024 * 1024
-    return 16 * 1024 * 1024
-
-
 def resident_scan_ok(model, batch: int, hidden: int, seq: int,
                      local: bool = False) -> bool:
     """Whether the VMEM-resident kernel path applies: TPU, lane-aligned
-    hidden, sublane-aligned batch, and recurrent weights that fit VMEM
-    residency comfortably. The budget is sized for the BACKWARD kernel,
-    which pins wh AND whT simultaneously, at the model's actual
-    compute-dtype width (fp32 doubles it), PLUS the per-step streamed
-    blocks (xp/dz at b×4h, h/c residual and output blocks at b×h,
-    double-buffered by the pipeline) and the fp32 carry scratch —
-    against the ATTACHED device's VMEM with 40% headroom for Mosaic
-    temps, not a flat constant (an eligible-looking large-hidden config
-    on a 16 MiB-VMEM generation must fall back to lax.scan instead of
-    dying in Mosaic compilation).
+    hidden, sublane-aligned batch, and a backward kernel (wh AND whT
+    pinned, at the model's compute-dtype width) that fits the ATTACHED
+    device's VMEM — an eligible-looking large-hidden config must fall
+    back to lax.scan instead of dying in Mosaic compilation.
 
     `local=False` additionally requires a single-device mesh (a direct
     pallas call cannot run inside GSPMD); `local=True` checks per-SHARD
@@ -230,13 +237,11 @@ def scan_shape_fits(model, batch: int, hidden: int, seq: int,
     shared by the runtime route predicate and the strategy search's
     backend-independent candidate predicate. `vmem_bytes` overrides the
     attached device's VMEM (search prices for the TARGET chip)."""
+    if not vmem_bytes:
+        from ...search.cost_model import TPUSpec
+        vmem_bytes = TPUSpec.detect().vmem_bytes
     itemsize = jnp.dtype(getattr(model.config, "jnp_compute_dtype",
                                  jnp.bfloat16)).itemsize
-    resident = 2 * hidden * 4 * hidden * itemsize   # bwd: wh + whT
-    # per-grid-step blocks: xp/dz (b,4h) + ~4 (b,h) blocks, x2 for the
-    # pipeline's double buffering; carries are fp32 scratch
-    blocks = 2 * (batch * 4 * hidden + 4 * batch * hidden) * itemsize
-    blocks += 2 * batch * hidden * 4
-    budget = 0.6 * (vmem_bytes or _device_vmem_bytes())
     return (hidden % 128 == 0 and batch % 8 == 0 and seq >= 2
-            and resident + blocks <= budget)
+            and _scan_vmem_need(batch, hidden, itemsize)
+            <= _VMEM_SHARE * vmem_bytes)

@@ -134,26 +134,17 @@ def _recurrent_scan(model, xproj, whc, cdt, op=None):
     route = _dp_route(model, op, b, h, s)
     if route is not None:
         axes, _ = route
-        import inspect
-
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map as _shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map as _shard_map
-        # the replication-check kwarg was renamed check_rep -> check_vma
-        _ckw = ({"check_vma": False}
-                if "check_vma" in inspect.signature(_shard_map).parameters
-                else {"check_rep": False})
+
+        from ..parallel.mesh import smap
 
         def local(xp, w):
             ys = lstm_scan(jnp.swapaxes(xp, 0, 1), w)
             return jnp.swapaxes(ys, 0, 1)
 
-        return _shard_map(
-            local, mesh=model.mesh,
-            in_specs=(P(axes, None, None), P(None, None)),
-            out_specs=P(axes, None, None), **_ckw)(xproj, whc)
+        return smap(local, model.mesh,
+                    in_specs=(P(axes, None, None), P(None, None)),
+                    out_specs=P(axes, None, None))(xproj, whc)
 
     def cell(carry, xp):
         hprev, cprev = carry

@@ -63,8 +63,8 @@ mechanisms make that hold:
 Capacity: the dense exchange reserves `n_local` slots per peer (the
 always-exact worst case — one owner could receive every local lookup);
 the dedup'd exchange reserves min(n_local, flat_rows_local). A
-production TPU kernel would use a ragged exchange at the actual
-distinct-id counts (this jax version predates `ragged_all_to_all`); the
+production TPU kernel would use a ragged exchange
+(`lax.ragged_all_to_all`) at the actual distinct-id counts; the
 cost model prices that balanced exchange — with the expected distinct
 ids from an observed id histogram (utils/histogram.py) when one is
 attached — which is also what the padded dense form approaches.
@@ -80,23 +80,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
-try:  # renamed across jax versions
-    from jax import shard_map as _shard_map          # type: ignore
-except ImportError:                                   # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
+from .mesh import smap as _smap
 from .sharding import param_axis_indices
 
 _INT_MAX = np.iinfo(np.int32).max
-
-
-def _smap(f, mesh, in_specs, out_specs):
-    import inspect
-    params = inspect.signature(_shard_map).parameters
-    kw = {"check_vma": False} if "check_vma" in params else \
-        {"check_rep": False}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
 
 
 @dataclass(frozen=True)

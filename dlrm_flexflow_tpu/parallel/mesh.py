@@ -68,6 +68,14 @@ def make_mesh(devices: Optional[Sequence] = None,
     return Mesh(arr, names)
 
 
+def smap(f, mesh: Mesh, in_specs, out_specs):
+    """`jax.shard_map` with the replication check off — the one form the
+    package uses (Pallas calls and hand-routed collectives inside the
+    body carry no replication rule)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
 def mesh_axis_sizes(mesh: Mesh) -> List[int]:
     return [mesh.shape[name] for name in mesh.axis_names]
 

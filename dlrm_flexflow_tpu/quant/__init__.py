@@ -19,18 +19,15 @@ row bytes. One policy multiplies against nearly every subsystem:
 - serving: ``EmbeddingCache`` / the shard tier / the warm cache hold
   ~4x more rows per MB, dequantizing at the RANKER boundary.
 
-Execution model (two halves, one semantics):
-
-- **TPU storage path**: the Pallas gather kernel dequantizes int8/fp8
-  row tiles in VMEM (scales ride beside the row tiles via scalar
-  prefetch, ``ops/pallas/embedding_kernel.embedding_bag_quant``).
-- **Portable (XLA / CPU) path**: *master-resident simulated
-  quantization* — the trainable parameter remains an fp32 master whose
-  values are exact dequantizations of the quantized representation, so
-  every existing update path (replicated / row-sharded / hybrid,
-  SGD / momentum / Adam, superstep scan) runs unchanged while storage
-  boundaries (checkpoints' delta publishes, serving tables, caches)
-  ship true ``q + scale`` payloads bit-exactly.
+Execution model: *master-resident simulated quantization* on every
+backend — the trainable parameter remains an fp32 master whose values
+are exact dequantizations of the quantized representation, so every
+existing update path (replicated / row-sharded / hybrid, SGD / momentum
+/ Adam, superstep scan) runs unchanged while storage boundaries
+(checkpoints' delta publishes, serving tables, caches) ship true
+``q + scale`` payloads bit-exactly. (A Pallas gather that dequantized
+int8 rows in VMEM was removed: Mosaic refuses single-row slices of an
+int8 HBM table, whose rows are tiled four to a 32-bit word.)
 
 Update rules:
 

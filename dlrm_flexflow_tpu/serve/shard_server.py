@@ -23,7 +23,30 @@ in-process standby from the same cache this process booted from.
 from __future__ import annotations
 
 import argparse
+import os
+import subprocess
 import sys
+
+_REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def spawn(cache_dir: str, nshards: int, slot: int,
+          **popen_kw) -> subprocess.Popen:
+    """Start slot ``slot`` as a child process on an OS-assigned port
+    (read it off the ``SHARD_SERVER_OK`` line of its stdout). A shard is
+    a host-RAM lookup process by design, so the child is pinned to the
+    CPU platform whatever the parent runs on: an accelerator belongs to
+    one process, and a child that reached for the ranker's chip (the
+    shard's ``topk`` asks JAX for its backend) would fail or hang."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (_REPO, env.get("PYTHONPATH", "")) if p)
+    return subprocess.Popen(
+        [sys.executable, "-m", "dlrm_flexflow_tpu.serve.shard_server",
+         "--cache-dir", cache_dir, "--nshards", str(nshards),
+         "--slot", str(slot), "--port", "0"],
+        env=env, text=True, stdout=subprocess.PIPE, **popen_kw)
 
 
 def build_shard(cache_dir: str, nshards: int, slot: int):

@@ -336,12 +336,15 @@ class CompileCache:
                           "compile", reason)
 
     # --- read ----------------------------------------------------------
-    def get(self, key: str):
-        out = self._get(key)
+    def get(self, key: str, devices):
+        """`devices`: the mesh's devices, in mesh order — the executable
+        loads onto exactly these (a sub-mesh executable must not be
+        spread over every device the backend has)."""
+        out = self._get(key, devices)
         _obs_cache_event("compile", "hit" if out is not None else "miss")
         return out
 
-    def _get(self, key: str):
+    def _get(self, key: str, devices):
         from . import faults
         path = self._path(key)
         if not os.path.isfile(path):
@@ -370,7 +373,8 @@ class CompileCache:
                 raise ValueError("payload CRC mismatch (bit rot)")
             from jax.experimental import serialize_executable
             exec_ = serialize_executable.deserialize_and_load(
-                payload, blob["in_tree"], blob["out_tree"])
+                payload, blob["in_tree"], blob["out_tree"],
+                execution_devices=list(devices))
         except Exception as e:   # noqa: BLE001 — stale/corrupt/unsupported
             self._reject(f"{name}: {e}")
             self.misses += 1

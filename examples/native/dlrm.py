@@ -58,6 +58,7 @@ def main(argv=None):
         from dlrm_flexflow_tpu.parallel.distributed import \
             initialize_distributed
         initialize_distributed()
+    ff.use_compile_cache()
     cfg = ff.FFConfig.parse_args(argv)
     dcfg = DLRMConfig.parse_args(cfg.unparsed)
     data_path = None
@@ -78,9 +79,10 @@ def main(argv=None):
     else:
         ndev = min(cfg.num_devices, len(jax.devices())) or len(jax.devices())
         mesh = make_mesh(num_devices=ndev)
-    log_app.info("devices=%d processes=%d batch=%d tables=%d "
-                 "zipf_alpha=%g", ndev,
-                 jax.process_count(), cfg.batch_size,
+    log_app.info("platform=%s device_kind=%r devices=%d processes=%d "
+                 "batch=%d tables=%d zipf_alpha=%g",
+                 jax.devices()[0].platform, jax.devices()[0].device_kind,
+                 ndev, jax.process_count(), cfg.batch_size,
                  len(dcfg.embedding_size), dcfg.zipf_alpha)
 
     model = ff.FFModel(cfg)

@@ -50,6 +50,30 @@ def _assert_tree_close(a, b, rtol=2e-4, atol=2e-5):
         np.testing.assert_allclose(x, y, rtol=rtol, atol=atol)
 
 
+@pytest.mark.parametrize("row_shard", [False, True])
+def test_sharded_init_equals_eager_init(monkeypatch, row_shard):
+    """init_layers picks the sharded SPMD init program for large ops and
+    the eager init + placement for small ones; both give the same bits
+    under the same shardings, in the same dict order."""
+    from dlrm_flexflow_tpu.core import model as model_mod
+    dcfg = DLRMConfig(embedding_size=[64] * 8, sparse_feature_size=8,
+                      mlp_bot=[4, 16, 8], mlp_top=[72, 16, 1])
+
+    def strat(m, d, n):
+        return dlrm_strategy(m, d, n, row_shard=row_shard)
+
+    eager = _build_dlrm_model(dcfg, 8, strat).params
+    monkeypatch.setattr(model_mod, "_SHARDED_INIT_BYTES", 0)
+    sharded = _build_dlrm_model(dcfg, 8, strat).params
+    assert list(eager) == list(sharded)
+    for name in eager:
+        assert list(eager[name]) == list(sharded[name])
+        for n, a in eager[name].items():
+            b = sharded[name][n]
+            assert a.sharding.is_equivalent_to(b.sharding, a.ndim), (name, n)
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_dp_matches_single_chip():
     single = _train_dlrm(1)
     multi = _train_dlrm(8)  # default: data parallel over 8 devices

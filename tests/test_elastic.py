@@ -809,7 +809,7 @@ class TestWarmCaches:
         blob = pickle.load(open(path, "rb"))
         blob["code"] = "deadbeef00000000"
         pickle.dump(blob, open(path, "wb"))
-        assert cache.get(key) is None
+        assert cache.get(key, jax.devices()[:1]) is None
         assert "stale code fingerprint" in cache.stats()["last_reject"]
 
     def test_fit_auto_attaches_caches_next_to_manifest(self, tmp_path):

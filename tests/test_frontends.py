@@ -348,7 +348,7 @@ def test_keras_model_reusable_as_layer():
 
 
 def test_fit_trains_remainder_and_off_size_batch():
-    """VERDICT r4 weak #5: keras fit on 1,000 samples x b64 must train 15
+    """keras fit on 1,000 samples x b64 must train 15
     full batches PLUS the 40-sample remainder (per-shape executable
     cache), and FFModel.fit must accept batch_size != compile-time by
     recompiling instead of raising."""

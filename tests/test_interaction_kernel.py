@@ -4,9 +4,8 @@ The Pallas kernel (ops/pallas/interaction_kernel.py, exercised in
 interpreter mode on the CPU backend) must match the unfused jnp oracle
 ``fused_interaction_reference`` — the exact composition the default
 graph builds as five ops — to float32 rounding: forward (relu and
-linear heads, 2-D and bagged indices), the custom-vjp backward for
-every differentiable input, and the quantized twin (int8 / fp8 table,
-in-kernel row dequant) against its dequantize-then-interact oracle.
+linear heads, 2-D and bagged indices) and the custom-vjp backward for
+every differentiable input.
 
 The op wrapper (ops/interaction.py FusedDotInteraction, built by
 build_dlrm(fuse_interaction=True)) must train on the fallback path
@@ -27,9 +26,8 @@ from dlrm_flexflow_tpu.analysis.hlo_audit import audit_interaction_fusion
 from dlrm_flexflow_tpu.models.dlrm import (DLRMConfig, build_dlrm,
                                            synthetic_batch)
 from dlrm_flexflow_tpu.ops.pallas.interaction_kernel import (
-    fused_interaction, fused_interaction_quant,
-    fused_interaction_quant_reference, fused_interaction_reference,
-    scatter_tril_weight, supports, tril_pairs)
+    fused_interaction, fused_interaction_reference, scatter_tril_weight,
+    supports, tril_pairs)
 from dlrm_flexflow_tpu.parallel.mesh import make_mesh
 
 T, ROWS, D, BAG, H, B = 4, 64, 128, 3, 32, 13
@@ -128,37 +126,6 @@ class TestKernelVsOracle:
             rtol=1e-5, atol=1e-5)
         with pytest.raises(ValueError, match="tril weight"):
             scatter_tril_weight(w_tril[:-1], F)
-
-
-class TestQuantKernel:
-    @pytest.mark.parametrize("qdtype", ["int8", "fp8"])
-    def test_dequant_in_kernel(self, qdtype):
-        """The quantized twin dequantizes rows DURING the gather
-        accumulate and matches the dequantize-then-interact oracle."""
-        rng = np.random.RandomState(2)
-        _, idx, bottom, w, bias = _inputs(seed=2)
-        q = rng.randint(-127, 128, size=(T * ROWS, D)).astype(np.int8)
-        q = jnp.asarray(q)
-        if qdtype == "fp8":
-            q = q.astype(jnp.float8_e4m3fn)
-        scales = jnp.asarray(
-            (rng.rand(T * ROWS) * 0.1 + 0.01).astype(np.float32))
-        out_k = fused_interaction_quant(q, scales, idx, bottom, w, bias,
-                                        True, True)
-        out_r = fused_interaction_quant_reference(q, scales, idx,
-                                                  bottom, w, bias,
-                                                  relu=True)
-        np.testing.assert_allclose(out_k, out_r, rtol=1e-5, atol=1e-3)
-
-    def test_quant_supports_gate(self):
-        rng = np.random.RandomState(3)
-        q = jnp.asarray(rng.randint(-127, 128,
-                                    size=(T * ROWS, 64)).astype(np.int8))
-        scales = jnp.ones((T * ROWS,), jnp.float32)
-        _, idx, bottom, w, bias = _inputs(seed=3)
-        with pytest.raises(ValueError, match="dim % 128"):
-            fused_interaction_quant(q, scales, idx, bottom[:, :64],
-                                    w[:P + 64], bias, True, True)
 
 
 # =====================================================================

@@ -33,7 +33,7 @@ def test_dryrun_8dev_no_spmd_rematerialization():
     # train a real step on the hybrid DCN+ICI mesh
     assert "terabyte ok" in out
     # the north-star v5e-64 topology EXECUTES (8 slices x 8, spawned as
-    # a 64-virtual-device child; VERDICT r4 #6)
+    # a 64-virtual-device child)
     assert "terabyte-64 ok" in out
     assert "rematerialization" not in out, "\n".join(
         l[:200] for l in out.splitlines() if "rematerial" in l)

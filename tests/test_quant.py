@@ -259,39 +259,6 @@ def _pb_field_keys(blob):
 
 
 # ---------------------------------------------------------------------
-# Pallas in-kernel dequant gather (interpret mode on CPU)
-# ---------------------------------------------------------------------
-class TestPallasQuantKernel:
-    # fp8 rides the sum row only — the avg path is a scalar divide on
-    # top of sum, already covered by the int8 pair
-    @pytest.mark.parametrize("dt,aggr", [("int8", "sum"), ("int8", "avg"),
-                                         ("fp8", "sum")])
-    def test_matches_dequant_oracle(self, dt, aggr):
-        from dlrm_flexflow_tpu.ops.pallas.embedding_kernel import (
-            embedding_bag_quant, embedding_bag_quant_reference)
-        rng = np.random.RandomState(0)
-        tbl = rng.randn(64, 128).astype(np.float32)
-        idx = rng.randint(0, 64, (9, 4))
-        q, s = quantize_rows_np(tbl, dt)
-        out = embedding_bag_quant(jnp.asarray(q), jnp.asarray(s),
-                                  jnp.asarray(idx), aggr,
-                                  interpret=True)
-        ref = embedding_bag_quant_reference(jnp.asarray(q),
-                                            jnp.asarray(s),
-                                            jnp.asarray(idx), aggr)
-        assert np.allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
-
-    def test_rejects_unsupported_width(self):
-        from dlrm_flexflow_tpu.ops.pallas.embedding_kernel import (
-            embedding_bag_quant)
-        q, s = quantize_rows_np(np.zeros((8, 96), np.float32), "int8")
-        with pytest.raises(ValueError, match="dim % 128"):
-            embedding_bag_quant(jnp.asarray(q), jnp.asarray(s),
-                                jnp.zeros((2, 2), jnp.int32),
-                                interpret=True)
-
-
-# ---------------------------------------------------------------------
 # master_weight: bit-identical to the fp32-accumulator reference
 # ---------------------------------------------------------------------
 class TestMasterWeightBitIdentity:

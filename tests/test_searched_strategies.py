@@ -1,6 +1,6 @@
-"""The committed SEARCHED DLRM strategies must EXECUTE (VERDICT r4 #3:
-search -> export .pb -> load -> compile -> train-step, closed for the
-DLRM configs like the InceptionV3 pipeline already is).
+"""The committed SEARCHED DLRM strategies must EXECUTE (search ->
+export .pb -> load -> compile -> train-step, closed for the DLRM
+configs like the InceptionV3 pipeline already is).
 
 Strategies key op NAMES (reference strategy.cc:23-26), which are
 table-size-independent — the tests rebuild each config with scaled-down
