@@ -38,7 +38,6 @@ from ..parallel.pconfig import ParallelConfig, StrategyMap
 from ..parallel.sharding import AxisAssigner
 from ..parallel.distributed import MeshDegraded, MeshReturned, put_global
 from ..obs import trace as obstrace
-from ..utils.profiling import superstep_annotation
 from ..utils.watchdog import StallReport, WorkerStalled
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from . import losses as losses_mod
@@ -844,11 +843,14 @@ class FFModel:
                 p = {pn: _to_memory(v, "host") for pn, v in p.items()}
             else:
                 ctx = contextlib.nullcontext()
+            # the op's name in the compiled step's metadata (autodiff makes
+            # it jvp(ff.<name>) / transpose(jvp(ff.<name>)) for the backward)
+            scope = jax.named_scope(f"ff.{op.name}")
             if hasattr(op, "apply_with_state"):
                 st = op_state.get(op.name, {})
                 if host:
                     st = jax.tree.map(lambda v: _to_memory(v, "host"), st)
-                with ctx:
+                with ctx, scope:
                     outs, st2 = op.apply_with_state(p, st, xs,
                                                     training=training,
                                                     rng=rng)
@@ -857,7 +859,7 @@ class FFModel:
                                        st2)
                 new_state[op.name] = st2
             else:
-                with ctx:
+                with ctx, scope:
                     outs = op.apply(p, xs, training=training, rng=rng)
             if host:
                 outs = [_to_memory(o, "device") for o in outs]
@@ -931,7 +933,15 @@ class FFModel:
                 f"got {policy!r}")
         self._anomaly_policy = policy
         sentinel = policy != "none"
-        loss_f = losses_mod.loss_fn(self.loss_type)
+        plain_loss = losses_mod.loss_fn(self.loss_type)
+
+        def loss_f(logits, labels):
+            with jax.named_scope("ff.loss"):
+                return plain_loss(logits, labels)
+
+        def opt_update(p, grads, state):
+            with jax.named_scope("ff.optimizer"):
+                return self.optimizer.update(p, grads, state)
         logits_guid = self._logits_tensor.guid
         preds_guid = self._preds_tensor.guid
         metric_names = self.metrics
@@ -1011,11 +1021,13 @@ class FFModel:
                 for op in sparse_ops:
                     xs_ = [anc_env[t.guid] for t in op.inputs]
                     f = getattr(op, "apply_with_fwd", None)
-                    if f is not None:
-                        outs, fwd = f(params[op.name], xs_, rng=rng)
-                    else:
-                        outs, fwd = op.apply(params[op.name], xs_,
-                                             training=True, rng=rng), None
+                    with jax.named_scope(f"ff.{op.name}"):
+                        if f is not None:
+                            outs, fwd = f(params[op.name], xs_, rng=rng)
+                        else:
+                            outs, fwd = op.apply(params[op.name], xs_,
+                                                 training=True,
+                                                 rng=rng), None
                     v = outs[0]
                     sh = self._out_sharding.get(op.outputs[0].guid)
                     if sh is not None:
@@ -1058,8 +1070,7 @@ class FFModel:
                                            if pk in sparse_names}
                     else:
                         dense_state[k] = sub
-                new_params, new_opt = self.optimizer.update(p_dense, gd,
-                                                            dense_state)
+                new_params, new_opt = opt_update(p_dense, gd, dense_state)
                 stateful = bool(slab_names) or (
                     isinstance(self.optimizer, SGDOptimizer)
                     and self.optimizer.weight_decay != 0.0)
@@ -1067,26 +1078,27 @@ class FFModel:
                                          jnp.zeros((), jnp.int32))
                 for op in sparse_ops:
                     xs = [anc_env[t.guid] for t in op.inputs]
-                    if stateful:
-                        # the whole per-param slab dict goes in (the
-                        # hybrid placement splits an embedding into
-                        # kernel + hot_kernel, each with its own state)
-                        slabs = {k: dict(sparse_state[k][op.name])
-                                 for k in slab_names}
-                        new_k, new_slabs = op.sparse_opt_update(
-                            params[op.name], xs, gev[op.name],
-                            self.optimizer, slabs, pre_step,
-                            fwd=emb_fwd.get(op.name))
-                        new_params[op.name] = new_k
-                        for k in slab_names:
-                            ns = new_slabs[k]
-                            new_opt[k][op.name] = (
-                                ns if isinstance(ns, dict)
-                                else {"kernel": ns})
-                    else:
-                        new_params[op.name] = op.sparse_sgd_update(
-                            params[op.name], xs, gev[op.name],
-                            self.optimizer.lr, fwd=emb_fwd.get(op.name))
+                    with jax.named_scope(f"ff.update.{op.name}"):
+                        if stateful:
+                            # the whole per-param slab dict goes in (the
+                            # hybrid placement splits an embedding into
+                            # kernel + hot_kernel, each with its own state)
+                            slabs = {k: dict(sparse_state[k][op.name])
+                                     for k in slab_names}
+                            new_k, new_slabs = op.sparse_opt_update(
+                                params[op.name], xs, gev[op.name],
+                                self.optimizer, slabs, pre_step,
+                                fwd=emb_fwd.get(op.name))
+                            new_params[op.name] = new_k
+                            for k in slab_names:
+                                ns = new_slabs[k]
+                                new_opt[k][op.name] = (
+                                    ns if isinstance(ns, dict)
+                                    else {"kernel": ns})
+                        else:
+                            new_params[op.name] = op.sparse_sgd_update(
+                                params[op.name], xs, gev[op.name],
+                                self.optimizer.lr, fwd=emb_fwd.get(op.name))
                 if host_ops:
                     host_cts = {op.name: gev[op.name] for op in host_ops}
             else:
@@ -1098,57 +1110,58 @@ class FFModel:
                 (loss, (preds, st2)), grads = jax.value_and_grad(
                     objective, has_aux=True)(params, op_state)
                 grad_leaves = jax.tree.leaves(grads)
-                new_params, new_opt = self.optimizer.update(params, grads,
-                                                            opt_state)
+                new_params, new_opt = opt_update(params, grads, opt_state)
             # quantized storage, stochastic_rounding rule: re-quantize
             # the updated tables IN the step (master_weight keeps the
             # exact fp32 master — no requant, bit-identical to fp32
             # training; quantization happens at storage boundaries)
             new_params = self._requant_sr_params(new_params, rng)
-            # anomaly sentinel: ONE on-device finiteness predicate over the
-            # loss and the global gradient norm. Under any active policy
-            # the non-finite update is suppressed ON DEVICE (jnp.where
-            # against the pre-step values — both live inside the step, so
-            # donation costs nothing), keeping params/opt/op-state clean
-            # without a host sync; rollback/raise additionally read the
-            # flag back at the step boundary (train_batch_device).
-            step_ok = None
-            if sentinel:
-                gsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                          for g in grad_leaves)
-                gnorm = jnp.sqrt(gsq)
-                step_ok = jnp.isfinite(loss) & jnp.isfinite(gnorm)
+            # one scope for the step's bookkeeping: sentinel, metrics, sums
+            with jax.named_scope("ff.metrics"):
+                # anomaly sentinel: ONE on-device finiteness predicate over the
+                # loss and the global gradient norm. Under any active policy
+                # the non-finite update is suppressed ON DEVICE (jnp.where
+                # against the pre-step values — both live inside the step, so
+                # donation costs nothing), keeping params/opt/op-state clean
+                # without a host sync; rollback/raise additionally read the
+                # flag back at the step boundary (train_batch_device).
+                step_ok = None
+                if sentinel:
+                    gsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                              for g in grad_leaves)
+                    gnorm = jnp.sqrt(gsq)
+                    step_ok = jnp.isfinite(loss) & jnp.isfinite(gnorm)
 
-                def _keep(new, old):
-                    return jax.tree.map(
-                        lambda n, o: jnp.where(step_ok, n, o), new, old)
-                new_params = _keep(new_params, params)
-                new_opt = _keep(new_opt, opt_state)
-                st2 = _keep(st2, op_state)
-            # CCE metrics expect probabilities; when the graph doesn't end
-            # in a Softmax op, preds are raw logits — normalize them here
-            if "crossentropy" in loss_type and preds_guid == logits_guid:
-                mpreds = jax.nn.softmax(preds.astype(jnp.float32), axis=-1)
-            else:
-                mpreds = preds
-            mets = metrics_mod.compute_metrics(metric_names, loss_type,
-                                               mpreds, batch["label"])
-            # accumulate running sums ON DEVICE inside the step (the
-            # reference accumulates in device memory with atomics and folds
-            # once per epoch, metrics_functions.cu:57-135; host-side
-            # accumulation would dispatch extra tiny kernels every step)
-            if sentinel:
-                # a skipped step contributes nothing (NaNs would poison
-                # the epoch's running sums irreversibly)
-                new_msums = {k: msums[k]
-                             + jnp.where(step_ok, v, jnp.zeros_like(v))
-                             for k, v in mets.items()}
-            else:
-                new_msums = {k: msums[k] + v for k, v in mets.items()}
-            mets["loss"] = loss
-            if sentinel:
-                mets["anomaly"] = ~step_ok
-                mets["grad_norm"] = gnorm
+                    def _keep(new, old):
+                        return jax.tree.map(
+                            lambda n, o: jnp.where(step_ok, n, o), new, old)
+                    new_params = _keep(new_params, params)
+                    new_opt = _keep(new_opt, opt_state)
+                    st2 = _keep(st2, op_state)
+                # CCE metrics expect probabilities; when the graph doesn't end
+                # in a Softmax op, preds are raw logits — normalize them here
+                if "crossentropy" in loss_type and preds_guid == logits_guid:
+                    mpreds = jax.nn.softmax(preds.astype(jnp.float32), axis=-1)
+                else:
+                    mpreds = preds
+                mets = metrics_mod.compute_metrics(metric_names, loss_type,
+                                                   mpreds, batch["label"])
+                # accumulate running sums ON DEVICE inside the step (the
+                # reference accumulates in device memory with atomics and folds
+                # once per epoch, metrics_functions.cu:57-135; host-side
+                # accumulation would dispatch extra tiny kernels every step)
+                if sentinel:
+                    # a skipped step contributes nothing (NaNs would poison
+                    # the epoch's running sums irreversibly)
+                    new_msums = {k: msums[k]
+                                 + jnp.where(step_ok, v, jnp.zeros_like(v))
+                                 for k, v in mets.items()}
+                else:
+                    new_msums = {k: msums[k] + v for k, v in mets.items()}
+                mets["loss"] = loss
+                if sentinel:
+                    mets["anomaly"] = ~step_ok
+                    mets["grad_norm"] = gnorm
             if host_cts is not None:
                 mets["_host_cts"] = host_cts
             # the step counter stays device-resident across calls (feeding
@@ -1244,11 +1257,12 @@ class FFModel:
             if name not in new_params:
                 continue
             sub = dict(new_params[name])
-            for j, pname in enumerate(("kernel", "hot_kernel")):
-                if pname in sub:
-                    k = jax.random.fold_in(rng, 0x51 + 2 * i + j)
-                    sub[pname] = fake_quant_stochastic(
-                        sub[pname], pol.dtype, k)
+            with jax.named_scope(f"ff.update.{name}"):   # part of the update
+                for j, pname in enumerate(("kernel", "hot_kernel")):
+                    if pname in sub:
+                        k = jax.random.fold_in(rng, 0x51 + 2 * i + j)
+                        sub[pname] = fake_quant_stochastic(
+                            sub[pname], pol.dtype, k)
             new_params[name] = sub
         return new_params
 
@@ -1568,17 +1582,21 @@ class FFModel:
         (torn, stale code, wrong mesh) compile fresh and re-store.
         `fresh=True` skips the lookup — the GSPMD
         recompile-on-sharding-disagree fallback must not re-load the
-        very entry that just disagreed."""
+        very entry that just disagreed. Every step executable, built or
+        loaded, comes from here, so this is where obs.trace learns which
+        program's scope map `program_scopes()` answers with."""
         cache = getattr(self, "_compile_cache", None)
-        if cache is None:
-            return lower().compile()
-        ckey = cache.exec_key(kind, self, shape_key)
-        if not fresh:
-            exec_ = cache.get(ckey, self.mesh.devices.flat)
-            if exec_ is not None:
-                return exec_
-        exec_ = lower().compile()
-        cache.put(ckey, exec_)
+        with obstrace.span(f"compile/{kind}"):
+            exec_ = None
+            if cache is not None:
+                ckey = cache.exec_key(kind, self, shape_key)
+                if not fresh:
+                    exec_ = cache.get(ckey, self.mesh.devices.flat)
+            if exec_ is None:
+                exec_ = lower().compile()
+                if cache is not None:
+                    cache.put(ckey, exec_)
+        obstrace.note_program(kind, exec_)
         return exec_
 
     def _maybe_return_devices(self, k: int = 1) -> None:
@@ -1782,6 +1800,7 @@ class FFModel:
         return self.train_batch_staged(
             self._stage_superstep(stack_batches(batches)))
 
+    @obstrace.spanned("train/dispatch")
     def train_superstep_device(self, sbatch: Dict):
         """Train step for a staged [K, batch, ...] megabatch: ONE
         host→device dispatch of the AOT-cached fused-scan executable
@@ -1823,10 +1842,10 @@ class FFModel:
         if exec_ is None:
             exec_ = execs[key] = self._cached_compile(
                 "superstep", key, lambda: self._superstep_fn.lower(*args))
-        with obstrace.span("train/superstep", step=self._step, k=k), \
-                superstep_annotation(self._step, k,
-                                     enabled=bool(
-                                         self.config.profile_dir)):
+        # once in K steps, so it can carry what a trace reader divides a
+        # fused span by
+        with obstrace.span("train/superstep", step_num=self._step,
+                           superstep=k):
             try:
                 outs = exec_(*args)
             except ValueError as e:
@@ -1861,6 +1880,7 @@ class FFModel:
                         stacked["grad_norm"])[idx]))
         return mets
 
+    @obstrace.spanned("train/dispatch")
     def _train_dispatch(self, device_batch: Dict, host_idx,
                         next_host_idx=None):
         self._ensure_step_state()
@@ -1904,7 +1924,7 @@ class FFModel:
         if exec_ is None:
             exec_ = execs[key] = self._cached_compile(
                 "train", key, lambda: self._train_step.lower(*args))
-        with obstrace.span("train/step", step=self._step):
+        with obstrace.span("train/step"):
             try:
                 outs = exec_(*args)
             except ValueError as e:
@@ -2808,6 +2828,7 @@ class FFModel:
             staging_cost = float("inf")
         elif stage_mode == "always":
             staging_cost = 0.0
+        @obstrace.spanned("fit/stage")
         def _stage_all():
             # (re)build the device-resident batches against the model's
             # CURRENT input shardings — called once up front, and again
@@ -2869,6 +2890,16 @@ class FFModel:
         throttle = 1 if jax.default_backend() == "cpu" else 32
         from collections import deque
         inflight = deque()
+
+        def _throttled(m):
+            # bound the pipeline without draining it: block on the step
+            # issued `throttle` iterations AGO
+            inflight.append(m["loss"])
+            if len(inflight) > throttle:
+                with obstrace.span("fit/throttle"):
+                    jax.block_until_ready(inflight.popleft())
+            return m
+
         start = time.time()
         mets = None
         num_samples = 0
@@ -2986,16 +3017,12 @@ class FFModel:
             return pipe.get()
 
         def _train_streamed():
-            m = self.train_batch_staged(
-                _next_staged(),
-                next_host_idx=_peek_next_host_idx if hres_async else None)
             # same in-flight bound as the pre-staged path: the producer
             # keeps the dispatch queue fed, so the throttle is what
             # keeps XLA-CPU collectives from starving
-            inflight.append(m["loss"])
-            if len(inflight) > throttle:
-                jax.block_until_ready(inflight.popleft())
-            return m
+            return _throttled(self.train_batch_staged(
+                _next_staged(),
+                next_host_idx=_peek_next_host_idx if hres_async else None))
 
         if use_pipe:
             _build_pipe(start_epoch, start_batch)
@@ -3036,12 +3063,9 @@ class FFModel:
                                     if drift_mon is not None else 0.0)
                         if k > 1:
                             if staged is not None:
-                                mets = self.train_superstep_device(
-                                    staged_super[b])
-                                inflight.append(mets["loss"])
-                                if len(inflight) > throttle:
-                                    jax.block_until_ready(
-                                        inflight.popleft())
+                                mets = _throttled(
+                                    self.train_superstep_device(
+                                        staged_super[b]))
                             elif pipe is not None:
                                 mets = _train_streamed()
                             else:
@@ -3059,12 +3083,7 @@ class FFModel:
                                          for kk, v in inputs.items()}
                                 batch["label"] = labels[sl]
                                 db_b = self._device_batch(batch)
-                            mets = self.train_batch_device(db_b)
-                            # bound the pipeline without draining it: block
-                            # on the step issued `throttle` iterations AGO
-                            inflight.append(mets["loss"])
-                            if len(inflight) > throttle:
-                                jax.block_until_ready(inflight.popleft())
+                            mets = _throttled(self.train_batch_device(db_b))
                         elif pipe is not None:
                             mets = _train_streamed()
                         else:
@@ -3204,20 +3223,23 @@ class FFModel:
                     if use_pipe:
                         _build_pipe(epoch, b0)
                     continue
-                if verbose and mets is not None:
-                    # host sync happens here only (metrics are async)
-                    print(f"epoch {epoch}: loss={float(mets['loss']):.6f} "
-                          + self.perf.summary_line())
-                if callbacks:
-                    for cb in callbacks:
-                        cb(self, epoch, self.perf.report())
+                with obstrace.span("fit/epoch_end"):
+                    if verbose and mets is not None:
+                        # host sync happens here only (metrics are async)
+                        print(f"epoch {epoch}: "
+                              f"loss={float(mets['loss']):.6f} "
+                              + self.perf.summary_line())
+                    if callbacks:
+                        for cb in callbacks:
+                            cb(self, epoch, self.perf.report())
                 epoch += 1
                 b0 = 0
-            if mets is not None:
-                # the loss readback waits for the last step, and so for
-                # the whole timed loop
-                float(mets["loss"])
-        self._host_drain()   # land the last async host scatter, if any
+            with obstrace.span("fit/drain"):
+                if mets is not None:
+                    # the loss readback waits for the last step, and so
+                    # for the whole timed loop
+                    float(mets["loss"])
+                self._host_drain()   # land the last async host scatter
         if mgr is not None:
             mgr.wait()        # surface any background-save error
             mgr.save(self, {"epoch": epochs, "batch": 0})  # final snapshot

@@ -19,7 +19,9 @@ Three modules, one switch:
   structured warning when measured/predicted exceeds the threshold.
 
 Everything is OFF by default and free when off (no-op singletons, type
-identity pinned like ``make_lock``). Turn it on with ``--obs on``
+identity pinned like ``make_lock``); the one thing that is always there
+is that a span is a profiler annotation (``trace.span``), inert without
+a profiler session. Turn the rest on with ``--obs on``
 (plus ``--obs-trace-dir DIR`` to export traces) or programmatically via
 :func:`configure` / the per-module ``override`` context managers.
 Configure BEFORE building engines/fleets — instruments resolve at

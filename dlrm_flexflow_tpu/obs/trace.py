@@ -1,5 +1,6 @@
-"""Structured tracing: named spans in a bounded in-memory ring,
-exported as Chrome-trace / Perfetto JSON.
+"""Structured tracing: named spans on the profiler's clock and, with
+``--obs on``, in a bounded in-memory ring exported as Chrome-trace /
+Perfetto JSON; and the scope map of the compiled step programs.
 
 ``utils/profiling.py`` covers the two reference layers (per-op timing,
 whole-run xprof capture); what neither shows is the CROSS-SUBSYSTEM
@@ -7,7 +8,10 @@ story — where a request spent its time between the prefetch ring, the
 superstep dispatch, the delta publisher, the snapshot watcher, and the
 serving batcher. This module instruments those seams:
 
-- training: ``prefetch/produce`` → ``train/step`` / ``train/superstep``
+- training: ``prefetch/produce`` → ``train/dispatch`` around
+  ``train/step`` / ``train/superstep``; in ``fit()``'s loop
+  ``fit/stage``, ``fit/throttle``, ``fit/epoch_end``, ``fit/drain``;
+  ``compile/<kind>`` where a step program is built or loaded
 - serving:  ``serve/enqueue`` → ``serve/batch-form`` →
   ``serve/dispatch`` → ``serve/swap``
 - freshness: ``publish/full`` / ``publish/delta`` →
@@ -22,19 +26,35 @@ JSON — load it at ``chrome://tracing`` or https://ui.perfetto.dev —
 with complete ("X") events whose ts/dur nesting reconstructs the span
 tree per thread.
 
-Off (the default) is free: :func:`span` returns a shared no-op context
-manager (type identity pinned, like ``make_lock`` and the metrics
-twins), and :func:`instant` returns immediately.
+:func:`span` is the program's one span call. It always is a
+``jax.profiler.TraceAnnotation``, so a profiler session (``--profile-dir``,
+the benchmark's traced slice) sees the program's spans beside JAX's own
+events and the device's ops, on one clock; without a session the
+annotation is inert (a third of a microsecond). With ``--obs on`` the span
+also lands in the ring. Off (the default), :func:`instant` and
+:func:`complete` return immediately and the ring stays empty.
+
+The device half of the story is not a span: every op of the model is
+traced under a ``jax.named_scope("ff.<op name>")`` (core/model.py), which
+XLA keeps as each instruction's ``op_name``. :func:`program_scopes` reads
+that back from the newest compiled step programs, so "what is
+``fusion.7``" has an answer: ``jit(train_step)/.../ff.update.emb/dedup/sort``.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import os
+import re
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+from ..utils.logging import get_logger
 
 _ENABLED = False
 _TRACE_DIR = ""
@@ -130,40 +150,29 @@ def _emit(ev: Dict[str, Any]) -> None:
     _APPENDED += 1
 
 
-class _NullSpan:
-    """Shared reusable no-op context manager — the obs-off fast path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-NULL_SPAN = _NullSpan()
-
-
 class Span:
-    """One named duration. Records a complete ("X") event on exit, so
-    an abandoned span (thread died mid-work) simply never lands — the
+    """One named duration, on both clocks: a profiler annotation while it
+    is open and a complete ("X") event in the ring on exit, so an
+    abandoned span (thread died mid-work) simply never lands — the
     instants around it still tell the story."""
 
-    __slots__ = ("name", "cat", "args", "_t0")
+    __slots__ = ("name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, name: str, cat: str, args: Dict[str, Any]):
         self.name = name
         self.cat = cat
         self.args = args
         self._t0 = 0.0
+        self._ann = TraceAnnotation(name, **args)
 
     def __enter__(self) -> "Span":
+        self._ann.__enter__()
         self._t0 = _now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = _now_us()
+        self._ann.__exit__(exc_type, exc, tb)
         args = self.args
         if exc_type is not None:
             args = dict(args)
@@ -174,11 +183,24 @@ class Span:
 
 
 def span(name: str, cat: str = "", **args):
-    """Context manager timing one named unit of work. The shared no-op
-    singleton when tracing is off — ``span(...) is NULL_SPAN``."""
+    """Context manager timing one named unit of work: a profiler
+    annotation always (inert without a profiler session), and an event in
+    the ring as well when tracing is on. Spans that run once a step pass
+    no keyword arguments."""
     if not _ENABLED:
-        return NULL_SPAN
+        return TraceAnnotation(name, **args)
     return Span(name, cat, args)
+
+
+def spanned(name: str):
+    """Decorator form of :func:`span`: a function's whole body."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run(*args, **kwargs):
+            with span(name):
+                return fn(*args, **kwargs)
+        return run
+    return wrap
 
 
 def complete(name: str, t0_s: float, cat: str = "", **args) -> None:
@@ -201,6 +223,58 @@ def instant(name: str, cat: str = "", **args) -> None:
         return
     _emit({"name": name, "cat": cat or "ff", "ph": "i", "s": "t",
            "ts": _now_us(), "args": args})
+
+
+# ---------------------------------------------------------------------
+# the scope map: which op of the model an instruction of a step program is
+# ---------------------------------------------------------------------
+# kind -> the newest executable `_cached_compile` handed over, built or
+# loaded. A kind is one jitted function (train -> jit_train_step), so
+# this is the newest per module; nothing is read from an executable until
+# someone asks, so a program that cannot give its text costs a step nothing
+_PROGRAMS: Dict[str, Any] = {}
+_MODULE = re.compile(r"HloModule ([^\s,]+)")
+_INSTRUCTION = re.compile(
+    r'^\s*(?:ROOT )?%?([\w.\-]+) = (?:.*\bop_name="([^"]*)")?')
+
+
+def note_program(kind: str, executable) -> None:
+    """Keep the newest compiled program of each kind: one dict store a
+    compile."""
+    _PROGRAMS[kind] = executable
+
+
+def hlo_scopes(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: op_name path} of one optimized HLO module's
+    text ("" for an instruction that carries none). The trace calls a
+    device op by the same instruction name (`fusion.7`, `sort.0`)."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m:
+            out[m.group(1)] = m.group(2) or ""
+    return out
+
+
+def program_scopes() -> Dict[str, Dict[str, str]]:
+    """{module name: {instruction name: op_name path}} of the noted step
+    programs, e.g. ``program_scopes()["jit_train_step"]["fusion.7"]`` ->
+    ``"jit(train_step)/jit(main)/ff.update.emb/dedup/sort"``. Parsed from
+    the executables' text on request only."""
+    out = {}
+    for kind, executable in _PROGRAMS.items():
+        try:
+            text = executable.as_text()
+        except Exception as e:   # noqa: BLE001 - a diagnostic, never fatal
+            # (a backend may keep no HLO for an executable it loaded)
+            get_logger("obs").warning(
+                "program_scopes: the %s executable gives no text (%s: %s)",
+                kind, type(e).__name__, e)
+            continue
+        module = _MODULE.match(text)
+        if module:
+            out[module.group(1)] = hlo_scopes(text)
+    return out
 
 
 # ---------------------------------------------------------------------

@@ -79,12 +79,13 @@ def _packed_gather_tiles(tbl, ix, r, d):
     (n, r*d)) — THE packed-layout invariant (tile = ix//r, sub-row =
     ix%r, wrap) in one place; the tiles are the forward residuals the
     write-only sparse update reuses."""
-    vrow = (ix // r).reshape(-1)
-    tiles = jnp.take(tbl, vrow, axis=0, mode="wrap")    # (n, r*d)
-    sub = (ix % r).reshape(-1)
-    rows = jnp.take_along_axis(
-        tiles.reshape(-1, r, d), sub[:, None, None], axis=1)[:, 0, :]
-    return rows.reshape(ix.shape + (d,)), vrow, tiles
+    with jax.named_scope("gather"):
+        vrow = (ix // r).reshape(-1)
+        tiles = jnp.take(tbl, vrow, axis=0, mode="wrap")    # (n, r*d)
+        sub = (ix % r).reshape(-1)
+        rows = jnp.take_along_axis(
+            tiles.reshape(-1, r, d), sub[:, None, None], axis=1)[:, 0, :]
+        return rows.reshape(ix.shape + (d,)), vrow, tiles
 
 
 def _packed_gather(tbl, ix, r, d):
@@ -1668,14 +1669,15 @@ class EmbeddingBagStacked(Op):
             return self.apply(params, xs, training=True, rng=rng), None
         (idx,) = xs
         table = params["kernel"]
-        idx = idx.astype(jnp.int32) % self.num_entries
-        if self._table_order is not None:
-            idx = jnp.take(idx, self._table_order, axis=1)
         r, d = self._pack, self.out_dim
         T, rows = self.num_tables, self.num_entries
         view = table.reshape(T * rows // r, r * d)
-        offs = (jnp.arange(T, dtype=jnp.int32) * rows)[None, :, None]
-        g = idx + offs                                 # (batch, T, bag)
+        with jax.named_scope("index"):
+            idx = idx.astype(jnp.int32) % self.num_entries
+            if self._table_order is not None:
+                idx = jnp.take(idx, self._table_order, axis=1)
+            offs = (jnp.arange(T, dtype=jnp.int32) * rows)[None, :, None]
+            g = idx + offs                             # (batch, T, bag)
         rows_g, _, tiles = _packed_gather_tiles(view, g, r, d)
         out = (jnp.mean(rows_g, axis=2) if self.aggr == AGGR_MODE_AVG
                else jnp.sum(rows_g, axis=2))
@@ -1984,9 +1986,10 @@ class EmbeddingBagConcat(Op):
     def _global_indices(self, idx):
         """Per-table modulo (wrap semantics like the gathers above) then
         offset into the concatenated rows."""
-        sizes = jnp.asarray(self.table_sizes, jnp.int32)[None, :, None]
-        offs = jnp.asarray(self._offsets, jnp.int32)[None, :, None]
-        return idx.astype(jnp.int32) % sizes + offs       # (batch, T, bag)
+        with jax.named_scope("index"):
+            sizes = jnp.asarray(self.table_sizes, jnp.int32)[None, :, None]
+            offs = jnp.asarray(self._offsets, jnp.int32)[None, :, None]
+            return idx.astype(jnp.int32) % sizes + offs       # (batch, T, bag)
 
     # ---- row/PARAM-axis sharding hooks (see configure_row_shard) -------
     def _row_shard_geometry(self):

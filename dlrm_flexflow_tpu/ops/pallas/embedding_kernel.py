@@ -114,12 +114,14 @@ def _pallas_forward(table: jax.Array, indices: jax.Array,
             pltpu.SemaphoreType.DMA((_SLOTS,)),
         ],
     )
-    out = pl.pallas_call(
-        functools.partial(_bag_kernel, bag, k),
-        out_shape=jax.ShapeDtypeStruct((padded, dim), table.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(idx_flat, table.reshape(rows * k, _LANES))
+    with jax.named_scope("emb_gather"):
+        out = pl.pallas_call(
+            functools.partial(_bag_kernel, bag, k),
+            out_shape=jax.ShapeDtypeStruct((padded, dim), table.dtype),
+            grid_spec=grid_spec,
+            interpret=interpret,
+            name="emb_gather",
+        )(idx_flat, table.reshape(rows * k, _LANES))
     return out[:batch]
 
 
@@ -300,27 +302,28 @@ def _pack_tile_updates(indices, updates, dim, dtype):
     to 128 one-hot selects, inflating the HLO and compile time faster
     than the runtime win pays back — those fall back to the dynamic
     roll."""
-    r_per_tile = _LANES // dim
-    indices = indices.astype(jnp.int32)
-    tile_rows = indices // r_per_tile
-    padded = jnp.pad(updates.astype(dtype), ((0, 0), (0, _LANES - dim)))
-    if r_per_tile == 1:
-        return tile_rows, padded
-    slot = indices % r_per_tile                       # (n,)
-    if r_per_tile > 16:
-        # low-dim fallback: one dynamic lane roll per row instead of r
-        # unrolled one-hot selects (compile-time guard; see docstring)
-        shift = (slot * dim).astype(jnp.int32)
-        return tile_rows, jax.vmap(jnp.roll)(padded, shift)
-    out = None
-    for s in range(r_per_tile):
-        rolled = jnp.roll(padded, s * dim, axis=1)    # static lane rotate
-        # select, not multiply: 0 * NaN would smear a non-finite update
-        # into the other unpacked rows sharing this tile
-        sel = jnp.where((slot == s)[:, None], rolled,
-                        jnp.zeros_like(rolled))
-        out = sel if out is None else out + sel
-    return tile_rows, out
+    with jax.named_scope("scatter"):
+        r_per_tile = _LANES // dim
+        indices = indices.astype(jnp.int32)
+        tile_rows = indices // r_per_tile
+        padded = jnp.pad(updates.astype(dtype), ((0, 0), (0, _LANES - dim)))
+        if r_per_tile == 1:
+            return tile_rows, padded
+        slot = indices % r_per_tile                       # (n,)
+        if r_per_tile > 16:
+            # low-dim fallback: one dynamic lane roll per row instead of r
+            # unrolled one-hot selects (compile-time guard; see docstring)
+            shift = (slot * dim).astype(jnp.int32)
+            return tile_rows, jax.vmap(jnp.roll)(padded, shift)
+        out = None
+        for s in range(r_per_tile):
+            rolled = jnp.roll(padded, s * dim, axis=1)    # static lane rotate
+            # select, not multiply: 0 * NaN would smear a non-finite update
+            # into the other unpacked rows sharing this tile
+            sel = jnp.where((slot == s)[:, None], rolled,
+                            jnp.zeros_like(rolled))
+            out = sel if out is None else out + sel
+        return tile_rows, out
 
 
 def _dedup_tile_updates(tile_rows, tile_upds):
@@ -330,34 +333,35 @@ def _dedup_tile_updates(tile_rows, tile_upds):
     (target (m,), summed (m, 128), rep (m,), m) where rep[s] is one
     original position whose update landed in segment s (for callers that
     need a representative forward tile)."""
-    m = tile_rows.shape[0]
-    order = jnp.argsort(tile_rows)
-    srows = tile_rows[order]
-    supds = tile_upds[order]
-    first = jnp.concatenate([jnp.ones((1,), jnp.bool_),
-                             srows[1:] != srows[:-1]])
-    seg = jnp.cumsum(first) - 1                      # (m,) segment ids
-    summed = jax.ops.segment_sum(supds, seg, num_segments=m,
-                                 indices_are_sorted=True)
-    target = jax.ops.segment_max(srows, seg, num_segments=m,
-                                 indices_are_sorted=True)
-    rep = jax.ops.segment_max(order, seg, num_segments=m,
-                              indices_are_sorted=True)
-    num_unique = seg[-1] + 1
-    valid = jnp.arange(m) < num_unique
-    target = jnp.where(valid, target, -1).astype(jnp.int32)
-    # empty segments get INT_MIN from segment_max; mask to a safe index so
-    # downstream takes never depend on fill behavior (their rows carry
-    # target=-1 and are skipped by the kernels regardless)
-    rep = jnp.where(valid, rep, 0)
+    with jax.named_scope("dedup"):
+        m = tile_rows.shape[0]
+        order = jnp.argsort(tile_rows)
+        srows = tile_rows[order]
+        supds = tile_upds[order]
+        first = jnp.concatenate([jnp.ones((1,), jnp.bool_),
+                                 srows[1:] != srows[:-1]])
+        seg = jnp.cumsum(first) - 1                      # (m,) segment ids
+        summed = jax.ops.segment_sum(supds, seg, num_segments=m,
+                                     indices_are_sorted=True)
+        target = jax.ops.segment_max(srows, seg, num_segments=m,
+                                     indices_are_sorted=True)
+        rep = jax.ops.segment_max(order, seg, num_segments=m,
+                                  indices_are_sorted=True)
+        num_unique = seg[-1] + 1
+        valid = jnp.arange(m) < num_unique
+        target = jnp.where(valid, target, -1).astype(jnp.int32)
+        # empty segments get INT_MIN from segment_max; mask to a safe index so
+        # downstream takes never depend on fill behavior (their rows carry
+        # target=-1 and are skipped by the kernels regardless)
+        rep = jnp.where(valid, rep, 0)
 
-    pad_n = (-m) % _SCATTER_B
-    if pad_n:
-        target = jnp.pad(target, (0, pad_n), constant_values=-1)
-        summed = jnp.pad(summed, ((0, pad_n), (0, 0)))
-        rep = jnp.pad(rep, (0, pad_n))
-        m += pad_n
-    return target, summed, rep, m
+        pad_n = (-m) % _SCATTER_B
+        if pad_n:
+            target = jnp.pad(target, (0, pad_n), constant_values=-1)
+            summed = jnp.pad(summed, ((0, pad_n), (0, 0)))
+            rep = jnp.pad(rep, (0, pad_n))
+            m += pad_n
+        return target, summed, rep, m
 
 
 def _dedup_and_scatter(view, tile_rows, tile_upds, interpret):
@@ -377,13 +381,15 @@ def _dedup_and_scatter(view, tile_rows, tile_upds, interpret):
             pltpu.SemaphoreType.DMA((_SCATTER_B,)),
         ],
     )
-    return pl.pallas_call(
-        _scatter_unique_kernel,
-        out_shape=jax.ShapeDtypeStruct(view.shape, view.dtype),
-        grid_spec=grid_spec,
-        input_output_aliases={2: 0},
-        interpret=interpret,
-    )(target, summed.astype(view.dtype), view)
+    with jax.named_scope("emb_scatter_add"):
+        return pl.pallas_call(
+            _scatter_unique_kernel,
+            out_shape=jax.ShapeDtypeStruct(view.shape, view.dtype),
+            grid_spec=grid_spec,
+            input_output_aliases={2: 0},
+            interpret=interpret,
+            name="emb_scatter_add",
+        )(target, summed.astype(view.dtype), view)
 
 
 def _scatter_write_kernel(idx_ref, val_ref, tbl_ref, out_ref, wsems):
@@ -433,8 +439,9 @@ def scatter_write_rows_packed(view: jax.Array, indices: jax.Array,
     target, summed, rep, m = _dedup_tile_updates(tile_rows, tile_upds)
     # any duplicate's forward tile is the same pre-update value, so the
     # representative original position's tile stands in for the segment
-    vals = (jnp.take(fwd_tiles, rep, axis=0).astype(view.dtype)
-            + summed.astype(view.dtype))
+    with jax.named_scope("scatter"):
+        vals = (jnp.take(fwd_tiles, rep, axis=0).astype(view.dtype)
+                + summed.astype(view.dtype))
     return scatter_write_tiles(view, target, vals, interpret=interpret)
 
 
@@ -466,13 +473,15 @@ def scatter_write_tiles(view: jax.Array, target: jax.Array,
             pltpu.SemaphoreType.DMA((_SCATTER_B,)),
         ],
     )
-    return pl.pallas_call(
-        _scatter_write_kernel,
-        out_shape=jax.ShapeDtypeStruct(view.shape, view.dtype),
-        grid_spec=grid_spec,
-        input_output_aliases={2: 0},
-        interpret=interpret,
-    )(target, vals.astype(view.dtype), view)
+    with jax.named_scope("emb_scatter_write"):
+        return pl.pallas_call(
+            _scatter_write_kernel,
+            out_shape=jax.ShapeDtypeStruct(view.shape, view.dtype),
+            grid_spec=grid_spec,
+            input_output_aliases={2: 0},
+            interpret=interpret,
+            name="emb_scatter_write",
+        )(target, vals.astype(view.dtype), view)
 
 
 def sharded_scatter_add_packed(mesh, row_axes, view, indices, updates,

@@ -201,13 +201,15 @@ def _pallas_fused(table, indices, bottom, w, bias, relu, interpret):
             pltpu.SemaphoreType.DMA((_SLOTS,)),
         ],
     )
-    out = pl.pallas_call(
-        functools.partial(_interaction_kernel, T, bag, k, F, relu),
-        out_shape=jax.ShapeDtypeStruct((padded, H), jnp.float32),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(idx_flat, table.reshape(rows * k, _LANES), bot, w_bot, m,
-      bias.astype(jnp.float32).reshape(1, H))
+    with jax.named_scope("interaction_fused"):
+        out = pl.pallas_call(
+            functools.partial(_interaction_kernel, T, bag, k, F, relu),
+            out_shape=jax.ShapeDtypeStruct((padded, H), jnp.float32),
+            grid_spec=grid_spec,
+            interpret=interpret,
+            name="interaction_fused",
+        )(idx_flat, table.reshape(rows * k, _LANES), bot, w_bot, m,
+          bias.astype(jnp.float32).reshape(1, H))
     return out[:batch]
 
 

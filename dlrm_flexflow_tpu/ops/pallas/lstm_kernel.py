@@ -101,22 +101,24 @@ def _run_fwd(xproj, wh, interpret, with_residuals=True):
     kernel = (_fwd_kernel if with_residuals else
               (lambda xp, w, ys, h_s, c_s:
                _fwd_kernel(xp, w, ys, None, h_s, c_s)))
-    out = pl.pallas_call(
-        kernel,
-        grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, b, h4), lambda i: (i, 0, 0)),
-            pl.BlockSpec(wh.shape, lambda i: (0, 0)),   # VMEM-resident
-        ],
-        out_specs=[blk, blk] if with_residuals else blk,
-        out_shape=[shp, shp] if with_residuals else shp,
-        scratch_shapes=[
-            pltpu.VMEM((b, h), jnp.float32),
-            pltpu.VMEM((b, h), jnp.float32),
-        ],
-        compiler_params=_compiler_params(b, h, wh.dtype.itemsize),
-        interpret=interpret,
-    )(xproj, wh)
+    with jax.named_scope("lstm_fwd"):
+        out = pl.pallas_call(
+            kernel,
+            grid=(T,),
+            in_specs=[
+                pl.BlockSpec((1, b, h4), lambda i: (i, 0, 0)),
+                pl.BlockSpec(wh.shape, lambda i: (0, 0)),   # VMEM-resident
+            ],
+            out_specs=[blk, blk] if with_residuals else blk,
+            out_shape=[shp, shp] if with_residuals else shp,
+            scratch_shapes=[
+                pltpu.VMEM((b, h), jnp.float32),
+                pltpu.VMEM((b, h), jnp.float32),
+            ],
+            compiler_params=_compiler_params(b, h, wh.dtype.itemsize),
+            interpret=interpret,
+            name="lstm_fwd",
+        )(xproj, wh)
     return out if with_residuals else (out, None)
 
 
@@ -156,24 +158,26 @@ def _run_bwd(xproj, wh, hs_prev, cs_prev, cs, dys, interpret):
     whT = jnp.swapaxes(wh, 0, 1)
     rev = lambda i: (T - 1 - i, 0, 0)
     blk_h = pl.BlockSpec((1, b, h), rev)
-    dzs = pl.pallas_call(
-        _bwd_kernel,
-        grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, b, h4), rev),
-            pl.BlockSpec(wh.shape, lambda i: (0, 0)),    # resident
-            pl.BlockSpec(whT.shape, lambda i: (0, 0)),   # resident
-            blk_h, blk_h, blk_h, blk_h,
-        ],
-        out_specs=pl.BlockSpec((1, b, h4), rev),
-        out_shape=jax.ShapeDtypeStruct((T, b, h4), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((b, h), jnp.float32),
-            pltpu.VMEM((b, h), jnp.float32),
-        ],
-        compiler_params=_compiler_params(b, h, wh.dtype.itemsize),
-        interpret=interpret,
-    )(xproj, wh, whT, dys, hs_prev, cs_prev, cs)
+    with jax.named_scope("lstm_bwd"):
+        dzs = pl.pallas_call(
+            _bwd_kernel,
+            grid=(T,),
+            in_specs=[
+                pl.BlockSpec((1, b, h4), rev),
+                pl.BlockSpec(wh.shape, lambda i: (0, 0)),    # resident
+                pl.BlockSpec(whT.shape, lambda i: (0, 0)),   # resident
+                blk_h, blk_h, blk_h, blk_h,
+            ],
+            out_specs=pl.BlockSpec((1, b, h4), rev),
+            out_shape=jax.ShapeDtypeStruct((T, b, h4), jnp.float32),
+            scratch_shapes=[
+                pltpu.VMEM((b, h), jnp.float32),
+                pltpu.VMEM((b, h), jnp.float32),
+            ],
+            compiler_params=_compiler_params(b, h, wh.dtype.itemsize),
+            interpret=interpret,
+            name="lstm_bwd",
+        )(xproj, wh, whT, dys, hs_prev, cs_prev, cs)
     return dzs
 
 

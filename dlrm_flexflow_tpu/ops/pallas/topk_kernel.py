@@ -172,31 +172,33 @@ def _pallas_topk(q_codes, q_scales, codes, scales, k, chunk, interpret):
         jnp.asarray(codes, jnp.int8))
     scales_p = jnp.zeros((Rp, 1), jnp.float32).at[:R, 0].set(
         jnp.asarray(scales, jnp.float32))
-    out_s, out_i = pl.pallas_call(
-        functools.partial(_topk_kernel, int(k), C, R),
-        grid=(Rp // C,),
-        in_specs=[
-            pl.BlockSpec((B, d), lambda i: (0, 0)),              # queries
-            pl.BlockSpec((B, 1), lambda i: (0, 0)),              # q scales
-            pl.BlockSpec((C, d), lambda i: (i, 0)),              # chunk
-            pl.BlockSpec((C, 1), lambda i: (i, 0)),              # scales
-        ],
-        out_specs=[
-            pl.BlockSpec((B, int(k)), lambda i: (0, 0)),
-            pl.BlockSpec((B, int(k)), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, int(k)), jnp.float32),
-            jax.ShapeDtypeStruct((B, int(k)), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((B, int(k)), jnp.float32),
-            pltpu.VMEM((B, int(k)), jnp.int32),
-        ],
-        interpret=interpret,
-    )(jnp.asarray(q_codes, jnp.int8),
-      jnp.asarray(q_scales, jnp.float32).reshape(B, 1),
-      codes_p, scales_p)
+    with jax.named_scope("topk"):
+        out_s, out_i = pl.pallas_call(
+            functools.partial(_topk_kernel, int(k), C, R),
+            grid=(Rp // C,),
+            in_specs=[
+                pl.BlockSpec((B, d), lambda i: (0, 0)),              # queries
+                pl.BlockSpec((B, 1), lambda i: (0, 0)),              # q scales
+                pl.BlockSpec((C, d), lambda i: (i, 0)),              # chunk
+                pl.BlockSpec((C, 1), lambda i: (i, 0)),              # scales
+            ],
+            out_specs=[
+                pl.BlockSpec((B, int(k)), lambda i: (0, 0)),
+                pl.BlockSpec((B, int(k)), lambda i: (0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, int(k)), jnp.float32),
+                jax.ShapeDtypeStruct((B, int(k)), jnp.int32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((B, int(k)), jnp.float32),
+                pltpu.VMEM((B, int(k)), jnp.int32),
+            ],
+            interpret=interpret,
+            name="topk",
+        )(jnp.asarray(q_codes, jnp.int8),
+          jnp.asarray(q_scales, jnp.float32).reshape(B, 1),
+          codes_p, scales_p)
     return np.asarray(out_s), np.asarray(out_i)
 
 

@@ -10,7 +10,9 @@ Parity with the reference's two profiling layers (SURVEY.md §5.1):
 - whole-run tracing (reference Legion Prof via -lg:prof): here
   `jax.profiler.trace(dir)` captures an xprof/TensorBoard trace of the
   jitted train step — set FFConfig.profile_dir (CLI `--profile-dir`)
-  before calling fit().
+  before calling fit(). The trace carries the program's own names: the
+  host spans of obs/trace.py (`train/dispatch`, `fit/throttle`, ...) and,
+  on every device op, the `ff.<op name>` scope it was traced under.
 
 Per-iteration trace *replay* (reference begin_trace/end_trace(111),
 dlrm.cc:179-185) needs no hook: jit compile-once/execute-many subsumes it.
@@ -64,26 +66,6 @@ def format_profile(rows: List[Dict]) -> str:
             f"{meas:>12}{r['roofline_ms']:>13.4f}"
             f"{r['flops'] / 1e9:>9.3f}")
     return "\n".join(lines)
-
-
-def superstep_annotation(step: int, num_steps: int = 1,
-                         enabled: bool = True):
-    """Wrap one (super)step dispatch in a `jax.profiler.
-    StepTraceAnnotation` so `--profile-dir` traces show superstep
-    boundaries and per-K timing instead of one undifferentiated blob:
-    xprof groups device work under step markers, and the `superstep`
-    metadata key carries K so a trace reader can divide a fused span
-    into per-trained-step time.
-
-    `enabled=False` returns a no-op context — the hot loop must not pay
-    even a TraceMe when no trace is being captured (this PR exists to
-    delete per-step host overhead)."""
-    if not enabled:
-        import contextlib
-        return contextlib.nullcontext()
-    import jax
-    return jax.profiler.StepTraceAnnotation(
-        "ff_superstep", step_num=int(step), superstep=int(num_steps))
 
 
 class TraceContext:

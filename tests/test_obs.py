@@ -96,15 +96,28 @@ class TestObsOff:
             c.inc()
             c.inc(5, a="1")
 
-    def test_span_identity_and_reusable(self):
+    def test_span_is_a_profiler_annotation_and_the_ring_stays_empty(self):
+        """The one span API: off, a span is just the profiler's
+        annotation (inert without a profiler session) and nothing lands
+        in the ring; on, the same call also records the event."""
+        import jax
         with trace.override(False):
+            trace.clear()
             s = trace.span("anything", k=1)
-            assert s is trace.NULL_SPAN
+            assert type(s) is jax.profiler.TraceAnnotation
             with s:
                 with trace.span("nested"):
                     pass
             trace.instant("marker")
+            trace.complete("timed", 0.0)
             assert trace.events() == []
+        with trace.override(True):
+            trace.clear()
+            with trace.span("anything", k=1):
+                pass
+            (ev,) = trace.events()
+            assert (ev["name"], ev["args"]) == ("anything", {"k": 1})
+            trace.clear()
 
     def test_off_latency_reservoir_is_plain_and_unregistered(self):
         with metrics.override(False):

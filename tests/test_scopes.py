@@ -1,0 +1,235 @@
+"""The program names its work (ISSUE 24): an `ff.<op name>` scope on every
+op of the compiled step, a `name=` on every Pallas kernel, the scope map
+`obs.trace.program_scopes()` reads back from the executable, and the host
+spans of `fit()`'s loop on the profiler's clock. All on the CPU: scopes are
+compile-time metadata, the same on every backend."""
+
+import ast
+import contextlib
+import glob
+import os
+import re
+
+import pytest
+
+import jax
+
+import dlrm_flexflow_tpu as ff
+from dlrm_flexflow_tpu.models.dlrm import (DLRMConfig, build_dlrm,
+                                           synthetic_batch)
+from dlrm_flexflow_tpu.obs import trace
+from dlrm_flexflow_tpu.parallel.mesh import make_mesh
+
+PKG = os.path.dirname(os.path.abspath(ff.__file__))
+BS, NB = 16, 4
+MODELS = {
+    # the stacked (uniform tables, `cat`) and the concatenated (uneven
+    # tables, `dot`) embedding ops: the two routes the benchmark's cells take
+    "cat": DLRMConfig(embedding_size=[64] * 4, sparse_feature_size=8,
+                      mlp_bot=[4, 16, 8], mlp_top=[40, 16, 1]),
+    "dot": DLRMConfig(embedding_size=[64, 32, 16], sparse_feature_size=8,
+                      mlp_bot=[4, 16, 8], mlp_top=[14, 16, 1],
+                      arch_interaction_op="dot"),
+}
+# what may go without a scope of the program's: instructions that do no
+# work of an op (XLA's own plumbing carries no metadata, a parameter or its
+# bitcast carries the argument's name) and the step counter's `step + 1`
+PLUMBING = {"parameter", "constant", "broadcast", "bitcast", "copy",
+            "tuple", "get-tuple-element", "iota"}
+STEP_COUNTER = "jit(train_step)/add"
+
+
+def _fit(kind, epochs=1, **cfg_kw):
+    dcfg = MODELS[kind]
+    model = ff.FFModel(ff.FFConfig(batch_size=BS, seed=2, **cfg_kw))
+    build_dlrm(model, dcfg)
+    model.compile(ff.SGDOptimizer(lr=0.1), "mean_squared_error", ["mse"],
+                  mesh=make_mesh(devices=jax.devices()[:1]))
+    model.init_layers()
+    x, y = synthetic_batch(dcfg, BS * NB, seed=1)
+    model.fit(x, y, epochs=epochs, verbose=False)
+    return model
+
+
+def _step_text():
+    return trace._PROGRAMS["train"].as_text()
+
+
+def _opcodes(hlo_text):
+    """{instruction name: opcode} of an optimized HLO module's text."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = .*?[\]})] ([\w\-]+)\(",
+                     line)
+        if m:
+            out[m.group(1)] = m.group(2)
+    return out
+
+
+@pytest.mark.parametrize("kind", sorted(MODELS))
+def test_every_instruction_of_the_step_has_a_path(kind):
+    model = _fit(kind)
+    scopes = trace.program_scopes()["jit_train_step"]
+    opcodes = _opcodes(_step_text())
+    assert set(scopes) == set(opcodes) and len(scopes) > 100
+    unscoped = {
+        name: path for name, path in scopes.items()
+        if "ff." not in path and opcodes[name] not in PLUMBING
+        # a reduction's or a scatter's combiner: its own tiny computation,
+        # named after the primitive, never an event of the trace
+        and "/" in path}
+    assert set(unscoped.values()) <= {STEP_COUNTER}, unscoped
+    # every op of the graph, its update and the step's bookkeeping are there
+    found = {m for p in scopes.values()
+             for m in re.findall(r"ff\.[\w.]+", p)}
+    # (a reshape compiles to nothing, so it names nothing)
+    wanted = {f"ff.{op.name}" for op in model.ops
+              if type(op).__name__ not in ("InputOp", "Reshape", "Flat")}
+    wanted |= {f"ff.update.{n}" for n in model._sparse_update_ops}
+    wanted |= {"ff.optimizer", "ff.loss", "ff.metrics"}
+    assert wanted <= found, wanted - found
+    # autodiff names the backward by itself, and the sub-scopes nest
+    paths = set(scopes.values())
+    assert any("transpose(jvp(ff.top_dense_0))" in p for p in paths)
+    emb = model._sparse_update_ops[0]
+    sub = {"cat": "gather", "dot": "index"}[kind]
+    assert any(re.search(rf"ff\.{emb}/(vmap\()?{sub}\)?/", p)
+               for p in paths), sorted(p for p in paths if emb in p)
+
+
+def test_scopes_are_metadata_only(monkeypatch):
+    """No op is added and no fusion changes: the optimized step has as
+    many instructions and fusions with the scopes as without."""
+    def census():
+        ops = _opcodes(_step_text())
+        return len(ops), sum(1 for o in ops.values() if o == "fusion")
+
+    _fit("dot")
+    with_scopes = census()
+    assert any("ff." in p for p in
+               trace.program_scopes()["jit_train_step"].values())
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    _fit("dot")
+    assert not any("ff." in p for p in
+                   trace.program_scopes()["jit_train_step"].values())
+    assert census() == with_scopes
+
+
+def test_every_pallas_call_is_named_and_scoped():
+    """Each `pallas_call` of ops/pallas/ passes a literal `name=` and is
+    the body of a `jax.named_scope` of the same name, so the kernel is
+    found by either."""
+    names = []
+    for path in sorted(glob.glob(os.path.join(PKG, "ops", "pallas",
+                                              "*.py"))):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        scoped = {}     # id(pallas_call node) -> enclosing scope's name
+        for node in ast.walk(tree):
+            if not isinstance(node, ast.With):
+                continue
+            ctx = node.items[0].context_expr
+            if (isinstance(ctx, ast.Call)
+                    and getattr(ctx.func, "attr", "") == "named_scope"):
+                for sub in ast.walk(node):
+                    scoped[id(sub)] = ctx.args[0].value
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.Call)
+                    and getattr(node.func, "attr", "") == "pallas_call"):
+                kw = {k.arg: k.value for k in node.keywords}
+                where = f"{os.path.basename(path)}:{node.lineno}"
+                assert isinstance(kw.get("name"), ast.Constant), where
+                assert scoped.get(id(node)) == kw["name"].value, where
+                names.append(kw["name"].value)
+    assert sorted(names) == ["emb_gather", "emb_scatter_add",
+                             "emb_scatter_write", "interaction_fused",
+                             "lstm_bwd", "lstm_fwd", "topk"]
+
+
+def test_a_loaded_executable_gives_the_same_scope_map(tmp_path):
+    """The benchmark's runs are warm: the step executable comes from a
+    cache. A deserialized executable must answer like the built one."""
+    from dlrm_flexflow_tpu.utils.warmcache import CompileCache
+
+    def scopes_of(model_cache):
+        dcfg = MODELS["cat"]
+        model = ff.FFModel(ff.FFConfig(batch_size=BS, seed=2))
+        build_dlrm(model, dcfg)
+        model.compile(ff.SGDOptimizer(lr=0.1), "mean_squared_error",
+                      ["mse"], mesh=make_mesh(devices=jax.devices()[:1]))
+        model.attach_compile_cache(model_cache)
+        model.init_layers()
+        x, y = synthetic_batch(dcfg, BS * NB, seed=1)
+        model.fit(x, y, epochs=1, verbose=False)
+        return trace.program_scopes()["jit_train_step"]
+
+    cold, warm = CompileCache(str(tmp_path)), CompileCache(str(tmp_path))
+    built = scopes_of(cold)
+    loaded = scopes_of(warm)
+    assert (cold.stats()["puts"], warm.stats()["hits"]) == (1, 1)
+    assert loaded == built
+    assert any("ff.update.emb" in p for p in loaded.values())
+
+
+def test_hlo_scopes_reads_one_instruction_a_line():
+    text = "\n".join([
+        "HloModule jit_train_step, is_scheduled=true",
+        "%fused_computation (p: f32[4]) -> f32[4] {",
+        '  %p = f32[4]{0} parameter(0)',
+        '  ROOT %neg.1 = f32[4]{0} negate(%p), metadata={op_name='
+        '"jit(train_step)/jit(main)/jvp(ff.top_dense_1)/neg" '
+        'source_file="a.py" source_line=3}',
+        "}",
+        "ENTRY %main.2 (x: f32[4]) -> f32[4] {",
+        "  fusion.7 = f32[4]{0} fusion(x), kind=kLoop, calls="
+        'fused_computation, metadata={op_name="jit(train_step)/'
+        'jit(main)/ff.update.emb/dedup/sort"}',
+        "  %copy-start.1 = (f32[4]{0}, f32[4]{0}, u32[]) copy-start(%x)",
+        "}"])
+    assert trace.hlo_scopes(text) == {
+        "p": "",
+        "neg.1": "jit(train_step)/jit(main)/jvp(ff.top_dense_1)/neg",
+        "fusion.7": "jit(train_step)/jit(main)/ff.update.emb/dedup/sort",
+        "copy-start.1": ""}
+
+
+def test_fit_emits_its_spans_on_the_profilers_clock(tmp_path):
+    """Under a profiler session, with `--obs off`, every span of fit()'s
+    loop is in the profiler's trace, on the thread that ran fit:
+    `train/step` inside `train/dispatch`, one of each a step. Nothing
+    lands in the ring."""
+    from jax.profiler import ProfileData
+    epochs, steps = 2, 2 * NB
+    trace.clear()
+    assert not trace.enabled()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _fit("cat", epochs=epochs)
+    finally:
+        jax.profiler.stop_trace()
+    assert trace.events() == []
+    (pb,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    spans = {}          # name -> [(thread, start, end)]
+    for plane in ProfileData.from_file(pb).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.split("/")[0] in ("train", "fit", "compile"):
+                    spans.setdefault(e.name, []).append(
+                        (line.name, e.start_ns, e.start_ns + e.duration_ns))
+    counts = {name: len(evs) for name, evs in spans.items()}
+    # the CPU's throttle is 1: every step but the first waits on the one
+    # before it
+    assert counts == {"fit/stage": 1, "compile/train": 1,
+                      "train/dispatch": steps, "train/step": steps,
+                      "fit/throttle": steps - 1, "fit/epoch_end": epochs,
+                      "fit/drain": 1}
+    assert len({t for evs in spans.values() for t, _, _ in evs}) == 1
+    for (_, a, b), (_, c, d) in zip(sorted(spans["train/dispatch"]),
+                                    sorted(spans["train/step"])):
+        assert a <= c and d <= b

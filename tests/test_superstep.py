@@ -451,11 +451,26 @@ class TestBenchAndProfiling:
         with pytest.raises(ValueError):
             fit_dispatch_floor({1: 1.0})
 
-    def test_superstep_annotation_gating(self):
-        import contextlib
-
-        from dlrm_flexflow_tpu.utils.profiling import superstep_annotation
-        assert isinstance(superstep_annotation(0, 4, enabled=False),
-                          contextlib.nullcontext)
-        with superstep_annotation(3, 4, enabled=True):
-            pass
+    def test_superstep_span_carries_k(self):
+        """One span API (obs.trace.span): the fused dispatch's span says
+        which step it starts at and how many it trains, so a trace reader
+        can divide it; it runs inside `train/dispatch`; the gated
+        StepTraceAnnotation helper is gone."""
+        from dlrm_flexflow_tpu.obs import trace
+        from dlrm_flexflow_tpu.utils import profiling
+        assert not hasattr(profiling, "superstep_annotation")
+        model = _build(4)
+        x, y = _dataset()
+        with trace.override(True):
+            trace.clear()
+            model.fit(x, y, epochs=1, verbose=False)
+            evs = trace.events()
+            trace.clear()
+        fused = [e for e in evs if e["name"] == "train/superstep"]
+        assert [e["args"] for e in fused] == [
+            {"step_num": 0, "superstep": 4}, {"step_num": 4, "superstep": 4}]
+        outer = [e for e in evs if e["name"] == "train/dispatch"]
+        assert len(outer) == len(fused)
+        for o, f in zip(outer, fused):
+            assert o["ts"] <= f["ts"]
+            assert f["ts"] + f["dur"] <= o["ts"] + o["dur"]
