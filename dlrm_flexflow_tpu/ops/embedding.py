@@ -12,7 +12,8 @@ table is pinned whole to one device = table parallelism
 TPU-native redesign:
 - forward lookup is `jnp.take` (XLA gather, MXU-free, HBM-bandwidth bound);
   backward is XLA scatter-add from jax.grad — no atomics needed. A Pallas
-  double-buffered gather kernel lives in ops/pallas/embedding_kernel.py.
+  gather kernel for dim % 128 == 0 (a block of row fetches in flight at
+  once) lives in ops/pallas/embedding_kernel.py.
 - table ("parameter") parallelism: the table's row or width dim is sharded
   over mesh axes. Width (out_dim) sharding keeps the lookup local and
   concat-compatible. Row sharding (for huge tables) does the lookup under a

@@ -6,10 +6,13 @@ plus an AVX2 CPU embedding-bag path (src/ops/embedding_avx2.cc). The TPU has
 no atomics and gathers are HBM-bandwidth bound, so the design here is:
 
 - forward: a Pallas kernel that keeps the table in HBM and streams exactly
-  the needed rows into VMEM with double-buffered async DMA (two row slots,
-  the next row's DMA in flight while the current row is accumulated) — the
-  TPU analog of the AVX2 embedding-bag blocked loads. Indices arrive via
-  scalar prefetch so row addresses are known before the body runs.
+  the needed rows into VMEM with async DMA. Random 512 B reads are
+  latency-bound and cost the scalar core a few instructions each, so a
+  grid step covers a block of a few hundred row fetches (`_gather_block`),
+  starts the NEXT block's fetches before it waits for its own, and sums
+  the bag once a block — the TPU analog of the AVX2 embedding-bag blocked
+  loads. Indices arrive via scalar prefetch so row addresses are known
+  before the body runs.
   Mosaic requires HBM row slices to be exactly one (1, 128) lane tile, so
   a row of width dim = k*128 is streamed as k chunk-DMAs against a
   (rows*k, 128) view of the table; tables whose dim is not a multiple of
@@ -34,12 +37,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# samples per grid step: one float32 sublane tile
-_TILE_B = 8
+# one float32 sublane tile: the granule of a gather block's sample count
+_SUBLANES = 8
 _LANES = 128
-# outstanding row DMAs: random 512 B reads are latency-bound, so keep a
-# deep pipeline of in-flight fetches rather than classic double buffering
-_SLOTS = 8
+# gather-kernel block, in (1, 128) chunk fetches: how many the kernel starts
+# before it waits (two blocks are in flight, _bag_kernel). Swept on the v5e,
+# f32[11739136,128], 89,856 zipf ids, ns a row with the id clamp around the
+# kernel: 64 -> 5.64, 128 -> 4.91, 256 -> 4.81, 512 -> 4.75 (PERF.md, PR 25);
+# 256 is within 1.3% of 512 at half the VMEM and splits small batches finer
+_GATHER_FETCHES = 256
 # scatter-kernel block: the update DMA pipeline drains at each grid-step
 # boundary, so the block size IS the outstanding-write depth; 64 keeps
 # the random-write pipeline full (8 left the update ~3x slower per row
@@ -52,42 +58,75 @@ def supports(dim: int) -> bool:
     return dim % _LANES == 0
 
 
-def _bag_kernel(bag: int, k: int, idx_ref, table_ref, out_ref, row_buf,
-                sems):
-    """One grid step = _TILE_B samples.
+def _gather_block(batch: int, bag: int, k: int) -> int:
+    """Samples a grid step of the gather covers.
 
-    table_ref is the (rows*k, 128) chunk view resident in HBM; row_buf has
-    _SLOTS (1, 128) VMEM slots holding a deep pipeline of in-flight
-    fetches (DMA j+_SLOTS-1 starts before chunk j is consumed).
+    A block is g*bag*k chunk fetches, all in flight at once. The rule keeps
+    that at or under _GATHER_FETCHES (but a block is one sublane tile of
+    samples at the least, so never under 8*bag*k): that bounds each of the
+    kernel's two landing buffers at that many 512 B chunks, one wait at
+    g*16 <= 4096 DMA granules, and the output block at g*k chunks. A block
+    never covers more than the batch rounded up to a sublane tile.
     """
-    tb = out_ref.shape[0]
-    total = tb * bag * k
-    base = pl.program_id(0) * tb * bag
+    g_max = max(_SUBLANES,
+                _GATHER_FETCHES // (bag * k) // _SUBLANES * _SUBLANES)
+    return min(g_max, pl.cdiv(batch, _SUBLANES) * _SUBLANES)
 
-    def dma(j, slot):
-        # j enumerates (sample, chunk, bag) as ((s*k + c)*bag + b); the
-        # chunk of table row idx[s, b] lives at view row idx*k + c
-        s_c, b = j // bag, j % bag
-        s, c = s_c // k, s_c % k
-        view_row = idx_ref[base + s * bag + b] * k + c
-        return pltpu.make_async_copy(
-            table_ref.at[pl.ds(view_row, 1), :], row_buf.at[slot],
-            sems.at[slot])
 
-    depth = min(_SLOTS - 1, total)
-    for j in range(depth):
-        dma(j, j % _SLOTS).start()
-    for s in range(tb):                # static unroll: all bounds small
-        for c in range(k):
-            acc = jnp.zeros((1, _LANES), jnp.float32)
-            for b in range(bag):
-                j = (s * k + c) * bag + b
-                if j + depth < total:
-                    dma(j + depth, (j + depth) % _SLOTS).start()
-                dma(j, j % _SLOTS).wait()
-                acc = acc + row_buf[j % _SLOTS].astype(jnp.float32)
-            out_ref[pl.ds(s, 1), c * _LANES:(c + 1) * _LANES] = \
-                acc.astype(out_ref.dtype)
+def _bag_kernel(bag: int, k: int, idx_ref, table_ref, out_ref, planes, sems):
+    """One grid step = one block of g samples (`_gather_block`).
+
+    table_ref is the (rows*k, 128) chunk view resident in HBM. Chunk c of
+    bag slot b of sample s lands in row s of planes[slot, b*k + c], a
+    (g, 128) tile column (Mosaic takes a one-sublane DMA target only in a
+    buffer one lane tile wide). Step i starts every fetch of block i+1 into
+    the other slot BEFORE it waits for block i, whose fetches step i-1
+    started: the fetch queue never drains between blocks. Then the planes
+    are summed over b in float32 into the output block; for bag == 1 that
+    is a copy, bit for bit.
+    """
+    i, n = pl.program_id(0), pl.num_programs(0)
+    g = out_ref.shape[0]
+
+    def start_block(blk):
+        slot = blk % 2
+
+        def tile(t, carry):
+            # one sublane tile of samples a trip: Mosaic unrolls a fori_loop
+            # fully or not at all, and hundreds of bodies would bloat set-up
+            for u in range(_SUBLANES):
+                s = t * _SUBLANES + u
+                for b in range(bag):
+                    # chunk c of table row idx[s, b] is view row idx*k + c
+                    row = idx_ref[(blk * g + s) * bag + b] * k
+                    for c in range(k):
+                        pltpu.make_async_copy(
+                            table_ref.at[pl.ds(row + c, 1), :],
+                            planes.at[slot, b * k + c, pl.ds(s, 1), :],
+                            sems.at[slot]).start()
+            return carry
+
+        jax.lax.fori_loop(0, g // _SUBLANES, tile, None)
+
+    @pl.when(i == 0)
+    def _():
+        start_block(0)
+
+    @pl.when(i + 1 < n)
+    def _():
+        start_block(i + 1)
+
+    slot = i % 2
+    for p in range(bag * k):
+        # a wait takes the byte count of its target off the semaphore: one
+        # wait for a whole plane stands for the g fetches that filled it
+        plane = planes.at[slot, p]
+        pltpu.make_async_copy(plane, plane, sems.at[slot]).wait()
+    for c in range(k):
+        acc = planes[slot, c].astype(jnp.float32)
+        for b in range(1, bag):
+            acc = acc + planes[slot, b * k + c].astype(jnp.float32)
+        out_ref[:, c * _LANES:(c + 1) * _LANES] = acc.astype(out_ref.dtype)
 
 
 def _pallas_forward(table: jax.Array, indices: jax.Array,
@@ -99,19 +138,24 @@ def _pallas_forward(table: jax.Array, indices: jax.Array,
         raise ValueError(f"pallas embedding_bag needs dim % {_LANES} == 0, "
                          f"got {dim}; use embedding_bag_reference")
     k = dim // _LANES
-    padded = ((batch + _TILE_B - 1) // _TILE_B) * _TILE_B
-    idx_flat = jnp.zeros((padded * bag,), jnp.int32)
-    idx_flat = idx_flat.at[: batch * bag].set(
-        indices.astype(jnp.int32).reshape(-1))
+    g = _gather_block(batch, bag, k)
+    padded = pl.cdiv(batch, g) * g
+    # the kernel runs without Mosaic's per-DMA bounds checks (two of them a
+    # fetch: 10 of the 15 instruction bundles a row, 14.6 against 5.7 ns a
+    # row on the v5e), so an id is clamped into the table here, as XLA's
+    # gather clamps. Slots past the batch fetch row 0: under a block more.
+    idx_flat = jnp.pad(
+        jnp.clip(indices.astype(jnp.int32), 0, rows - 1).reshape(-1),
+        (0, (padded - batch) * bag))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(padded // _TILE_B,),
+        grid=(padded // g,),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((_TILE_B, dim), lambda i, idx: (i, 0)),
+        out_specs=pl.BlockSpec((g, dim), lambda i, idx: (i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((_SLOTS, 1, _LANES), table.dtype),
-            pltpu.SemaphoreType.DMA((_SLOTS,)),
+            pltpu.VMEM((2, bag * k, g, _LANES), table.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     with jax.named_scope("emb_gather"):
@@ -119,6 +163,10 @@ def _pallas_forward(table: jax.Array, indices: jax.Array,
             functools.partial(_bag_kernel, bag, k),
             out_shape=jax.ShapeDtypeStruct((padded, dim), table.dtype),
             grid_spec=grid_spec,
+            # a step waits for fetches the step before it started
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                disable_bounds_checks=True),
             interpret=interpret,
             name="emb_gather",
         )(idx_flat, table.reshape(rows * k, _LANES))

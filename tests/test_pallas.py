@@ -11,6 +11,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from dlrm_flexflow_tpu.ops.pallas import embedding_kernel
 from dlrm_flexflow_tpu.ops.pallas.embedding_kernel import (
     embedding_bag, embedding_bag_reference, stacked_embedding_bag, supports)
 
@@ -30,6 +31,71 @@ class TestEmbeddingBagKernel:
         out = embedding_bag(table, idx, "sum", True)
         ref = embedding_bag_reference(table, idx, "sum")
         np.testing.assert_allclose(out, ref, rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize("dim,batch", [
+        (128, 3), (128, 257), (128, 300), (128, 1000), (256, 300)])
+    def test_bag_of_one_is_a_bit_identical_copy(self, dim, batch):
+        """bag 1 is a copy through the landing buffers: below one sublane
+        tile, one past a block, a partly padded last block, several
+        blocks (so both buffers, and the block started a step ahead), and
+        two chunks a row (two planes)."""
+        table, idx = _mk(500, dim, batch, 1, seed=batch)
+        out = embedding_bag(table, idx, "sum", True)
+        np.testing.assert_array_equal(out, jnp.take(table, idx[:, 0], axis=0))
+
+    def test_bag_spanning_two_blocks(self):
+        batch, bag, dim = 50, 3, 256
+        block = embedding_kernel._gather_block(batch, bag, dim // 128)
+        assert block < batch <= 2 * block
+        table, idx = _mk(200, dim, batch, bag, seed=3)
+        out = embedding_bag(table, idx, "sum", True)
+        ref = embedding_bag_reference(table, idx, "sum")
+        np.testing.assert_allclose(out, ref, rtol=1e-6, atol=1e-6)
+
+    def test_hot_row_fetched_a_block_over(self):
+        """Every id equal: one row is in flight as many times as the block
+        has slots."""
+        table, _ = _mk(64, 128, 1, 1)
+        idx = jnp.full((600, 1), 37, jnp.int32)
+        out = embedding_bag(table, idx, "sum", True)
+        np.testing.assert_array_equal(
+            out, jnp.broadcast_to(table[37], (600, 128)))
+
+    def test_first_and_last_row_in_the_padded_block(self):
+        rows, batch = 400, 300
+        block = embedding_kernel._gather_block(batch, 1, 1)
+        assert batch % block, "the last block must be partly padding"
+        table, idx = _mk(rows, 128, batch, 1, seed=5)
+        idx = idx.at[-2:, 0].set(jnp.asarray([0, rows - 1], jnp.int32))
+        out = embedding_bag(table, idx, "sum", True)
+        np.testing.assert_array_equal(out, jnp.take(table, idx[:, 0], axis=0))
+
+    def test_ids_outside_the_table_are_clamped(self):
+        """The kernel runs without Mosaic's DMA bounds checks, so no id may
+        reach it unclamped: below 0 reads row 0, past the end the last."""
+        table, _ = _mk(40, 128, 1, 1)
+        idx = jnp.asarray([[-5, 3], [40, 3], [2 ** 31 - 1, 3]], jnp.int32)
+        out = embedding_bag(table, idx, "sum", True)
+        ref = jnp.stack([table[0], table[39], table[39]]) + table[3]
+        np.testing.assert_array_equal(out, ref)
+
+    @pytest.mark.parametrize("batch,bag,k", [
+        (89856, 1, 1), (3328, 1, 1), (5, 1, 3), (17, 3, 1), (50, 3, 2),
+        (1000, 4, 1), (64, 40, 2)])
+    def test_gather_block_bound(self, batch, bag, k):
+        """`_gather_block`'s stated rule: a whole number of sublane tiles,
+        no more than the batch rounded up to one, and at most
+        _GATHER_FETCHES chunk fetches a block unless one sublane tile of
+        samples already needs more."""
+        g = embedding_kernel._gather_block(batch, bag, k)
+        whole_batch = -(-batch // 8) * 8
+        assert g >= 8 and g % 8 == 0
+        assert g <= whole_batch
+        assert g * bag * k <= max(embedding_kernel._GATHER_FETCHES,
+                                  8 * bag * k)
+        # the largest such block: one more sublane tile breaks the rule
+        if g < whole_batch:
+            assert (g + 8) * bag * k > embedding_kernel._GATHER_FETCHES
 
     def test_avg_mode(self):
         table, idx = _mk(100, 128, 9, 4)

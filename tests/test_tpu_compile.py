@@ -1,0 +1,59 @@
+"""Compile the main path's Pallas kernels for a described (not attached)
+v5e at real widths: Mosaic refuses what interpret mode lets through (a
+DMA target that is not aligned to the tiling, a loop it cannot unroll,
+too much VMEM). Nothing runs, so these say nothing of results or speed.
+
+All such tests live in this one file, and the topology is described in a
+fixture: only the worker that is given the file loads the TPU's library.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from dlrm_flexflow_tpu.ops.pallas import embedding_kernel
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+# (table rows, dim, batch, bag): one chip's share of the terabyte model at
+# both benchmark batches, then the shapes that take the planes (k > 1,
+# bag > 1) and a batch under one sublane tile
+@pytest.mark.parametrize("rows,dim,batch,bag", [
+    (11739136, 128, 89856, 1), (11739136, 128, 3328, 1),
+    (100000, 256, 1000, 3), (100000, 384, 5, 1), (100000, 128, 300, 4)])
+def test_emb_gather_compiles_for_v5e(one_chip, no_compile_cache,
+                                     rows, dim, batch, bag):
+    table = jax.ShapeDtypeStruct((rows, dim), jnp.float32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((batch, bag), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda t, i: embedding_kernel.embedding_bag(t, i, "sum", False)
+    ).lower(table, idx).compile()
+    assert "tpu_custom_call" in compiled.as_text()
