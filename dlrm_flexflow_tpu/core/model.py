@@ -274,6 +274,44 @@ class FFModel:
         return MultiHeadAttention(self, q, k, v, embed_dim, num_heads,
                                   causal, name).outputs[0]
 
+    def rms_norm(self, input_tensor, eps=1e-6, to_compute_dtype=False,
+                 name=None):
+        from ..ops.norm import RMSNorm
+        return RMSNorm(self, input_tensor, eps, to_compute_dtype,
+                       name).outputs[0]
+
+    def gated_delta_net(self, x, num_k_heads, num_v_heads, head_k_dim,
+                        head_v_dim, conv_width=4, eps=1e-6,
+                        kernel_initializer=None, name=None):
+        """The linear-attention mixer of a hybrid language model (see
+        ops/delta_net.GatedDeltaNet)."""
+        from ..ops.delta_net import GatedDeltaNet
+        return GatedDeltaNet(self, x, num_k_heads, num_v_heads, head_k_dim,
+                             head_v_dim, conv_width, eps,
+                             kernel_initializer, name).outputs[0]
+
+    def gated_attention(self, x, num_heads, num_kv_heads, head_dim,
+                        rotary_dim, rope_theta=1e7, eps=1e-6,
+                        kernel_initializer=None, name=None):
+        """Causal grouped-query self-attention with q/k norm, partial
+        rotary embedding and a sigmoid output gate (see
+        ops/attention.GatedAttention)."""
+        from ..ops.attention import GatedAttention
+        return GatedAttention(self, x, num_heads, num_kv_heads, head_dim,
+                              rotary_dim, rope_theta, eps,
+                              kernel_initializer, name).outputs[0]
+
+    def moe(self, x, num_experts, top_k, expert_dim, shared_dim,
+            experts_held=None, expert_offset=0, norm_topk=True,
+            kernel_initializer=None, name=None):
+        """Sparse experts, the share of them this chip holds (see
+        ops/moe.MoE): the router scores all `num_experts`, the op computes
+        experts `expert_offset .. expert_offset + experts_held - 1`."""
+        from ..ops.moe import MoE
+        return MoE(self, x, num_experts, top_k, expert_dim, shared_dim,
+                   experts_held, expert_offset, norm_topk,
+                   kernel_initializer, name).outputs[0]
+
     def lstm_stack(self, input_tensor, hidden, num_layers, name=None):
         """N stacked LSTM layers in ONE scan (see ops/rnn.LSTMStack:
         pays the serial per-iteration latency once per timestep instead
@@ -846,21 +884,29 @@ class FFModel:
             # the op's name in the compiled step's metadata (autodiff makes
             # it jvp(ff.<name>) / transpose(jvp(ff.<name>)) for the backward)
             scope = jax.named_scope(f"ff.{op.name}")
+            # a block op keeps its inputs for the backward, not its
+            # insides: the backward runs its forward again
+            remat = (jax.checkpoint
+                     if training and getattr(op, "recompute", False)
+                     else lambda f: f)
             if hasattr(op, "apply_with_state"):
                 st = op_state.get(op.name, {})
                 if host:
                     st = jax.tree.map(lambda v: _to_memory(v, "host"), st)
                 with ctx, scope:
-                    outs, st2 = op.apply_with_state(p, st, xs,
-                                                    training=training,
-                                                    rng=rng)
+                    outs, st2 = remat(
+                        lambda p_, st_, xs_, op=op: op.apply_with_state(
+                            p_, st_, xs_, training=training, rng=rng))(
+                                p, st, xs)
                 if host:
                     st2 = jax.tree.map(lambda v: _to_memory(v, "device"),
                                        st2)
                 new_state[op.name] = st2
             else:
                 with ctx, scope:
-                    outs = op.apply(p, xs, training=training, rng=rng)
+                    outs = remat(
+                        lambda p_, xs_, op=op: op.apply(
+                            p_, xs_, training=training, rng=rng))(p, xs)
             if host:
                 outs = [_to_memory(o, "device") for o in outs]
             for t, v in zip(op.outputs, outs):
@@ -1354,6 +1400,13 @@ class FFModel:
             params.update(self._init_params_sharded(big_keys))
         self.params = params
         self.op_state = op_state
+        if any("pairs" in st for st in op_state.values()):
+            # the registry outlives a model: it gets a weak hold on this one
+            import weakref
+            from ..obs import metrics as obsm
+            me = weakref.ref(self)
+            obsm.register_collector(
+                lambda: me()._obs_collect_experts() if me() else ())
         # multi-controller: build optimizer state as one SPMD program so
         # every leaf (incl. fresh scalars like Adam's step) is a global
         # array, never a rank-local committed one
@@ -1364,6 +1417,23 @@ class FFModel:
         self._step_dev = None
         self._msums = None
         return self
+
+    def expert_stats(self) -> Dict[str, Dict[str, np.ndarray]]:
+        """{expert op: {"tokens", "pairs" (experts held,), "rows"}}: the
+        expert ops' cumulative counters (ops/moe.py), read from the op
+        state. The step never reads them back; this call does."""
+        return {name: {k: np.asarray(v) for k, v in st.items()}
+                for name, st in (self.op_state or {}).items()
+                if "pairs" in st}
+
+    def _obs_collect_experts(self):
+        """Registry collector: the expert counters as scrapeable samples."""
+        for name, st in self.expert_stats().items():
+            yield "ff_moe_tokens_total", {"op": name}, int(st["tokens"])
+            yield "ff_moe_rows_total", {"op": name}, int(st["rows"])
+            for e, n in enumerate(st["pairs"]):
+                yield ("ff_moe_pairs_total",
+                       {"op": name, "expert": str(e)}, int(n))
 
     def _init_params_sharded(self, op_keys):
         """Parameters of the LARGE ops (>= _SHARDED_INIT_BYTES), each born

@@ -14,6 +14,13 @@ LSTM grid). This op is the designed-in TPU upgrade: long-context scaling via
 - plain DP (degrees[0]) composes with both.
 
 Self-attention: pass the same tensor as q, k, v.
+
+`GatedAttention` is the block attention of a sparse language model
+(Qwen3-Next): grouped KV heads, a per-head RMS norm on q and k, rotary
+embedding on the leading part of each head, and a sigmoid gate on the
+output. Both ops go through one `attend` core, which picks the route: the
+ring, jax's shipped flash kernel, query blocks through XLA, or the dense
+scores, by whether the scores fit beside what the model keeps resident.
 """
 
 from __future__ import annotations
@@ -51,32 +58,99 @@ def _online_softmax_block(q, k, v, m_prev, num_prev, den_prev, mask):
     return m_new, num, den
 
 
+def _group_heads(q, kv_heads: int):
+    """(b, h, s, hd) -> (b, kv_heads, h // kv_heads, s, hd): the query
+    heads that share one K/V head, side by side."""
+    b, h, s, hd = q.shape
+    return q.reshape(b, kv_heads, h // kv_heads, s, hd)
+
+
 def _attention_local(q, k, v, causal, q_offset=0, k_offset=0):
-    """Dense attention on local blocks (single shard or within-block)."""
+    """Dense attention on local blocks (single shard or within-block).
+    q (b, h, sq, hd); k, v (b, hk, sk, hd) with hk dividing h: each K/V
+    head serves h // hk query heads, without being repeated in memory."""
     b, h, sq, hd = q.shape
-    sk = k.shape[2]
+    hk, sk = k.shape[1], k.shape[2]
     if causal:
         qpos = q_offset + jnp.arange(sq)[:, None]
         kpos = k_offset + jnp.arange(sk)[None, :]
         mask = jnp.where(kpos <= qpos, 0.0, -jnp.inf).astype(jnp.float32)
     else:
         mask = jnp.zeros((sq, sk), jnp.float32)
-    m0 = jnp.full((b, h, sq), -jnp.inf, jnp.float32)
-    num0 = jnp.zeros((b, h, sq, hd), jnp.float32)
-    den0 = jnp.zeros((b, h, sq), jnp.float32)
-    m, num, den = _online_softmax_block(q, k, v, m0, num0, den0, mask)
-    return num / jnp.maximum(den, 1e-20)[..., None]
+    # the G query heads of a K/V head fold into the query axis
+    g = h // hk
+    qg = _group_heads(q, hk).reshape(b, hk, g * sq, hd)
+    mask = jnp.tile(mask, (g, 1))
+    m0 = jnp.full((b, hk, g * sq), -jnp.inf, jnp.float32)
+    num0 = jnp.zeros((b, hk, g * sq, hd), jnp.float32)
+    den0 = jnp.zeros((b, hk, g * sq), jnp.float32)
+    m, num, den = _online_softmax_block(qg, k, v, m0, num0, den0, mask)
+    out = num / jnp.maximum(den, 1e-20)[..., None]
+    return out.reshape(b, h, sq, hd)
+
+
+BLOCK_Q = 1024      # query rows a step of the blockwise route attends with
+
+
+def _attention_blockwise(q, k, v, causal, block_q: int):
+    """The dense route a block of queries at a time (a `lax.scan`, each
+    step recomputed in the backward): the scores alive are block_q x sk a
+    head, never sq x sk. XLA only; what the flash kernel does where it may
+    not run. A causal block still multiplies against every key and masks:
+    half its products are wasted, which the kernel's route avoids."""
+    b, h, sq, hd = q.shape
+    nb = sq // block_q
+    qb = q.reshape(b, h, nb, block_q, hd).transpose(2, 0, 1, 3, 4)
+
+    @jax.checkpoint
+    def one(qi, i):
+        return _attention_local(qi, k, v, causal, q_offset=i * block_q)
+
+    def body(_, xs):
+        qi, i = xs
+        return None, one(qi, i).astype(q.dtype)
+
+    _, out = lax.scan(body, None, (qb, jnp.arange(nb)))
+    return out.transpose(1, 2, 0, 3, 4).reshape(b, h, sq, hd)
+
+
+def _resident_bytes(model) -> float:
+    """What a training step keeps on a chip whatever the activations: the
+    parameters, their gradient and the optimizer's slabs, all fp32."""
+    ndev = max(getattr(getattr(model, "mesh", None), "size", 1), 1)
+    params = sum(op.param_bytes() for op in model.ops)
+    opt = getattr(model, "optimizer", None)
+    slabs = len(opt.sparse_slab_names()) if opt is not None else 0
+    return (2 + slabs) * params / ndev
+
+
+def _hbm_bytes() -> float:
+    stats = jax.local_devices()[0].memory_stats() or {}
+    if stats.get("bytes_limit"):
+        return float(stats["bytes_limit"])
+    from ..search.cost_model import TPUSpec
+    return TPUSpec().hbm_capacity_bytes
+
+
+def _scores_fit(model, q, k) -> bool:
+    """Whether the dense route's fp32 scores fit beside what is resident.
+    The dense forward and backward keep about three score-sized arrays (s,
+    p, dp); a tenth of the memory is left to everything else. Measured on
+    v5e: XLA's fused dense attention is FASTER while the scores fit (377k
+    vs 313k tok/s @ seq 2048), so dense stays the route wherever it can."""
+    b, h, sq, sk = q.shape[0], q.shape[1], q.shape[2], k.shape[2]
+    score_bytes = 4.0 * b * h * sq * sk
+    return _resident_bytes(model) + 3.0 * score_bytes <= 0.9 * _hbm_bytes()
 
 
 def _flash_gate(model, op_name, q, k) -> bool:
     """Route single-chip TPU attention through jax's shipped Pallas
     flash-attention kernel (jax.experimental.pallas.ops.tpu): O(seq)
-    memory instead of the O(seq²) scores _attention_local materializes —
-    seq 8192 @ d1024/h16 OOMs 16 GB of HBM without it. Shares the common
-    Pallas routing policy (TPU backend, opt-in, single chip, not
-    host-offloaded — a Mosaic call can't run under compute_on) and adds
-    the shapes/dtypes validated on hardware (bf16, head_dim %64,
-    seq %512)."""
+    memory instead of the O(seq²) scores _attention_local materializes.
+    Shares the common Pallas routing policy (TPU backend, opt-in, single
+    chip, not host-offloaded — a Mosaic call can't run under compute_on)
+    and adds the shapes/dtypes validated on hardware (bf16, head_dim %64,
+    seq %512). Taken only where the scores do not fit (`_scores_fit`)."""
     from .embedding import _pallas_gate
     if not _pallas_gate(model, op_name, True):
         return False
@@ -84,13 +158,25 @@ def _flash_gate(model, op_name, q, k) -> bool:
     if not (q.dtype == jnp.bfloat16 and hd % 64 == 0
             and sq % 512 == 0 and sk % 512 == 0):
         return False
-    # measured on v5e: XLA's fused dense attention is FASTER while the
-    # fp32 score tensor fits comfortably (377k vs 313k tok/s @ seq 2048);
-    # flash wins only where the scores blow HBM (seq 8192 @ d1024/h16
-    # OOMs dense, runs 108k tok/s with flash). Route by score footprint.
-    b, h = q.shape[0], q.shape[1]
-    score_bytes = 4.0 * b * h * sq * sk
-    return score_bytes > 6e9
+    return not _scores_fit(model, q, k)
+
+
+def attend(model, op_name, q, k, v, causal: bool):
+    """softmax(q k^T / sqrt(hd)) v on one shard: q (b, h, s, hd); k, v
+    (b, hk, s, hd), hk dividing h. The one place both attention ops pick
+    their route. Returns q's dtype."""
+    h, hk, sq = q.shape[1], k.shape[1], q.shape[2]
+    if _flash_gate(model, op_name, q, k):
+        from jax.experimental.pallas.ops.tpu.flash_attention import (
+            flash_attention)
+        if hk != h:     # the kernel wants one K/V head a query head
+            k, v = (jnp.repeat(t, h // hk, axis=1) for t in (k, v))
+        return flash_attention(
+            q, k, v, causal=causal,
+            sm_scale=1.0 / math.sqrt(q.shape[-1])).astype(q.dtype)
+    if not _scores_fit(model, q, k) and sq % BLOCK_Q == 0 and sq > BLOCK_Q:
+        return _attention_blockwise(q, k, v, causal, BLOCK_Q)
+    return _attention_local(q, k, v, causal).astype(q.dtype)
 
 
 def ring_attention(q, k, v, axis_name: str, causal: bool):
@@ -193,14 +279,8 @@ class MultiHeadAttention(Op):
             from ..parallel.mesh import smap
             attn = smap(fn, mesh, in_specs=(spec, spec, spec),
                          out_specs=spec)(q, k, v)
-        elif _flash_gate(self.model, self.name, q, k):
-            from jax.experimental.pallas.ops.tpu.flash_attention import (
-                flash_attention)
-            attn = flash_attention(
-                q, k, v, causal=self.causal,
-                sm_scale=1.0 / math.sqrt(self.head_dim)).astype(q.dtype)
         else:
-            attn = _attention_local(q, k, v, self.causal).astype(q.dtype)
+            attn = attend(self.model, self.name, q, k, v, self.causal)
 
         b, h, s, hd = attn.shape
         merged = attn.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
@@ -252,4 +332,114 @@ class MultiHeadAttention(Op):
         # block-wise softmax rescaling/recomputation, the causal mask
         # discards half the score tiles' work, and small batch*heads
         # grids underfill the chip
+        return 0.25
+
+
+def rotary_tables(seq: int, rotary_dim: int, theta: float):
+    """cos, sin (seq, rotary_dim) fp32 for positions 0..seq-1: the
+    frequencies theta^(-2i/rotary_dim), laid out twice (rotate-half)."""
+    inv = 1.0 / (theta ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32)
+                           / rotary_dim))
+    ang = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = jnp.concatenate([ang, ang], axis=-1)
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def apply_rotary(x, cos, sin):
+    """Rotate-half rotary embedding on the first cos.shape[-1] features of
+    x (b, s, h, hd); the rest pass through."""
+    rd = cos.shape[-1]
+    xr, rest = x[..., :rd], x[..., rd:]
+    x1, x2 = xr[..., :rd // 2], xr[..., rd // 2:]
+    rot = jnp.concatenate([-x2, x1], axis=-1)
+    xr = xr * cos[None, :, None, :] + rot * sin[None, :, None, :]
+    return jnp.concatenate([xr, rest], axis=-1)
+
+
+class GatedAttention(Op):
+    """Causal self-attention as Qwen3-Next's full-attention layers have it:
+    `wq` makes, per query head, a query and a gate (head-major, [query |
+    gate] inside a head); K/V have `num_kv_heads` heads, each serving
+    num_heads / num_kv_heads query heads; q and k take a per-head RMS norm
+    (scale 1 + w) and rotary embedding on their first `rotary_dim`
+    features; the attended values are multiplied by sigmoid(gate) before
+    the output projection. No bias anywhere."""
+
+    type_name = "GatedAttention"
+    recompute = True     # the backward recomputes the block's insides
+
+    def __init__(self, model, x, num_heads: int, num_kv_heads: int,
+                 head_dim: int, rotary_dim: int, rope_theta: float = 1e7,
+                 eps: float = 1e-6, kernel_initializer=None,
+                 name: Optional[str] = None):
+        if x.num_dims != 3:
+            raise ValueError("attention expects (batch, seq, dim) inputs")
+        if num_heads % num_kv_heads != 0:
+            raise ValueError("num_kv_heads must divide num_heads")
+        if rotary_dim % 2 or rotary_dim > head_dim:
+            raise ValueError("rotary_dim must be even and <= head_dim")
+        super().__init__(model, [x], name)
+        self.num_heads = int(num_heads)
+        self.num_kv_heads = int(num_kv_heads)
+        self.head_dim = int(head_dim)
+        self.rotary_dim = int(rotary_dim)
+        self.rope_theta = float(rope_theta)
+        self.eps = float(eps)
+        self.kernel_initializer = kernel_initializer or DEFAULT_KERNEL_INIT()
+        self.outputs = [self._make_output(x.shape, x.dtype)]
+
+    def param_defs(self) -> Dict[str, ParamDef]:
+        d = self.inputs[0].shape[-1]
+        h, hk, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        init = self.kernel_initializer
+        return {
+            "wq": ParamDef((d, h * hd * 2), jnp.float32, init),
+            "wk": ParamDef((d, hk * hd), jnp.float32, init),
+            "wv": ParamDef((d, hk * hd), jnp.float32, init),
+            "q_norm": ParamDef((hd,), jnp.float32, ZeroInitializer()),
+            "k_norm": ParamDef((hd,), jnp.float32, ZeroInitializer()),
+            "wo": ParamDef((h * hd, d), jnp.float32, init),
+        }
+
+    def apply(self, params, xs, *, training=False, rng=None):
+        from .norm import rms_norm
+        (x,) = xs
+        b, s, _ = x.shape
+        h, hk, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        cdt = self.model.compute_dtype
+        xc = x.astype(cdt)
+
+        def proj(w):
+            return jnp.dot(xc, w.astype(cdt),
+                           preferred_element_type=jnp.float32)
+
+        qg = proj(params["wq"]).reshape(b, s, h, 2 * hd)
+        q, gate = qg[..., :hd], qg[..., hd:]
+        k = proj(params["wk"]).reshape(b, s, hk, hd)
+        v = proj(params["wv"]).reshape(b, s, hk, hd).astype(cdt)
+        with jax.named_scope("qk_norm_rope"):
+            cos, sin = rotary_tables(s, self.rotary_dim, self.rope_theta)
+            q = apply_rotary(rms_norm(q, params["q_norm"], self.eps, True),
+                             cos, sin).astype(cdt)
+            k = apply_rotary(rms_norm(k, params["k_norm"], self.eps, True),
+                             cos, sin).astype(cdt)
+        with jax.named_scope("attend"):
+            attn = attend(self.model, self.name, q.transpose(0, 2, 1, 3),
+                          k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+                          True)
+            attn = attn.transpose(0, 2, 1, 3)            # (b, s, h, hd)
+        with jax.named_scope("gate"):
+            gated = (attn.astype(jnp.float32)
+                     * jax.nn.sigmoid(gate)).astype(cdt)
+        out = jnp.dot(gated.reshape(b, s, h * hd), params["wo"].astype(cdt),
+                      preferred_element_type=jnp.float32)
+        return [out.astype(x.dtype)]
+
+    def flops_per_sample(self) -> float:
+        _, s, d = self.outputs[0].shape
+        h, hk, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        proj = 2.0 * s * d * (2 * h * hd + 2 * hk * hd + h * hd)
+        return proj + 2.0 * s * s * h * hd      # the causal half of 4 s^2
+
+    def mxu_utilization_factor(self) -> float:
         return 0.25

@@ -1,0 +1,300 @@
+"""Sparse mixture of experts, as one chip of an expert-parallel group runs it.
+
+The router scores every token against ALL `num_experts` experts (fp32),
+keeps the `top_k` largest probabilities and, with `norm_topk`, divides them
+by their sum. This op holds `experts_held` of the experts, those numbered
+`expert_offset` .. `expert_offset + experts_held - 1`, and computes their
+part of the result:
+
+    routed = sum over e in (top-k and held) of w_e * down_e(silu(gate_e x) * up_e x)
+    out    = routed + sigmoid(x . w_sg) * shared_expert(x)
+
+The weights w_e are normalised over all `top_k` chosen experts, held here
+or not; what the experts held elsewhere would add is theirs to compute (on
+their chips, behind an exchange this op does not have yet), and is left out
+here. With every expert held (`experts_held == num_experts`) the op is the
+whole layer.
+
+Dropless: every (token, held expert) pair is computed, at any imbalance.
+The pairs are sorted by expert, so a held expert's pairs are one stretch of
+rows, and the stretches are walked a chunk of `chunk_rows` rows at a time:
+a chunk gathers its rows' tokens, multiplies them with ITS expert's three
+matrices (plain products, one expert a chunk), scales by the pair weights
+and writes its rows, side by side, into a buffer of all sorted pairs; the
+result is each token's sum over the rows of its held pairs (a gather by the
+inverse of the sort). The walk is a loop whose trip count is the step's own
+number of chunks, so a step with few pairs computes few rows, and the worst
+case (every token picks held experts, `num_experts / experts_held` times
+the expected) is only more trips. Reverse-mode autodiff cannot follow a
+loop of unknown length, so forward and backward are written out
+(`custom_vjp`): the backward walks the same chunks, recomputes each, adds an
+expert's weight gradient into that expert's slab and writes the rows'
+input gradients side by side, to be summed a token by the same gather. No
+scatter anywhere: a chunk's rows past its expert's last pair are computed
+and masked, and where they lie over the next expert's rows the next chunk
+writes over them.
+
+Counters (cumulative, in `op_state`, never read back by the step):
+`tokens` seen, `pairs` routed to each held expert, `rows` the chunks
+computed. rows - sum(pairs) carried no pair.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import Dict, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..core.initializers import DEFAULT_KERNEL_INIT, ZeroInitializer
+from ..core.op import Op, ParamDef
+
+# Rows of one expert's sorted pairs a trip computes. A trip's cost is mostly
+# fixed (on the v5e at Qwen3-Next's widths ~0.6 ms over forward, recomputation
+# and backward, whatever its rows), so the trips set the walk's time. With ~160
+# pairs an expert, 128 / 256 / 512 rows gave a step of 593-608 / 550-578 / 535
+# ms with 9% / 17% / 30% of the rows carrying no pair (chip runs, PR 26); at
+# 512 nearly every expert is one trip whatever the seed's routing, where at
+# 256 the number of two-trip experts moved the step by 3% from seed to seed.
+# At a deployment's 2,560 pairs an expert 512 pads under 10%.
+CHUNK_ROWS = 512
+
+
+def _silu_mul(g, u):
+    return jax.nn.silu(g) * u
+
+
+def _walk_plan(rows, counts):
+    """The walk over the held experts' stretches of sorted pairs, `rows`
+    rows a chunk: (chunks up to and including each expert (held,), each
+    expert's first sorted row (held,), number of chunks). Expert e's
+    stretch takes ceil(counts[e] / rows) chunks."""
+    last = jnp.cumsum((counts + rows - 1) // rows)
+    return last, jnp.cumsum(counts) - counts, last[-1]
+
+
+def _chunk(rows, j, plan, counts, order):
+    """Chunk j of the walk: (its expert, the pair of each of its rows
+    (rows,), its first row, which rows carry a pair of its expert)."""
+    last, start, _ = plan
+    e = jnp.searchsorted(last, j, side="right").astype(jnp.int32)
+    per = (counts[e] + rows - 1) // rows
+    first = start[e] + (j - (last[e] - per)) * rows
+    valid = first + jnp.arange(rows) < start[e] + counts[e]
+    return e, lax.dynamic_slice(order, (first,), (rows,)), first, valid
+
+
+def _expert_ffn(cdt, xs, wg, wu, wd, w_row):
+    """One expert on a chunk's rows: xs (rows, D) in `cdt`; wg, wu (D, F),
+    wd (F, D) fp32; w_row (rows,) -> the rows' weighted outputs, fp32."""
+    def mm(a, w):
+        return jnp.dot(a, w.astype(cdt), preferred_element_type=jnp.float32)
+
+    h = _silu_mul(mm(xs, wg), mm(xs, wu)).astype(cdt)
+    return mm(h, wd) * w_row[:, None]
+
+
+def _sum_pairs(rows_buf, pos, held_pair, top_k):
+    """Each token's sum over the rows of its held pairs, fp32: rows_buf
+    (cap, D) is indexed by sorted position, pos and held_pair (T * k,) by
+    pair. One choice of the k at a time: a gather of T rows and an add."""
+    pos, held = pos.reshape(-1, top_k), held_pair.reshape(-1, top_k)
+    out = 0.0
+    for j in range(top_k):
+        mine = jnp.take(rows_buf, pos[:, j], axis=0).astype(jnp.float32)
+        out = out + jnp.where(held[:, j, None], mine, 0.0)
+    return out
+
+
+def _sorted_position(order, pairs):
+    """Where the sort put each pair: the inverse of `order`'s permutation
+    of the `pairs` pair indices (its padding left out)."""
+    return jnp.argsort(order[:pairs])
+
+
+def _add_slab(acc, e, g):
+    """acc[e] += g on one expert's slab, in place (a dynamic slice, not a
+    scatter, which XLA would run a row at a time)."""
+    at = (e,) + (0,) * g.ndim
+    slab = lax.dynamic_slice(acc, at, (1,) + g.shape)
+    return lax.dynamic_update_slice(acc, slab + g[None], at)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _routed(rows, top_k, cdt, xt, wg, wu, wd, pair_w, order, counts,
+            held_pair):
+    """The held experts' part of the result. xt (T, D); w* (held, ...)
+    fp32; pair_w (T * k,) fp32; order (T * k + rows,) pair indices sorted
+    by held expert, the pairs of experts held elsewhere last, then
+    padding; counts (held,) pairs a held expert; held_pair (T * k,) bool.
+    -> (T, D) fp32."""
+    plan = _walk_plan(rows, counts)
+
+    def trip(j, buf):
+        e, idx, first, _ = _chunk(rows, j, plan, counts, order)
+        with jax.named_scope("dispatch"):
+            xs = jnp.take(xt, idx // top_k, axis=0).astype(cdt)
+        with jax.named_scope("experts"):
+            y = _expert_ffn(cdt, xs, wg[e], wu[e], wd[e],
+                            jnp.take(pair_w, idx))
+        return lax.dynamic_update_slice(buf, y.astype(cdt), (first, 0))
+
+    buf = lax.fori_loop(0, plan[2], trip,
+                        jnp.zeros((order.size, xt.shape[1]), cdt))
+    with jax.named_scope("combine"):
+        return _sum_pairs(buf, _sorted_position(order, held_pair.size),
+                          held_pair, top_k)
+
+
+def _routed_fwd(rows, top_k, cdt, xt, wg, wu, wd, pair_w, order, counts,
+                held_pair):
+    out = _routed(rows, top_k, cdt, xt, wg, wu, wd, pair_w, order, counts,
+                  held_pair)
+    return out, (xt, wg, wu, wd, pair_w, order, counts, held_pair)
+
+
+def _routed_bwd(rows, top_k, cdt, res, ct):
+    xt, wg, wu, wd, pair_w, order, counts, held_pair = res
+    plan = _walk_plan(rows, counts)
+
+    def trip(j, carry):
+        dwg, dwu, dwd, dx_buf, dw_buf = carry
+        e, idx, first, valid = _chunk(rows, j, plan, counts, order)
+        tok = idx // top_k
+        with jax.named_scope("dispatch"):
+            xs = jnp.take(xt, tok, axis=0).astype(cdt)
+            dy = jnp.where(valid[:, None], jnp.take(ct, tok, axis=0), 0.0)
+        with jax.named_scope("experts"):
+            _, vjp = jax.vjp(partial(_expert_ffn, cdt), xs, wg[e], wu[e],
+                             wd[e], jnp.take(pair_w, idx))
+            dxs, dg, du, dd, dw_row = vjp(dy)
+        return (_add_slab(dwg, e, dg), _add_slab(dwu, e, du),
+                _add_slab(dwd, e, dd),
+                lax.dynamic_update_slice(dx_buf, dxs.astype(cdt), (first, 0)),
+                lax.dynamic_update_slice(dw_buf, dw_row, (first,)))
+
+    dwg, dwu, dwd, dx_buf, dw_buf = lax.fori_loop(
+        0, plan[2], trip,
+        (jnp.zeros_like(wg), jnp.zeros_like(wu), jnp.zeros_like(wd),
+         jnp.zeros((order.size, xt.shape[1]), cdt),
+         jnp.zeros((order.size,), jnp.float32)))
+    with jax.named_scope("combine"):
+        pos = _sorted_position(order, held_pair.size)
+        dxt = _sum_pairs(dx_buf, pos, held_pair, top_k).astype(xt.dtype)
+        dpair_w = jnp.where(held_pair, jnp.take(dw_buf, pos), 0.0)
+    return dxt, dwg, dwu, dwd, dpair_w, None, None, None
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
+class MoE(Op):
+    type_name = "MoE"
+    recompute = True     # the backward recomputes the block's insides
+
+    def __init__(self, model, x, num_experts: int, top_k: int,
+                 expert_dim: int, shared_dim: int,
+                 experts_held: Optional[int] = None, expert_offset: int = 0,
+                 norm_topk: bool = True, kernel_initializer=None,
+                 name: Optional[str] = None):
+        held = num_experts if experts_held is None else experts_held
+        if not 0 < top_k <= num_experts:
+            raise ValueError("top_k must lie in 1..num_experts")
+        if not (0 < held and 0 <= expert_offset
+                and expert_offset + held <= num_experts):
+            raise ValueError(
+                f"experts {expert_offset}..{expert_offset + held - 1} are "
+                f"not among the {num_experts} the router scores")
+        super().__init__(model, [x], name)
+        self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.expert_dim, self.shared_dim = int(expert_dim), int(shared_dim)
+        self.experts_held, self.expert_offset = int(held), int(expert_offset)
+        self.norm_topk = bool(norm_topk)
+        self.kernel_initializer = kernel_initializer or DEFAULT_KERNEL_INIT()
+        self.tokens = 1
+        for n in x.shape[:-1]:
+            self.tokens *= int(n)
+        self.chunk_rows = CHUNK_ROWS
+        self.outputs = [self._make_output(x.shape, x.dtype)]
+
+    def param_defs(self) -> Dict[str, ParamDef]:
+        d = self.inputs[0].shape[-1]
+        n, f, fs = self.experts_held, self.expert_dim, self.shared_dim
+        init, f32 = self.kernel_initializer, jnp.float32
+        return {
+            "router": ParamDef((d, self.num_experts), f32, init),
+            "w_gate": ParamDef((n, d, f), f32, init),
+            "w_up": ParamDef((n, d, f), f32, init),
+            "w_down": ParamDef((n, f, d), f32, init),
+            "shared_gate": ParamDef((d, fs), f32, init),
+            "shared_up": ParamDef((d, fs), f32, init),
+            "shared_down": ParamDef((fs, d), f32, init),
+            "shared_router": ParamDef((d,), f32, init),
+        }
+
+    def state_defs(self) -> Dict[str, ParamDef]:
+        zero, i32 = ZeroInitializer(), jnp.int32
+        return {"tokens": ParamDef((), i32, zero),
+                "pairs": ParamDef((self.experts_held,), i32, zero),
+                "rows": ParamDef((), i32, zero)}
+
+    def route(self, params, xt):
+        """(weights (T, k) fp32, experts (T, k) int32) of every token."""
+        logits = jnp.dot(xt.astype(jnp.float32), params["router"],
+                         precision=lax.Precision.HIGHEST)
+        top_p, top_e = lax.top_k(jax.nn.softmax(logits, axis=-1), self.top_k)
+        if self.norm_topk:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        return top_p, top_e
+
+    def apply_with_state(self, params, state, xs, *, training=False,
+                         rng=None):
+        (x,) = xs
+        d = x.shape[-1]
+        xt = x.reshape(-1, d)
+        held, cdt, f32 = self.experts_held, self.model.compute_dtype, \
+            jnp.float32
+        with jax.named_scope("router"):
+            pair_w, pair_e = self.route(params, xt)
+        with jax.named_scope("dispatch"):
+            local = pair_e.reshape(-1) - self.expert_offset
+            # the pairs of experts held elsewhere get the key `held` and
+            # sort behind every held expert's
+            key = jnp.where((local >= 0) & (local < held), local, held)
+            order = jnp.argsort(key).astype(jnp.int32)
+            # a chunk reads whole: the last one may read into the padding
+            order = jnp.pad(order, (0, self.chunk_rows))
+            counts = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+        routed = _routed(self.chunk_rows, self.top_k, cdt, xt,
+                         params["w_gate"], params["w_up"], params["w_down"],
+                         pair_w.reshape(-1), order, counts, key < held)
+        with jax.named_scope("shared"):
+            xc = xt.astype(cdt)
+
+            def mm(a, w):
+                return jnp.dot(a, w.astype(cdt), preferred_element_type=f32)
+
+            h = _silu_mul(mm(xc, params["shared_gate"]),
+                          mm(xc, params["shared_up"])).astype(cdt)
+            gate = jax.nn.sigmoid(jnp.sum(
+                xt.astype(f32) * params["shared_router"], axis=-1,
+                keepdims=True))
+            shared = gate * mm(h, params["shared_down"])
+        new_state = {"tokens": state["tokens"] + xt.shape[0],
+                     "pairs": state["pairs"] + counts,
+                     "rows": state["rows"] + self.chunk_rows * _walk_plan(
+                         self.chunk_rows, counts)[2]}
+        return [(routed + shared).reshape(x.shape).astype(x.dtype)], new_state
+
+    def apply(self, params, xs, *, training=False, rng=None):
+        raise RuntimeError("MoE uses apply_with_state")
+
+    def flops_per_sample(self) -> float:
+        tokens = self.tokens / max(self.outputs[0].shape[0], 1)
+        d = self.outputs[0].shape[-1]
+        pairs = self.top_k * self.experts_held / self.num_experts
+        return tokens * (2.0 * d * self.num_experts
+                         + 6.0 * d * (pairs * self.expert_dim
+                                      + self.shared_dim))

@@ -66,9 +66,9 @@ def _opcodes(hlo_text):
     return out
 
 
-@pytest.mark.parametrize("kind", sorted(MODELS))
-def test_every_instruction_of_the_step_has_a_path(kind):
-    model = _fit(kind)
+def _census():
+    """{instruction: path} of the newest step program, after the check
+    that every instruction doing an op's work lies under an `ff.` scope."""
     scopes = trace.program_scopes()["jit_train_step"]
     opcodes = _opcodes(_step_text())
     assert set(scopes) == set(opcodes) and len(scopes) > 100
@@ -76,9 +76,17 @@ def test_every_instruction_of_the_step_has_a_path(kind):
         name: path for name, path in scopes.items()
         if "ff." not in path and opcodes[name] not in PLUMBING
         # a reduction's or a scatter's combiner: its own tiny computation,
-        # named after the primitive, never an event of the trace
-        and "/" in path}
+        # named after the primitive (inside a recomputed block:
+        # `checkpoint/<primitive>`), never an event of the trace
+        and "/" in path.removeprefix("checkpoint/")}
     assert set(unscoped.values()) <= {STEP_COUNTER}, unscoped
+    return scopes
+
+
+@pytest.mark.parametrize("kind", sorted(MODELS))
+def test_every_instruction_of_the_step_has_a_path(kind):
+    model = _fit(kind)
+    scopes = _census()
     # every op of the graph, its update and the step's bookkeeping are there
     found = {m for p in scopes.values()
              for m in re.findall(r"ff\.[\w.]+", p)}
@@ -95,6 +103,49 @@ def test_every_instruction_of_the_step_has_a_path(kind):
     sub = {"cat": "gather", "dot": "index"}[kind]
     assert any(re.search(rf"ff\.{emb}/(vmap\()?{sub}\)?/", p)
                for p in paths), sorted(p for p in paths if emb in p)
+
+
+def test_every_instruction_of_the_qwen3_next_step_has_a_path():
+    """The census on the language model (ISSUE 26): block ops recomputed
+    in the backward, the expert walk's loops, Adam; and the scopes inside
+    the three block ops."""
+    from dlrm_flexflow_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                                     build_qwen3_next)
+    cfg = Qwen3NextConfig(
+        vocab_size=64, hidden_size=32, num_hidden_layers=4,
+        linear_num_key_heads=1, linear_num_value_heads=2,
+        linear_key_head_dim=8, linear_value_head_dim=8,
+        num_attention_heads=2, num_key_value_heads=1, head_dim=16,
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=16,
+        shared_expert_intermediate_size=16, experts_held=4)
+    model = ff.FFModel(ff.FFConfig(batch_size=2, seed=2))
+    build_qwen3_next(model, cfg, 32)
+    model.compile(ff.AdamOptimizer(alpha=1e-3),
+                  "sparse_categorical_crossentropy",
+                  ["sparse_categorical_crossentropy"],
+                  mesh=make_mesh(devices=jax.devices()[:1]))
+    model.init_layers()
+    tokens = (jax.numpy.arange(4 * 33).reshape(4, 33) % 64).astype("int32")
+    model.fit({"tokens": tokens[:, :-1]}, tokens[:, 1:], epochs=1,
+              verbose=False)
+    paths = set(_census().values())
+    found = {m for p in paths for m in re.findall(r"ff\.[\w.]+", p)}
+    wanted = {f"ff.{op.name}" for op in model.ops
+              if type(op).__name__ not in ("InputOp", "Reshape")}
+    wanted |= {"ff.update.embed", "ff.optimizer", "ff.loss", "ff.metrics"}
+    assert wanted <= found, wanted - found
+    inside = {"l0_moe": ["router", "dispatch", "experts", "combine",
+                         "shared"],
+              "l0_delta": ["proj", "conv", "scan", "gate_norm"],
+              "l3_attn": ["qk_norm_rope", "attend", "gate"]}
+    for op, subs in inside.items():
+        for sub in subs:
+            assert any(re.search(rf"ff\.{op}\b.*/{sub}\b", p)
+                       for p in paths), (op, sub)
+        # forward, and the backward through the recomputed block
+        assert any(f"transpose(jvp(ff.{op}))" in p for p in paths), op
+        assert any("checkpoint" in p or "remat" in p
+                   for p in paths if f"ff.{op}" in p), op
 
 
 def test_scopes_are_metadata_only(monkeypatch):

@@ -57,3 +57,45 @@ def test_emb_gather_compiles_for_v5e(one_chip, no_compile_cache,
         lambda t, i: embedding_kernel.embedding_bag(t, i, "sum", False)
     ).lower(table, idx).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_expert_walk_compiles_for_v5e(one_chip, no_compile_cache):
+    """The expert op's walk over its sorted pairs at Qwen3-Next's widths,
+    forward and backward: loops with no static trip count, and a chunk's
+    rows, not the worst case's 81,920, set the size of the products."""
+    from dlrm_flexflow_tpu.ops import moe
+    T, D, F, held, k = 8192, 2048, 512, 32, 10
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(xt, wg, wu, wd, pair_w, order, counts, held_pair):
+        return jnp.sum(moe._routed(moe.CHUNK_ROWS, k, jnp.bfloat16, xt, wg,
+                                   wu, wd, pair_w, order, counts, held_pair))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sds((T, D)), sds((held, D, F)), sds((held, D, F)),
+        sds((held, F, D)), sds((T * k,)),
+        sds((T * k + moe.CHUNK_ROWS,), jnp.int32), sds((held,), jnp.int32),
+        sds((T * k,), jnp.bool_)).compile()
+    assert "while" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
+def test_chunked_delta_rule_compiles_for_v5e(one_chip, no_compile_cache):
+    """The gated delta rule's chunked scan at Qwen3-Next's head sizes, two
+    spans of the sequence, forward and backward."""
+    from dlrm_flexflow_tpu.ops import delta_net
+    b, s, h, d = 1, 2 * delta_net.SPAN, 32, 128
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(delta_net.gated_delta_rule_chunked(
+            q, k, v, g, beta, compute_dtype=jnp.bfloat16))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sds((b, s, h, d)), sds((b, s, h, d)), sds((b, s, h, d)),
+        sds((b, s, h), jnp.float32), sds((b, s, h), jnp.float32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
