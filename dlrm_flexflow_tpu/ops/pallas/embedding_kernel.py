@@ -20,6 +20,14 @@ no atomics and gathers are HBM-bandwidth bound, so the design here is:
 - backward: no atomics — sort the flat indices and segment-sum the incoming
   gradients (indices_are_sorted lets XLA lower it as a linear pass), which
   replaces the reference's atomicAdd scatter.
+- sparse update: the same sort + segment-sum leaves DISTINCT target rows,
+  valid ones first, and one kernel (`_scatter_kernel`) applies them in
+  blocks of a few hundred (`_scatter_block`), as a read-modify-write
+  (`emb_scatter_add`) or, where the caller kept the forward's rows, as pure
+  writes (`emb_scatter_write`): the reads of the next block and the writes
+  of the last are in flight while a block is added, one vector add a block.
+  It runs without Mosaic's bounds checks: the count of valid targets,
+  computed in XLA, is what keeps every DMA inside the table.
 
 `embedding_bag` is a custom_vjp function usable both standalone and from
 ops/embedding.py. On non-TPU backends pass interpret=True (tests do) or use
@@ -46,11 +54,25 @@ _LANES = 128
 # kernel: 64 -> 5.64, 128 -> 4.91, 256 -> 4.81, 512 -> 4.75 (PERF.md, PR 25);
 # 256 is within 1.3% of 512 at half the VMEM and splits small batches finer
 _GATHER_FETCHES = 256
-# scatter-kernel block: the update DMA pipeline drains at each grid-step
-# boundary, so the block size IS the outstanding-write depth; 64 keeps
-# the random-write pipeline full (8 left the update ~3x slower per row
-# than the gather, r5 calibration) at a modest 32 KB VMEM cost
-_SCATTER_B = 64
+# scatter-kernel block, in (1, 128) tile updates a grid step (`_scatter_block`).
+# Swept on the v5e with three landing buffers, kernel time in us (PERF.md,
+# PR 27), 128 / 256 / 512 / 1024:
+#   read-modify-write, f32[11739136,128], 89,856 slots
+#     all valid:                            797 / 750 / 716 / 710
+#     zipf 1.05 (18,141 valid, the rest pads): 256 / 204 / 173 / 166
+#     3,328 slots, zipf (1,441 valid):      16.6 / 16.1 / 17.7 / 17.2
+#   write-only, f32[4000000,128], 65,536 uniform slots: 313 / 281 / 271 / 266
+# (8.3 and 4.3 ns a valid row at 256; the parent's blocks of 64: 3825, 2969,
+# 121 and 957 us). 256 is within 5% of 512 on full blocks, divides the
+# benchmark's three slot counts, so the dedup pads none of them (a pad
+# copies the (m, 128) updates: more than 512 would win back), and splits
+# small calls finer. What 512 gains under zipf is grid steps past the count
+# (0.19 us each, 280 of 351 there)
+_SCATTER_ROWS = 256
+# landing buffers a scatter rotates through: with three, block i+1's reads
+# and block i-1's writes are both in flight while block i is added; two
+# (the writes waited before the next reads start) cost 6% and 13% more
+_SCATTER_SLOTS = 3
 
 
 def supports(dim: int) -> bool:
@@ -230,47 +252,201 @@ def scatter_supports(dim: int) -> bool:
     return dim % _LANES == 0 or _LANES % dim == 0
 
 
-def _scatter_unique_kernel(idx_ref, upd_ref, tbl_ref, out_ref, bufs,
-                           rsems, wsems):
-    """One grid step applies _SCATTER_B tile updates, pipelined.
+def _scatter_block(m: int) -> int:
+    """Tile updates a grid step of the scatter kernels covers: _SCATTER_ROWS,
+    or all m of a smaller call, rounded up to a sublane tile. That bounds
+    each landing buffer at that many 512 B tiles and a call's padding at
+    under one block."""
+    return min(_SCATTER_ROWS, pl.cdiv(m, _SUBLANES) * _SUBLANES)
 
-    PRECONDITION (established by scatter_add_rows' dedup pre-pass): all
-    view-row targets with row >= 0 are DISTINCT, so the _SCATTER_B (64)
-    RMWs of a block are independent: issue all reads, then add+write-back,
-    then drain. row < 0 marks a padding slot and is skipped. The reference
-    needed atomicAdd for this (embedding.cu:173-224); here distinctness
-    replaces atomicity.
+
+def _scatter_kernel(rmw: bool, idx_ref, cnt_ref, upd_ref, tbl_ref, out_ref,
+                    bufs, rsems, wsems):
+    """One grid step = one block of b tile updates (`_scatter_block`), both
+    forms: read-modify-write (out[row] += upd) and write-only
+    (out[row] = upd; the same pipeline without the read stage and the add).
+
+    PRECONDITION (established by _dedup_tile_updates and _valid_prefix):
+    the first cnt_ref[0] targets are DISTINCT rows of the view, so every
+    row's read-modify-write is independent of every other's, in its block
+    and across blocks. The reference needed atomicAdd for this
+    (embedding.cu:173-224); here distinctness replaces atomicity. Slots at
+    and past the count are never read: the kernel runs without Mosaic's
+    per-DMA bounds checks, and the count is what keeps it inside the view.
+
+    Row s of block i lives in row s of bufs[i % slots]. Step i waits for
+    the writes of the block whose buffer block i+1 takes (i-2 with three
+    buffers: they had a whole step), starts the reads of block i+1 into
+    it, and only then waits for its own reads, which step i-1 started:
+    neither DMA queue drains between blocks. It adds the update block in
+    one vector operation, starts its writes from the same buffer and
+    leaves them in flight; the last step drains. A block under the count
+    runs with no branch a row and one wait by byte count; the one block
+    the count ends in waits a row at a time; a block past it starts
+    nothing.
     """
-    i = pl.program_id(0)
+    i, n = pl.program_id(0), pl.num_programs(0)
+    slots, b = bufs.shape[0], bufs.shape[1]
 
-    def rd(s, row):
-        return pltpu.make_async_copy(
-            out_ref.at[pl.ds(row, 1), :], bufs.at[s], rsems.at[s])
+    def live(blk):
+        return jnp.clip(cnt_ref[0] - blk * b, 0, b)
 
-    def wr(s, row):
-        return pltpu.make_async_copy(
-            bufs.at[s], out_ref.at[pl.ds(row, 1), :], wsems.at[s])
+    def for_rows(blk, body):
+        """body(s, row) for the live rows of block blk: a sublane tile of
+        rows a trip (Mosaic unrolls a fori_loop fully or not at all), then
+        the rows of a last partial tile one a trip."""
+        c = live(blk)
 
-    for s in range(_SCATTER_B):            # static unroll: issue all reads
-        row = idx_ref[i * _SCATTER_B + s]
+        def one(s, carry):
+            body(s, idx_ref[blk * b + s])
 
-        @pl.when(row >= 0)
+        def tile(t, carry):
+            for u in range(_SUBLANES):
+                one(t * _SUBLANES + u, carry)
+
+        tiles = c // _SUBLANES
+        jax.lax.fori_loop(0, tiles, tile, None)
+        jax.lax.fori_loop(tiles * _SUBLANES, c, one, None)
+
+    def start_reads(blk):
+        slot = blk % slots
+        for_rows(blk, lambda s, row: pltpu.make_async_copy(
+            out_ref.at[pl.ds(row, 1), :], bufs.at[slot, pl.ds(s, 1), :],
+            rsems.at[slot]).start())
+
+    def start_writes(blk):
+        slot = blk % slots
+        for_rows(blk, lambda s, row: pltpu.make_async_copy(
+            bufs.at[slot, pl.ds(s, 1), :], out_ref.at[pl.ds(row, 1), :],
+            wsems.at[slot]).start())
+
+    def wait_rows(blk, sems):
+        """Take block blk's live rows off its semaphore: a wait takes the
+        byte count of its target, so one stands for a whole block."""
+        slot, c = blk % slots, live(blk)
+
+        @pl.when(c == b)
         def _():
-            rd(s, row).start()
-    for s in range(_SCATTER_B):            # add + async write-back
-        row = idx_ref[i * _SCATTER_B + s]
+            whole = bufs.at[slot]
+            pltpu.make_async_copy(whole, whole, sems.at[slot]).wait()
 
-        @pl.when(row >= 0)
+        @pl.when(c < b)
         def _():
-            rd(s, row).wait()
-            bufs[s] = (bufs[s] + upd_ref[pl.ds(s, 1), :]).astype(bufs.dtype)
-            wr(s, row).start()
-    for s in range(_SCATTER_B):            # drain before the next block
-        row = idx_ref[i * _SCATTER_B + s]
+            row = bufs.at[slot, pl.ds(0, 1), :]
 
-        @pl.when(row >= 0)
+            def wait_one(s, carry):
+                pltpu.make_async_copy(row, row, sems.at[slot]).wait()
+
+            jax.lax.fori_loop(0, c, wait_one, None)
+
+    @pl.when(i + 1 >= slots)
+    def _():
+        wait_rows(i + 1 - slots, wsems)
+
+    slot = i % slots
+    if rmw:
+        @pl.when(i == 0)
         def _():
-            wr(s, row).wait()
+            start_reads(0)
+
+        @pl.when(i + 1 < n)
+        def _():
+            start_reads(i + 1)
+
+        wait_rows(i, rsems)
+
+    @pl.when(live(i) > 0)
+    def _():
+        upd = upd_ref[...]
+        bufs[slot] = bufs[slot] + upd if rmw else upd
+
+    start_writes(i)
+
+    @pl.when(i + 1 == n)
+    def _():
+        for back in range(slots - 2, 0, -1):
+            @pl.when(i >= back)
+            def _():
+                wait_rows(i - back, wsems)
+
+        wait_rows(i, wsems)
+
+
+def _valid_prefix(target: jax.Array, view_rows: int) -> jax.Array:
+    """(1,) int32: how many leading targets are rows of the view. It is all
+    the kernel sees of the rest, so a pad (-1) or an id outside the view is
+    dropped wherever it stands, and every slot after it."""
+    m = target.shape[0]
+    with jax.named_scope("scatter"):
+        inside = (target >= 0) & (target < view_rows)
+        return jnp.min(jnp.where(inside, m, jnp.arange(m, dtype=jnp.int32)),
+                       keepdims=True).astype(jnp.int32)
+
+
+def _check_prefix(view_rows, target, count):
+    """Interpret mode only: no row of the view may stand behind the count,
+    where `_valid_prefix` has dropped it with everything after the first
+    pad or outside id."""
+    late = (target[int(count[0]):] >= 0) & (target[int(count[0]):] < view_rows)
+    if late.any():
+        raise ValueError(
+            f"{int(late.sum())} scatter targets inside the view stand behind "
+            f"the first invalid one (slot {int(count[0])}) and would be "
+            "dropped; order them first, as _dedup_tile_updates does")
+
+
+def _scatter_call(view, target, interpret):
+    """What the two scatters' pallas_calls share: the count of valid
+    targets, and every argument but the kernel and its name. `target` is
+    a `_scatter_block` multiple long."""
+    m = target.shape[0]
+    b = _scatter_block(m)
+    if m % b:
+        raise ValueError(f"{m} scatter targets are not a multiple of the "
+                         f"block of {b}; pad them as _dedup_tile_updates does")
+    count = _valid_prefix(target, view.shape[0])
+    if interpret:
+        # the chip runs unchecked; the interpreter is where a caller that
+        # breaks the prefix contract is told, not silently short of updates
+        jax.debug.callback(functools.partial(_check_prefix, view.shape[0]),
+                           target, count)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(m // b,),
+        in_specs=[
+            # a block past the count is never read: name the last live one
+            # again, and the pipeline fetches nothing
+            pl.BlockSpec((b, _LANES), lambda i, idx, cnt: (
+                jnp.minimum(i, jnp.maximum(cnt[0] - 1, 0) // b), 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((_SCATTER_SLOTS, b, _LANES), view.dtype),
+            pltpu.SemaphoreType.DMA((_SCATTER_SLOTS,)),
+            pltpu.SemaphoreType.DMA((_SCATTER_SLOTS,)),
+        ],
+    )
+    return count, dict(
+        out_shape=jax.ShapeDtypeStruct(view.shape, view.dtype),
+        grid_spec=grid_spec,
+        input_output_aliases={3: 0},
+        # a step waits for DMAs that earlier steps started
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            disable_bounds_checks=True),
+        interpret=interpret)
+
+
+def _scatter_add_tiles(view, target, tiles, interpret=False):
+    """view[target] += tiles for the distinct valid targets (the
+    read-modify-write twin of `scatter_write_tiles`, same preconditions)."""
+    count, shared = _scatter_call(view, target, interpret)
+    with jax.named_scope("emb_scatter_add"):
+        return pl.pallas_call(
+            functools.partial(_scatter_kernel, True),
+            name="emb_scatter_add", **shared,
+        )(target, count, tiles.astype(view.dtype), view)
 
 
 def scatter_add_rows(table: jax.Array, indices: jax.Array,
@@ -376,14 +552,21 @@ def _pack_tile_updates(indices, updates, dim, dtype):
 
 def _dedup_tile_updates(tile_rows, tile_upds):
     """Combine same-tile updates so a scatter kernel sees DISTINCT rows:
-    sort → segment-sum → per-segment target row (-1 marks invalid/pad
-    slots) → pad to a _SCATTER_B multiple. Returns
+    sort → segment-sum → per-segment target row → pad to a
+    `_scatter_block` multiple. Returns
     (target (m,), summed (m, 128), rep (m,), m) where rep[s] is one
     original position whose update landed in segment s (for callers that
-    need a representative forward tile)."""
+    need a representative forward tile).
+
+    The sort reads a row id as unsigned, so ids a view can hold come
+    first, ascending as a signed sort puts them, and an id below 0 sorts
+    behind every one of them: the valid targets are a prefix of `target`,
+    each once. Behind them stand ids outside the view, which only the
+    scatter knows (`_valid_prefix` drops them), and then the pads (-1)."""
     with jax.named_scope("dedup"):
         m = tile_rows.shape[0]
-        order = jnp.argsort(tile_rows)
+        order = jnp.argsort(
+            jax.lax.bitcast_convert_type(tile_rows, jnp.uint32))
         srows = tile_rows[order]
         supds = tile_upds[order]
         first = jnp.concatenate([jnp.ones((1,), jnp.bool_),
@@ -403,7 +586,7 @@ def _dedup_tile_updates(tile_rows, tile_upds):
         # target=-1 and are skipped by the kernels regardless)
         rep = jnp.where(valid, rep, 0)
 
-        pad_n = (-m) % _SCATTER_B
+        pad_n = (-m) % _scatter_block(m)
         if pad_n:
             target = jnp.pad(target, (0, pad_n), constant_values=-1)
             summed = jnp.pad(summed, ((0, pad_n), (0, 0)))
@@ -413,58 +596,8 @@ def _dedup_tile_updates(tile_rows, tile_upds):
 
 
 def _dedup_and_scatter(view, tile_rows, tile_upds, interpret):
-    target, summed, _, m = _dedup_tile_updates(tile_rows, tile_upds)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(m // _SCATTER_B,),
-        in_specs=[
-            pl.BlockSpec((_SCATTER_B, _LANES), lambda i, idx: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((_SCATTER_B, 1, _LANES), view.dtype),
-            pltpu.SemaphoreType.DMA((_SCATTER_B,)),
-            pltpu.SemaphoreType.DMA((_SCATTER_B,)),
-        ],
-    )
-    with jax.named_scope("emb_scatter_add"):
-        return pl.pallas_call(
-            _scatter_unique_kernel,
-            out_shape=jax.ShapeDtypeStruct(view.shape, view.dtype),
-            grid_spec=grid_spec,
-            input_output_aliases={2: 0},
-            interpret=interpret,
-            name="emb_scatter_add",
-        )(target, summed.astype(view.dtype), view)
-
-
-def _scatter_write_kernel(idx_ref, val_ref, tbl_ref, out_ref, wsems):
-    """Write-ONLY scatter: out[row] = val for _SCATTER_B distinct rows per
-    grid step (row < 0 skipped). No read DMA: callers that kept the
-    forward-gathered tiles compute new = fwd_tile + summed_update in XLA
-    and this kernel just lands the rows — half the random-HBM traffic of
-    the RMW form (the update side of the reference's atomicAdd backward,
-    embedding.cu:173-224, with distinctness + precomputed values replacing
-    atomicity)."""
-    i = pl.program_id(0)
-    for s in range(_SCATTER_B):            # static unroll: issue all writes
-        row = idx_ref[i * _SCATTER_B + s]
-
-        @pl.when(row >= 0)
-        def _():
-            pltpu.make_async_copy(
-                val_ref.at[pl.ds(s, 1), :], out_ref.at[pl.ds(row, 1), :],
-                wsems.at[s]).start()
-    for s in range(_SCATTER_B):            # drain before the next block
-        row = idx_ref[i * _SCATTER_B + s]
-
-        @pl.when(row >= 0)
-        def _():
-            pltpu.make_async_copy(
-                val_ref.at[pl.ds(s, 1), :], out_ref.at[pl.ds(row, 1), :],
-                wsems.at[s]).wait()
+    target, summed, _, _ = _dedup_tile_updates(tile_rows, tile_upds)
+    return _scatter_add_tiles(view, target, summed, interpret)
 
 
 def scatter_write_rows_packed(view: jax.Array, indices: jax.Array,
@@ -499,37 +632,24 @@ def scatter_write_tiles(view: jax.Array, target: jax.Array,
     """Pure-write scatter of whole (1, 128) tiles at DISTINCT view rows.
 
     PRECONDITIONS (the caller establishes them, e.g. via
-    _dedup_tile_updates): targets are distinct; target < 0 marks a pad
-    slot to skip; len(target) is a _SCATTER_B multiple. Used by the write-
-    only sparse-SGD update and by the stateful (momentum/Adam) sparse
-    update, which writes the new weight AND state tiles this way.
+    _dedup_tile_updates): the targets that are rows of the view are
+    distinct and stand first; behind them every slot is dropped, a pad
+    (-1) or an id outside the view alike (interpret mode refuses a row of
+    the view standing there, `_check_prefix`; the chip checks nothing);
+    len(target) is a `_scatter_block` multiple. Used by the write-only sparse-SGD update
+    and by the stateful (momentum/Adam) sparse update, which writes the
+    new weight AND state tiles this way.
 
     view   : (vrows, 128) (donated/aliased)
-    target : (m,) int32, m % _SCATTER_B == 0
+    target : (m,) int32, m % _scatter_block(m) == 0
     vals   : (m, 128) new tile values
     """
-    m = target.shape[0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(m // _SCATTER_B,),
-        in_specs=[
-            pl.BlockSpec((_SCATTER_B, _LANES), lambda i, idx: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[
-            pltpu.SemaphoreType.DMA((_SCATTER_B,)),
-        ],
-    )
+    count, shared = _scatter_call(view, target, interpret)
     with jax.named_scope("emb_scatter_write"):
         return pl.pallas_call(
-            _scatter_write_kernel,
-            out_shape=jax.ShapeDtypeStruct(view.shape, view.dtype),
-            grid_spec=grid_spec,
-            input_output_aliases={2: 0},
-            interpret=interpret,
-            name="emb_scatter_write",
-        )(target, vals.astype(view.dtype), view)
+            functools.partial(_scatter_kernel, False),
+            name="emb_scatter_write", **shared,
+        )(target, count, vals.astype(view.dtype), view)
 
 
 def sharded_scatter_add_packed(mesh, row_axes, view, indices, updates,

@@ -109,10 +109,13 @@ class TPUSpec:
     hbm_random_row_s: float = 1.2e-8
     # random-row SCATTER (the touched-rows update): per-raw-lookup cost
     # of the whole update machinery — lane pack + dedup sort + the
-    # 64-deep write-DMA scatter (_SCATTER_B) — measured r5 on kaggle
-    # (26k lookups, 2.7 ms step) and dlrm_random; ~2x the pipelined
-    # gather rate because the sort/pack passes ride along, not because
-    # the writes themselves are slow
+    # scatter kernel — measured r5 on kaggle (26k lookups, 2.7 ms step)
+    # and dlrm_random, when the kernel drained its write DMAs every 64
+    # rows; ~2x the pipelined gather rate because the sort/pack passes
+    # ride along, not because the writes themselves are slow. Since
+    # PR 27 the kernel pipelines blocks of 256 (`_scatter_block`) at
+    # 4-8 ns a valid row; the value stays until `sim_step_ratio`
+    # (ROADMAP R0.7) judges it against a cell
     hbm_scatter_row_s: float = 2.6e-8
     # per-TRAIN-STEP overhead (dispatch + epilogue) at steady pipelined
     # state. Round-5 value, not re-measured on the attached chip
@@ -479,8 +482,8 @@ class CostModel:
 
     def scatter_rows_time(self, rows: float) -> float:
         """Touched-rows UPDATE scatter: same fixed setup, slower per-row
-        sustained rate (write DMAs drain every 64-tile block — the
-        Pallas kernels' _SCATTER_B)."""
+        sustained rate (the dedup's sort and segment passes ride along
+        with the Pallas kernel's pipelined blocks, `_scatter_block`)."""
         if rows <= 0:
             return 0.0
         return (self.spec.hbm_random_fixed_s

@@ -158,6 +158,62 @@ class TestEmbeddingBagKernel:
                                    rtol=1e-5, atol=1e-5)
 
 
+# every form of the two scatter kernels: (entry point, row width). "add" is
+# scatter_add_rows (d=256: k = 2 chunks a row), "add_packed" and
+# "write_packed" take the lane-packed (rows * d / 128, 128) view
+SCATTER_FORMS = [("add", 128), ("add", 256), ("add_packed", 64),
+                 ("add_packed", 16), ("write_packed", 64),
+                 ("write_packed", 16)]
+
+
+def _scatter_form(form, logical, idx, upd):
+    """One scatter through the kernel (interpret mode) -> (rows, d)."""
+    kind, d = form
+    rows = logical.shape[0]
+    if kind == "add":
+        return np.asarray(embedding_kernel.scatter_add_rows(
+            jnp.asarray(logical), jnp.asarray(idx), jnp.asarray(upd),
+            interpret=True))
+    r = 128 // d
+    view = logical.reshape(rows // r, 128)
+    if kind == "add_packed":
+        got = embedding_kernel.scatter_add_rows_packed(
+            jnp.asarray(view), jnp.asarray(idx), jnp.asarray(upd), d,
+            interpret=True)
+    else:
+        # the forward's tiles; a lookup outside the table read none
+        fwd_tiles = view[np.clip(idx // r, 0, rows // r - 1)]
+        got = jax.jit(
+            lambda v, i, u, t: embedding_kernel.scatter_write_rows_packed(
+                v, i, u, t, d, interpret=True))(
+                    jnp.asarray(view), jnp.asarray(idx), jnp.asarray(upd),
+                    jnp.asarray(fwd_tiles))
+    return np.asarray(got).reshape(rows, d)
+
+
+def _scatter_tiles(rmw):
+    """The kernel alone on deduped tiles: read-modify-write or write-only."""
+    return (embedding_kernel._scatter_add_tiles if rmw
+            else embedding_kernel.scatter_write_tiles)
+
+
+def _check_scatter_form(form, rows, idx, seed=0):
+    """Against numpy's add.at over the ids inside the table: an id below 0
+    or at or past `rows` is dropped, as `.at[idx].add(mode="drop")` drops
+    one past the end."""
+    rng = np.random.RandomState(seed)
+    logical = rng.rand(rows, form[1]).astype(np.float32)
+    idx = np.asarray(idx, np.int32)
+    upd = rng.rand(len(idx), form[1]).astype(np.float32)
+    inside = (idx >= 0) & (idx < rows)
+    want = logical.copy()
+    np.add.at(want, idx[inside], upd[inside])
+    got = _scatter_form(form, logical, idx, upd)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    untouched = np.setdiff1d(np.arange(rows), idx[inside])
+    np.testing.assert_array_equal(got[untouched], logical[untouched])
+
+
 class TestScatterAddRows:
     """Pallas RMW scatter kernel family (interpret mode on CPU) vs the
     tbl.at[idx].add oracle — covers the sort+segment dedup, the distinct-
@@ -165,23 +221,10 @@ class TestScatterAddRows:
     packed-view paths."""
 
     def _check(self, rows, dim, n, seed=0, dup=True):
-        import numpy as np
-
-        import jax.numpy as jnp
-        from dlrm_flexflow_tpu.ops.pallas.embedding_kernel import \
-            scatter_add_rows
-        rng = np.random.RandomState(seed)
-        tbl = rng.rand(rows, dim).astype(np.float32)
-        idx = rng.randint(0, rows, (n,)).astype(np.int32)
+        idx = np.random.RandomState(seed + 1).randint(0, rows, (n,))
         if dup and n >= 8:
             idx[:8] = idx[0]   # heavy duplicates exercise the dedup
-        upd = rng.rand(n, dim).astype(np.float32)
-        want = tbl.copy()
-        np.add.at(want, idx, upd)
-        got = np.asarray(scatter_add_rows(
-            jnp.asarray(tbl), jnp.asarray(idx), jnp.asarray(upd),
-            interpret=True))
-        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        _check_scatter_form(("add", dim), rows, idx, seed)
 
     def test_wide_multichunk(self):
         self._check(500, 256, 33)
@@ -194,9 +237,6 @@ class TestScatterAddRows:
         self._check(1000, 16, 80)
 
     def test_all_same_row(self):
-        import numpy as np
-
-        import jax.numpy as jnp
         from dlrm_flexflow_tpu.ops.pallas.embedding_kernel import \
             scatter_add_rows
         tbl = np.zeros((64, 128), np.float32)
@@ -209,24 +249,142 @@ class TestScatterAddRows:
         assert np.abs(np.delete(got, 7, axis=0)).max() == 0.0
 
     def test_packed_view(self):
-        import numpy as np
-
-        import jax.numpy as jnp
-        from dlrm_flexflow_tpu.ops.pallas.embedding_kernel import \
-            scatter_add_rows_packed
-        rng = np.random.RandomState(3)
-        rows, d = 512, 16            # r = 8 rows per 128-lane tile
-        logical = rng.rand(rows, d).astype(np.float32)
-        idx = rng.randint(0, rows, (40,)).astype(np.int32)
+        idx = np.random.RandomState(3).randint(0, 512, (40,))
         idx[:4] = idx[0]
-        upd = rng.rand(40, d).astype(np.float32)
-        want = logical.copy()
-        np.add.at(want, idx, upd)
-        view = logical.reshape(rows // 8, 128)
-        got = np.asarray(scatter_add_rows_packed(
-            jnp.asarray(view), jnp.asarray(idx), jnp.asarray(upd), d,
-            interpret=True)).reshape(rows, d)
-        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        _check_scatter_form(("add_packed", 16), 512, idx, seed=3)
+
+
+class TestScatterBlocks:
+    """The block pipeline both scatter kernels share, every form: slot
+    counts around `_scatter_block`, the count of valid slots that stands in
+    for a predicate a row, ids outside the table."""
+
+    ROWS = 4096
+
+    @pytest.mark.parametrize("form", SCATTER_FORMS, ids=str)
+    @pytest.mark.parametrize("blocks,extra", [(1, -1), (1, 0), (1, 1),
+                                              (2, 3)])
+    def test_slot_counts_around_a_block(self, form, blocks, extra):
+        """B-1, B, B+1 and 2B+3 lookups: one block not full, one full,
+        a second block of one slot, three blocks (every landing buffer,
+        the reads started a step ahead, the writes waited two steps on)."""
+        n = blocks * embedding_kernel._SCATTER_ROWS + extra
+        idx = np.random.RandomState(n).randint(0, self.ROWS, (n,))
+        idx[:8] = idx[0]
+        _check_scatter_form(form, self.ROWS, idx)
+
+    @pytest.mark.parametrize("form", SCATTER_FORMS, ids=str)
+    def test_one_valid_slot_and_the_rest_pads(self, form):
+        idx = np.full((300,), -1)
+        idx[123] = 77
+        _check_scatter_form(form, self.ROWS, idx)
+
+    @pytest.mark.parametrize("form", SCATTER_FORMS, ids=str)
+    def test_every_slot_the_same_row(self, form):
+        _check_scatter_form(form, self.ROWS, np.full((300,), 1234))
+
+    @pytest.mark.parametrize("form", SCATTER_FORMS, ids=str)
+    def test_ids_outside_the_table_are_dropped(self, form):
+        """The kernels run without Mosaic's bounds checks: an id below 0
+        or at or past `rows` must start no DMA, wherever it stands among
+        the lookups, and the rows at the table's two ends stay as they
+        were unless a lookup names them."""
+        rows = self.ROWS
+        idx = np.random.RandomState(5).randint(0, rows, (300,))
+        idx[::7] = rows + idx[::7] % 50           # at and past the end
+        idx[3::11] = -1 - idx[3::11] % 50         # below 0
+        idx[5], idx[6], idx[9] = rows, -1, -rows  # next to both ends
+        idx[10], idx[12] = 1, rows - 2            # and inside, beside them
+        _check_scatter_form(form, rows, idx)
+
+    @pytest.mark.parametrize("form", SCATTER_FORMS, ids=str)
+    def test_every_id_outside_the_table(self, form):
+        idx = np.concatenate([np.arange(-40, 0), self.ROWS + np.arange(40)])
+        _check_scatter_form(form, self.ROWS, idx)
+
+    @pytest.mark.parametrize("rmw", [True, False], ids=["add", "write"])
+    @pytest.mark.parametrize("n", [40, 255, 515])
+    def test_one_operation_a_distinct_row_is_bit_exact(self, rmw, n):
+        """After the dedup a row is touched once: the kernel's result is
+        `.at[target].add(summed)` (`.set` for the write-only form) bit
+        for bit, and the valid targets are a prefix of `target`."""
+        rng = np.random.RandomState(n)
+        view = rng.randn(600, 128).astype(np.float32)
+        tile_rows = rng.randint(-5, 605, (n,)).astype(np.int32)
+        tile_upds = rng.randn(n, 128).astype(np.float32)
+        target, summed, _, m = embedding_kernel._dedup_tile_updates(
+            jnp.asarray(tile_rows), jnp.asarray(tile_upds))
+        assert m % embedding_kernel._scatter_block(n) == 0
+        count = int(embedding_kernel._valid_prefix(target, 600)[0])
+        inside = np.unique(tile_rows[(tile_rows >= 0) & (tile_rows < 600)])
+        np.testing.assert_array_equal(np.asarray(target)[:count], inside)
+        got = _scatter_tiles(rmw)(jnp.asarray(view), target, summed, True)
+        at = jnp.asarray(view).at[target[:count]]
+        want = at.add(summed[:count]) if rmw else at.set(summed[:count])
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("rmw", [True, False], ids=["add", "write"])
+    @pytest.mark.parametrize("m,count", [(16, 16), (64, 37), (64, 0),
+                                         (80, 33), (160, 150), (8, 3)])
+    def test_pipeline_has_no_race_and_leaves_no_dma(self, monkeypatch, rmw,
+                                                    m, count):
+        """Under the TPU interpreter, which keeps the DMAs asynchronous,
+        counts the semaphores and starts a copy as late as its wait
+        allows: blocks of 16 so that a few hundred rows cross every
+        buffer, the boundary block and blocks past the count."""
+        from jax._src.pallas.mosaic.interpret import \
+            interpret_pallas_call as interpreter
+        from jax.experimental.pallas import tpu as pltpu
+        monkeypatch.setattr(embedding_kernel, "_SCATTER_ROWS", 16)
+        rng = np.random.RandomState(m + count)
+        view = rng.rand(400, 128).astype(np.float32)
+        target = rng.permutation(400)[:m].astype(np.int32)
+        target[count:] = -1
+        tiles = rng.rand(m, 128).astype(np.float32)
+        want = view.copy()
+        if rmw:
+            want[target[:count]] += tiles[:count]
+        else:
+            want[target[:count]] = tiles[:count]
+        got = _scatter_tiles(rmw)(
+            jnp.asarray(view), jnp.asarray(target), jnp.asarray(tiles),
+            pltpu.InterpretParams(detect_races=True,
+                                  dma_execution_mode="on_wait"))
+        np.testing.assert_array_equal(np.asarray(got), want)
+        assert not interpreter.races.races_found
+
+    def test_targets_that_are_no_whole_blocks_are_refused(self):
+        view = jnp.zeros((64, 128), jnp.float32)
+        with pytest.raises(ValueError, match="multiple of the block"):
+            embedding_kernel.scatter_write_tiles(
+                view, jnp.zeros((300,), jnp.int32),
+                jnp.zeros((300, 128), jnp.float32), interpret=True)
+
+    @pytest.mark.parametrize("rmw", [True, False], ids=["add", "write"])
+    def test_a_valid_target_behind_an_invalid_one_is_refused(self, rmw):
+        """The count drops every slot after the first pad or outside id,
+        and on the chip nothing checks: the interpreter tells a caller
+        whose valid targets are no prefix, instead of losing updates."""
+        view = jnp.zeros((64, 128), jnp.float32)
+        tiles = jnp.ones((16, 128), jnp.float32)
+        for hole in (-1, 64):
+            target = np.arange(16, dtype=np.int32)
+            target[5] = hole
+            with pytest.raises(Exception, match="stand behind"):
+                jax.block_until_ready(_scatter_tiles(rmw)(
+                    view, jnp.asarray(target), tiles, True))
+
+    def test_scatter_block_follows_the_slot_count(self):
+        """The benchmark's three slot counts are whole blocks (the dedup
+        pads none of them), a small call is one block of its own size,
+        and the landing buffers stay a small part of VMEM."""
+        block = embedding_kernel._scatter_block
+        for m in (89856, 65536, 3328):
+            assert block(m) == embedding_kernel._SCATTER_ROWS
+            assert m % block(m) == 0
+        assert block(40) == 40 and block(33) == 40 and block(1) == 8
+        assert (embedding_kernel._SCATTER_SLOTS * block(10**6) * 512
+                <= 2**20)
 
 
 class TestShardedScatter:
@@ -276,28 +434,9 @@ class TestScatterWritePacked:
     RMW scatter_add result exactly (duplicates summed)."""
 
     def _run(self, rows, d, n, seed=3):
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-
-        from dlrm_flexflow_tpu.ops.pallas.embedding_kernel import (
-            scatter_write_rows_packed)
-        rng = np.random.RandomState(seed)
-        logical = rng.rand(rows, d).astype(np.float32)
-        idx = rng.randint(0, rows, (n,)).astype(np.int32)
+        idx = np.random.RandomState(seed + 1).randint(0, rows, (n,))
         idx[:5] = idx[0]                       # duplicates
-        upd = rng.rand(n, d).astype(np.float32)
-        want = logical.copy()
-        np.add.at(want, idx, upd)
-        r = 128 // d
-        view = logical.reshape(rows // r, r * d)
-        fwd_tiles = np.asarray(view)[idx // r]         # (n, 128)
-        got = jax.jit(lambda v, i, u, t: scatter_write_rows_packed(
-            v, i, u, t, d, interpret=True))(
-                jnp.asarray(view), jnp.asarray(idx), jnp.asarray(upd),
-                jnp.asarray(fwd_tiles))
-        np.testing.assert_allclose(
-            np.asarray(got).reshape(rows, d), want, rtol=1e-5, atol=1e-5)
+        _check_scatter_form(("write_packed", d), rows, idx, seed)
 
     def test_narrow_rows(self):
         self._run(rows=1024, d=16, n=96)
@@ -335,7 +474,8 @@ class TestStatefulTilesPacked:
     with the logical-row XLA path (its oracle) — including the per-lane
     touched masks that keep a tile's OTHER logical rows' state undecayed."""
 
-    def _run(self, opt, rows=256, d=16, n=96, fwd=False, seed=0):
+    def _run(self, opt, rows=256, d=16, n=96, fwd=False, seed=0,
+             past_end=0):
         import jax
         import jax.numpy as jnp
         import numpy as np
@@ -345,6 +485,8 @@ class TestStatefulTilesPacked:
         rng = np.random.RandomState(seed)
         logical = rng.randn(rows, d).astype(np.float32)
         gidx = rng.randint(0, rows, size=(n,)).astype(np.int32)
+        # lookups at and past the table's end: both paths drop them
+        gidx[:past_end] = rows + np.arange(past_end) * 3
         upd = rng.randn(n, d).astype(np.float32)
         slabs = {k: rng.rand(rows, d).astype(np.float32)
                  for k in opt.sparse_slab_names()}
@@ -361,7 +503,8 @@ class TestStatefulTilesPacked:
         view = logical.reshape(rows // r, r * d)
         slab_views = {k: v.reshape(rows // r, r * d)
                       for k, v in slabs.items()}
-        fwd_tiles = (jnp.asarray(view[gidx // r]) if fwd else None)
+        fwd_tiles = (jnp.asarray(view[np.minimum(gidx // r, rows // r - 1)])
+                     if fwd else None)
         got_w, got_s = jax.jit(
             lambda v, g, u, s: _stateful_update_tiles_packed(
                 v, g, u, d, opt, s, step, fwd_tiles=fwd_tiles,
@@ -397,3 +540,42 @@ class TestStatefulTilesPacked:
     def test_adam_full_tile_rows(self):
         import dlrm_flexflow_tpu as ff
         self._run(ff.AdamOptimizer(alpha=0.01), rows=128, d=128, n=64)
+
+    @pytest.mark.parametrize("blocks,extra", [(1, -1), (1, 1), (2, 3)])
+    @pytest.mark.parametrize("fwd", [False, True])
+    def test_adam_slot_counts_around_a_block(self, blocks, extra, fwd):
+        """The three write-only scatters of a step (weight, m, v) at
+        B-1, B+1 and 2B+3 lookups, some of them past the table's end."""
+        import dlrm_flexflow_tpu as ff
+        n = blocks * embedding_kernel._SCATTER_ROWS + extra
+        self._run(ff.AdamOptimizer(alpha=0.01), rows=8192, d=64, n=n,
+                  fwd=fwd, past_end=5)
+
+    def test_three_writes_share_one_target(self, monkeypatch):
+        """Weight and both Adam slabs land through scatter_write_tiles on
+        the one deduped `target`: valid rows first, each once, and a
+        `_scatter_block` multiple long."""
+        import dlrm_flexflow_tpu as ff
+        seen = []
+        real = embedding_kernel.scatter_write_tiles
+
+        def spy(view, target, vals, interpret=False):
+            seen.append((view.shape, np.asarray(target)))
+            return real(view, target, vals, interpret=interpret)
+
+        monkeypatch.setattr(embedding_kernel, "scatter_write_tiles", spy)
+        with jax.disable_jit():
+            self._run(ff.AdamOptimizer(alpha=0.01), rows=512, d=64, n=70,
+                      past_end=4)
+        assert len(seen) == 3
+        vrows = seen[0][0][0]
+        for shape, target in seen:
+            assert shape == (vrows, 128)
+            np.testing.assert_array_equal(target, seen[0][1])
+        target = seen[0][1]
+        assert len(target) % embedding_kernel._scatter_block(70) == 0
+        count = int(embedding_kernel._valid_prefix(jnp.asarray(target),
+                                                   vrows)[0])
+        inside = target[:count]
+        assert np.all(np.diff(inside) > 0) and inside[-1] < vrows
+        assert not np.any((target[count:] >= 0) & (target[count:] < vrows))

@@ -59,6 +59,40 @@ def test_emb_gather_compiles_for_v5e(one_chip, no_compile_cache,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# (view rows of the table, dim, lookups): the terabyte cells' table at both
+# benchmark batches, k = 2 chunks a row, and fewer lookups than one block
+@pytest.mark.parametrize("rows,dim,n", [
+    (11739136, 128, 89856), (11739136, 128, 3328), (100000, 256, 1000),
+    (11739136, 128, 40)])
+def test_emb_scatter_add_compiles_for_v5e(one_chip, no_compile_cache,
+                                          rows, dim, n):
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(embedding_kernel.scatter_add_rows).lower(
+        sds((rows, dim)), sds((n,), jnp.int32), sds((n, dim))).compile()
+    assert "emb_scatter_add" in compiled.as_text()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# (packed view rows, d, lookups): dlrm_random's eight d=64 tables as one
+# packed view at the benchmark batch, under one block, and kaggle's d=16
+@pytest.mark.parametrize("vrows,d,n", [
+    (4000000, 64, 65536), (4000000, 64, 40), (500000, 16, 1000)])
+def test_emb_scatter_write_compiles_for_v5e(one_chip, no_compile_cache,
+                                            vrows, d, n):
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda v, i, u, t: embedding_kernel.scatter_write_rows_packed(
+            v, i, u, t, d)
+    ).lower(sds((vrows, 128)), sds((n,), jnp.int32), sds((n, d)),
+            sds((n, 128))).compile()
+    assert "emb_scatter_write" in compiled.as_text()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_expert_walk_compiles_for_v5e(one_chip, no_compile_cache):
     """The expert op's walk over its sorted pairs at Qwen3-Next's widths,
     forward and backward: loops with no static trip count, and a chunk's
