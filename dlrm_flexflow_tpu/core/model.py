@@ -24,7 +24,10 @@ compile-once/execute-many.
 
 from __future__ import annotations
 
+import contextlib
+import math
 import time
+from collections import deque
 from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
                     Sequence)
 
@@ -37,6 +40,7 @@ from ..parallel.mesh import make_mesh
 from ..parallel.pconfig import ParallelConfig, StrategyMap
 from ..parallel.sharding import AxisAssigner
 from ..parallel.distributed import MeshDegraded, MeshReturned, put_global
+from ..analysis import sanitizer as _san
 from ..obs import trace as obstrace
 from ..utils.watchdog import StallReport, WorkerStalled
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
@@ -114,6 +118,28 @@ class StagedStep(NamedTuple):
     device_batch: Dict[str, Any]
     host_idx: Optional[Dict[str, Any]]
     k: int = 1
+
+
+class _Throttle:
+    """The bound on async steps in flight: XLA CPU's in-process
+    collectives can starve when many multi-device executions queue up on
+    few host cores (on TPU the device is the bottleneck; a deep pipeline
+    is safe). Bounds the pipeline without draining it: a call blocks on
+    the step issued `bound` calls AGO."""
+
+    def __init__(self):
+        self.bound = 1 if jax.default_backend() == "cpu" else 32
+        self._losses = deque()
+
+    def clear(self):
+        self._losses.clear()
+
+    def __call__(self, mets):
+        self._losses.append(mets["loss"])
+        if len(self._losses) > self.bound:
+            with obstrace.span("fit/throttle"):
+                jax.block_until_ready(self._losses.popleft())
+        return mets
 
 
 class FFModel:
@@ -1506,7 +1532,6 @@ class FFModel:
 
     def _device_batch(self, batch: Dict[str, np.ndarray],
                       with_label: bool = True) -> Dict[str, Any]:
-        from ..analysis import sanitizer as _san
         _san.note_jax_dispatch("batch staging device_put")
         out = {}
         puts: Dict[str, tuple] = {}   # name -> (host array, sharding)
@@ -1531,20 +1556,23 @@ class FFModel:
             if lab.shape[0] % ndev != 0:
                 sh = NamedSharding(self.mesh, PartitionSpec())
             puts["label"] = (lab, sh)
-        if jax.process_count() > 1:
-            for k, (v, sh) in puts.items():
-                out[k] = self._stage_input(v, sh)
-        elif puts:
-            # ONE batched device_put for the whole step input: the
-            # per-call dispatch overhead (not the bytes) dominates small
-            # H2D puts, and the hot loop pays it every step — batching
-            # the puts measured ~1.6x faster staging on the DLRM input
-            # dict (dense+sparse+label)
-            names = list(puts)
-            vals = jax.device_put([puts[k][0] for k in names],
-                                  [puts[k][1] for k in names])
-            out.update(zip(names, vals))
+        if puts:
+            out.update(self._put(puts))
         return out
+
+    def _put(self, puts: Dict[str, tuple]) -> Dict[str, Any]:
+        """{name: (host array, sharding)} on the device."""
+        if jax.process_count() > 1:
+            return {k: self._stage_input(v, sh)
+                    for k, (v, sh) in puts.items()}
+        # ONE batched device_put for the whole step input: the per-call
+        # dispatch overhead (not the bytes) dominates small H2D puts, and
+        # a streamed loop pays it every step — batching the puts measured
+        # ~1.6x faster staging on the DLRM input dict
+        # (dense+sparse+label)
+        names = list(puts)
+        return dict(zip(names, jax.device_put(
+            [puts[k][0] for k in names], [puts[k][1] for k in names])))
 
     def train_batch(self, batch: Dict[str, np.ndarray]):
         """One fused train step (forward+backward+update). Returns metrics
@@ -1668,6 +1696,103 @@ class FFModel:
                     cache.put(ckey, exec_)
         obstrace.note_program(kind, exec_)
         return exec_
+
+    def _executable(self, kind: str, execs: Dict, key, fn, args):
+        """The AOT executable of the jitted `fn` for `key`: the one
+        `execs` holds, or built against `args` and kept there. Builds,
+        never runs: fit()'s warm-up calls it with the keys its loop will
+        ask for."""
+        exec_ = execs.get(key)
+        if exec_ is None:
+            exec_ = execs[key] = self._cached_compile(
+                kind, key, lambda: fn.lower(*args))
+        return exec_
+
+    def _run_executable(self, kind: str, execs: Dict, key, fn, args,
+                        span: Optional[str] = None, **span_args):
+        """Outputs of `fn(*args)` through its executable for `key`, the
+        call alone inside `span`. Calling the AOT executable directly is
+        the hot loop's point — the pjit python dispatch re-validates the
+        big param pytree every call, which costs more than a fast
+        model's step — and the key is the batch signature, so
+        alternating shapes (a remainder batch) each compile once. GSPMD
+        may give step outputs different shardings than the initial
+        inputs; one recompile against the propagated shardings reaches
+        the fixed point (the sharding check runs before execution, so
+        donated buffers are still intact, and `fresh` keeps the
+        persistent cache from handing back the entry that just
+        disagreed)."""
+        _san.note_jax_dispatch(f"{kind} executable")
+        exec_ = execs.get(key) or self._executable(kind, execs, key, fn,
+                                                   args)
+        with (obstrace.span(span, **span_args) if span
+              else contextlib.nullcontext()):
+            try:
+                return exec_(*args)
+            except ValueError as e:
+                if not _sharding_mismatch(e):
+                    raise
+                exec_ = execs[key] = self._cached_compile(
+                    kind, key, lambda: fn.lower(*args), fresh=True)
+                return exec_(*args)
+
+    def _step_args(self, batch: Dict) -> tuple:
+        """What the train and superstep programs take, the host-table
+        rows aside."""
+        return (self.params, self.opt_state, self.op_state, self._msums,
+                batch, self._step_dev)
+
+    def _dispatch_faults(self, batch: Dict, k: int = 1) -> Dict:
+        """The fault harness at a dispatch boundary, for the window of
+        `k` steps about to be issued as one program. A device loss or
+        return scheduled for ANY step of the window raises its typed
+        exception BEFORE dispatch, so no state for the window is
+        half-applied (a simulated preemption shrinks the runtime's view
+        of the mesh by the LAST ndrop devices; they stay physically alive
+        on a CPU test mesh — exactly how a lost peer looks from the
+        surviving hosts). A scheduled NaN poisons that step's batch —
+        only row s of a megabatch, the sibling steps of the scan stay
+        clean — so NaNs flow through the REAL autodiff into the
+        loss/grad-norm the sentinel watches (same shapes, dtypes and
+        shardings: the cached executable holds). Called only under an
+        active fault plan: the off path is the caller's one
+        `faults.active()`."""
+        for s in range(self._step, self._step + k):
+            ndrop = faults.take_drop_device(s)
+            if ndrop:
+                devs = list(self.mesh.devices.flat)
+                ndrop = min(ndrop, len(devs) - 1)
+                raise MeshDegraded(
+                    f"fault-injected loss of {ndrop} device(s) at step "
+                    f"{self._step}" + (f" (superstep boundary, K={k})"
+                                       if k > 1 else ""),
+                    lost=devs[len(devs) - ndrop:],
+                    surviving=devs[:len(devs) - ndrop])
+        self._maybe_return_devices(k)
+        for s in range(k):
+            if faults.take_nan_grad(self._step + s):
+                batch = faults.poison_batch(batch,
+                                            row=s if k > 1 else None)
+        return batch
+
+    def _check_anomaly(self, step0: int, mets: Dict) -> None:
+        """Under "rollback"/"raise", read back the sentinel's flag for
+        the window that began at `step0` (one step's scalars, or a
+        superstep's stacked [K] arrays) and raise at its FIRST faulting
+        step. The readback is the one host sync these policies cost;
+        skip_step never syncs. Every bad update was already suppressed on
+        device, so state is clean whichever way the caller (fit's
+        rollback loop, or the user) handles this."""
+        if self._anomaly_policy not in ("rollback", "raise"):
+            return
+        flags = np.atleast_1d(np.asarray(mets["anomaly"]))
+        if flags.any():
+            idx = int(np.argmax(flags))
+            raise AnomalyError(
+                step=step0 + idx,
+                loss=float(np.atleast_1d(np.asarray(mets["loss"]))[idx]),
+                grad_norm=float(np.atleast_1d(np.asarray(
+                    mets["grad_norm"]))[idx]))
 
     def _maybe_return_devices(self, k: int = 1) -> None:
         """Scale-UP detection at a dispatch boundary: when elastic
@@ -1841,16 +1966,7 @@ class FFModel:
         if lab.shape[1] % ndev != 0:
             sh = NamedSharding(self.mesh, PartitionSpec())
         puts["label"] = (lab, self._superstep_sharding(sh))
-        out: Dict[str, Any] = {}
-        if jax.process_count() > 1:
-            for name, (v, shd) in puts.items():
-                out[name] = self._stage_input(v, shd)
-        else:
-            names = list(puts)
-            vals = jax.device_put([puts[n][0] for n in names],
-                                  [puts[n][1] for n in names])
-            out.update(zip(names, vals))
-        return out
+        return self._put(puts)
 
     def _stage_superstep(self, stacked: Dict[str, Any]) -> "StagedStep":
         """Fully stage one K-step megabatch (stacked host arrays with
@@ -1885,69 +2001,22 @@ class FFModel:
         k = int(next(iter(sbatch.values())).shape[0])
         self._ensure_step_state()
         if faults.active() is not None:
-            for s in range(self._step, self._step + k):
-                ndrop = faults.take_drop_device(s)
-                if ndrop:
-                    devs = list(self.mesh.devices.flat)
-                    ndrop = min(ndrop, len(devs) - 1)
-                    raise MeshDegraded(
-                        f"fault-injected loss of {ndrop} device(s) at "
-                        f"superstep boundary (step {self._step}, K={k})",
-                        lost=devs[len(devs) - ndrop:],
-                        surviving=devs[:len(devs) - ndrop])
-            for s in range(k):
-                if faults.take_nan_grad(self._step + s):
-                    # poison ONLY the faulting step's slice: the sibling
-                    # steps in the scan must stay clean, exactly like
-                    # the K=1 path poisons one step's batch
-                    sbatch = faults.poison_batch(sbatch, row=s)
-            self._maybe_return_devices(k)
-        args = (self.params, self.opt_state, self.op_state, self._msums,
-                sbatch, self._step_dev)
-        key = (k,) + self._exec_key(sbatch)
-        execs = getattr(self, "_superstep_execs", None)
-        if execs is None:
-            execs = self._superstep_execs = {}
-        exec_ = execs.get(key)
-        if exec_ is None:
-            exec_ = execs[key] = self._cached_compile(
-                "superstep", key, lambda: self._superstep_fn.lower(*args))
-        # once in K steps, so it can carry what a trace reader divides a
-        # fused span by
-        with obstrace.span("train/superstep", step_num=self._step,
-                           superstep=k):
-            try:
-                outs = exec_(*args)
-            except ValueError as e:
-                # same GSPMD recompile-on-sharding-disagree fallback as
-                # the K=1 dispatch
-                if not _sharding_mismatch(e):
-                    raise
-                exec_ = execs[key] = self._cached_compile(
-                    "superstep", key,
-                    lambda: self._superstep_fn.lower(*args), fresh=True)
-                outs = exec_(*args)
+            sbatch = self._dispatch_faults(sbatch, k)
+        # once in K steps, so the span can carry what a trace reader
+        # divides a fused span by
         (self.params, self.opt_state, self.op_state, self._msums,
-         self._step_dev, last, stacked) = outs
+         self._step_dev, last, stacked) = self._run_executable(
+            "superstep", self._superstep_execs,
+            (k,) + self._exec_key(sbatch), self._superstep_fn,
+            self._step_args(sbatch), "train/superstep",
+            step_num=self._step, superstep=k)
         step0 = self._step
         self._step += k
         self.perf.sums = dict(self._msums)
         mets = dict(last)
         mets["per_step"] = stacked
         mets["superstep"] = k
-        policy = getattr(self, "_anomaly_policy", "none")
-        if policy in ("rollback", "raise"):
-            flags = np.asarray(stacked["anomaly"])
-            if flags.any():
-                # every bad update was already suppressed per step ON
-                # DEVICE inside the scan (state is clean); report the
-                # FIRST faulting step so the caller's recovery targets it
-                idx = int(np.argmax(flags))
-                raise AnomalyError(
-                    step=step0 + idx,
-                    loss=float(np.asarray(stacked["loss"])[idx]),
-                    grad_norm=float(np.asarray(
-                        stacked["grad_norm"])[idx]))
+        self._check_anomaly(step0, stacked)
         return mets
 
     @obstrace.spanned("train/dispatch")
@@ -1955,64 +2024,20 @@ class FFModel:
                         next_host_idx=None):
         self._ensure_step_state()
         if faults.active() is not None:
-            ndrop = faults.take_drop_device(self._step)
-            if ndrop:
-                # simulated preemption: the runtime's view of the mesh
-                # shrinks by the LAST ndrop devices (they stay physically
-                # alive on a CPU test mesh — exactly how a lost peer
-                # looks from the surviving hosts). Raised BEFORE dispatch
-                # so no state for this step is half-applied.
-                devs = list(self.mesh.devices.flat)
-                ndrop = min(ndrop, len(devs) - 1)
-                raise MeshDegraded(
-                    f"fault-injected loss of {ndrop} device(s) at step "
-                    f"{self._step}", lost=devs[len(devs) - ndrop:],
-                    surviving=devs[:len(devs) - ndrop])
-            self._maybe_return_devices()
-        if faults.active() is not None and faults.take_nan_grad(self._step):
-            # fault harness: poison the batch so NaNs flow through the
-            # REAL autodiff into the loss/grad-norm the sentinel watches
-            # (same shapes/dtypes/shardings — the cached executable holds)
-            device_batch = faults.poison_batch(device_batch)
+            device_batch = self._dispatch_faults(device_batch)
+        # _step_args, inline: this is the step path of the host-paced cell
         args = (self.params, self.opt_state, self.op_state, self._msums,
                 device_batch, self._step_dev)
-        if host_idx is not None:
-            args = args + (self._host_emb_input(host_idx),)
         hres = host_idx is not None
-        # hot loop: call the AOT-compiled executable directly — the pjit
-        # python dispatch re-validates the big param pytree every call,
-        # which costs more than the step itself on fast models. Keyed by
-        # the batch signature so alternating shapes (e.g. a remainder
-        # batch) each compile once.
-        key = self._exec_key(device_batch)
-        from ..analysis import sanitizer as _san
-        _san.note_jax_dispatch("train executable")
-        execs = getattr(self, "_train_step_execs", None)
-        if execs is None:
-            execs = self._train_step_execs = {}
-        exec_ = execs.get(key)
-        if exec_ is None:
-            exec_ = execs[key] = self._cached_compile(
-                "train", key, lambda: self._train_step.lower(*args))
-        with obstrace.span("train/step"):
-            try:
-                outs = exec_(*args)
-            except ValueError as e:
-                # GSPMD may give step outputs different shardings than
-                # the initial inputs; one recompile against the
-                # propagated shardings reaches the fixed point (the
-                # sharding check runs before execution, so donated
-                # buffers are still intact)
-                if not _sharding_mismatch(e):
-                    raise
-                exec_ = execs[key] = self._cached_compile(
-                    "train", key, lambda: self._train_step.lower(*args),
-                    fresh=True)
-                outs = exec_(*args)
+        if hres:
+            args = args + (self._host_emb_input(host_idx),)
         (self.params, self.opt_state, self.op_state, self._msums,
-         self._step_dev, mets) = outs
+         self._step_dev, mets) = self._run_executable(
+            "train", self._train_step_execs,
+            self._exec_key(device_batch), self._train_step, args,
+            "train/step")
         self._step += 1
-        policy = getattr(self, "_anomaly_policy", "none")
+        policy = self._anomaly_policy
         # the sentinel flag (device bool) guards the host-table scatter on
         # every policy: NaN cotangents scattered into host tables could not
         # be undone by skip_step's on-device suppression
@@ -2023,7 +2048,7 @@ class FFModel:
                 # host scatter run on a worker thread, overlapping the
                 # NEXT step's gather/H2D/dispatch and device execution.
                 # When the caller knows the next batch (`next_host_idx` —
-                # fit's streaming prefetch does), the worker gathers the
+                # fit's streamed feed does), the worker gathers the
                 # NEXT step's rows FIRST (they are ready almost
                 # immediately, so the next dispatch never waits on the
                 # scatter), then scatters this step's update — the
@@ -2082,16 +2107,8 @@ class FFModel:
         # shallow-copy so perf.reset()/report() mutating perf.sums can
         # never corrupt the jit carry
         self.perf.sums = dict(self._msums)
-        if policy in ("rollback", "raise") and bool(
-                np.asarray(anomaly_flag)):
-            # the flag readback is the one host sync these policies cost;
-            # skip_step never syncs. The bad update was already suppressed
-            # on device, so state is clean whichever way the caller (fit's
-            # rollback loop, or the user) handles this.
-            raise AnomalyError(step=self._step - 1,
-                               loss=float(mets["loss"]),
-                               grad_norm=float(np.asarray(
-                                   mets["grad_norm"])))
+        if anomaly_flag is not None:
+            self._check_anomaly(self._step - 1, mets)
         return mets
 
     @property
@@ -2102,11 +2119,10 @@ class FFModel:
         pool's internal serialization."""
         lk = getattr(self, "_host_table_lock", None)
         if lk is None:
-            from ..analysis.sanitizer import make_lock
             # no_dispatch: gathers copy rows OUT under the lock and
             # device_put after release; a dispatch in the critical
             # section would stall the scatter worker (FLX203)
-            lk = self._host_table_lock = make_lock(
+            lk = self._host_table_lock = _san.make_lock(
                 "FFModel._host_table_lock", no_dispatch=True)
         return lk
 
@@ -2214,7 +2230,6 @@ class FFModel:
             for op in self._host_resident_list:
                 rows[op.name] = op.host_lookup(self.host_params[op.name],
                                                host_idx[op.name])
-        from ..analysis import sanitizer as _san
         _san.note_jax_dispatch("host-table row device_put")
         return {op.name: jax.device_put(
                     rows[op.name], self._out_sharding[op.outputs[0].guid])
@@ -2273,16 +2288,9 @@ class FFModel:
         skip the numpy table lookup; the default is the exact
         ``_host_emb_forward`` path."""
         db = self._device_batch(batch, with_label=False)
-        hres = getattr(self, "_host_resident_list", None)
-        if hres:
+        db, host_idx = self._split_host_idx(db)
+        if host_idx is not None:
             self._host_drain()   # eval must see the last step's scatter
-            db = dict(db)
-            host_idx = {}
-            for op in hres:
-                name = op.inputs[0].name
-                host_idx[op.name] = np.asarray(db[name])
-                if name in getattr(self, "_host_only_inputs", set()):
-                    db.pop(name)
             gather = host_gather or self._host_emb_forward
             return self._eval_dispatch(db, gather(host_idx))
         return self._eval_dispatch(db)
@@ -2385,9 +2393,8 @@ class FFModel:
         self._ensure_step_state()
         db = device_batch if device_batch is not None \
             else self.synthetic_device_batch()
-        args = (self.params, self.opt_state, self.op_state, self._msums,
-                db, self._step_dev)
-        return self._train_step.lower(*args).compile().as_text()
+        return self._train_step.lower(
+            *self._step_args(db)).compile().as_text()
 
     def lowered_eval_hlo(self, device_batch: Optional[Dict] = None
                          ) -> str:
@@ -2555,23 +2562,17 @@ class FFModel:
         python on EVERY call, which costs more than a fast model's
         forward itself — the cached `.lower().compile()` executable
         skips that, keyed by the batch signature (alternating shapes
-        each compile once), with the usual GSPMD
-        recompile-on-sharding-disagree fallback."""
-        from collections import OrderedDict
+        each compile once; `_run_executable`)."""
         args = (self.params, self.op_state, db)
         key = self._exec_key(db)
         if host_emb is not None:
             args = args + (host_emb,)
             key = key + ("host_emb",) + self._exec_key(host_emb)
-        from ..analysis import sanitizer as _san
-        _san.note_jax_dispatch("eval executable")
-        execs = getattr(self, "_eval_step_execs", None)
-        if execs is None:
-            execs = self._eval_step_execs = OrderedDict()
-        exec_ = execs.get(key)
-        if exec_ is None:
-            exec_ = execs[key] = self._cached_compile(
-                "eval", key, lambda: self._eval_step.lower(*args))
+        execs = self._eval_step_execs
+        if key in execs:
+            execs.move_to_end(key)
+        else:
+            self._executable("eval", execs, key, self._eval_step, args)
             # LRU-bound the cache: a serving engine fed many ad-hoc
             # shapes must not leak one compiled executable per shape
             # forever (config.eval_exec_cache, 0/negative = unbounded)
@@ -2580,17 +2581,8 @@ class FFModel:
                 execs.popitem(last=False)
                 self._eval_exec_evictions = getattr(
                     self, "_eval_exec_evictions", 0) + 1
-        else:
-            execs.move_to_end(key)
-        try:
-            return exec_(*args)
-        except ValueError as e:
-            if not _sharding_mismatch(e):
-                raise
-            exec_ = execs[key] = self._cached_compile(
-                "eval", key, lambda: self._eval_step.lower(*args),
-                fresh=True)
-            return exec_(*args)
+        return self._run_executable("eval", execs, key, self._eval_step,
+                                    args)
 
     def eval_exec_cache_stats(self) -> Dict[str, int]:
         """Occupancy of the eval-path AOT executable cache plus the
@@ -2636,6 +2628,94 @@ class FFModel:
             self.train_batch(self._pending_update)
             self._pending_update = None
 
+    def _staging_room(self):
+        """What fit()'s feed may keep on the device: (the bytes one chip
+        has for a resident data set, {input name or "label": the chips a
+        staged copy of it is spread over}). The room is per-chip HBM
+        minus what already lives there (params + optimizer state + op
+        state), with 30% headroom for activations/workspace; a staged
+        input costs a chip its full size when its sharding is replicated,
+        size/ndev when the sample dim is sharded (matches
+        _build_shardings' input specs). Off-TPU there is no HBM and all
+        virtual "chips" share one host's RAM: a modest cap on the TOTAL
+        second copy, so fit() on a CPU mesh never device_puts a huge data
+        set a second time."""
+        if jax.default_backend() != "tpu":
+            return 2e9, {}
+        from ..search.cost_model import TPUSpec
+        ndev = max(self.mesh.size, 1)
+        split = {t.name: ndev for t in self.input_tensors
+                 if self._out_sharding[t.guid].spec}
+        if self._label_sharding.spec:
+            split["label"] = ndev
+
+        def per_chip(leaf) -> float:
+            # per-chip bytes of a (possibly sharded) device array —
+            # .nbytes alone is the GLOBAL logical size
+            try:
+                shard = leaf.sharding.shard_shape(leaf.shape)
+                return float(math.prod(shard)) * leaf.dtype.itemsize
+            except Exception:
+                return float(getattr(leaf, "nbytes", 0))
+
+        resident = sum(per_chip(v) for v in jax.tree.leaves(
+            (self.params, self.opt_state, self.op_state)))
+        return max(0.0, 0.7 * TPUSpec.detect().hbm_capacity_bytes
+                   - resident), split
+
+    def _warm_up(self, feed) -> None:
+        """AOT-compile the train step — and the fused scan under a
+        superstep — against the first batch as the feed stages it, so the
+        loop starts warm without consuming a real optimizer step (the
+        reference warms its Legion trace during epoch 0 instead,
+        dlrm.cc:178-185). The executables are cached under the SAME keys
+        the loop's dispatches ask for, so its first step builds nothing.
+        A fit(batch_size=) the graph cannot take fails here, with the
+        reason."""
+        def refuse(e, cannot):
+            if feed.bs == self.config.batch_size:
+                raise e
+            raise ValueError(f"fit(batch_size={feed.bs}) cannot "
+                             + cannot.format(self.config.batch_size)
+                             + f": {e}") from e
+
+        try:
+            item = self._stage_step(feed.host_slice(0))
+        except Exception as e:
+            refuse(e, "stage against this model's input shardings "
+                      "(compiled for batch {})")
+        self._ensure_step_state()
+        args = self._step_args(item.device_batch)
+        if item.host_idx is not None:
+            args = args + (self._host_emb_forward(item.host_idx),)
+        try:
+            self._executable("train", self._train_step_execs,
+                             self._exec_key(item.device_batch),
+                             self._train_step, args)
+        except Exception as e:
+            refuse(e, "compile against this graph (an op bakes the "
+                      "compile-time batch {} into its shape)")
+        if feed.k > 1:
+            sbatch = self._stage_superstep(
+                feed.host_slice(0, feed.k)).device_batch
+            self._executable("superstep", self._superstep_execs,
+                             (feed.k,) + self._exec_key(sbatch),
+                             self._superstep_fn, self._step_args(sbatch))
+
+    def _drift_monitor(self, name: str):
+        """--obs on: process-wide metrics + span tracing + the drift
+        monitor comparing measured step time (and lowered collective
+        bytes, once) against the simulator's predictions — the runtime
+        twin of shardcheck FLX513. Off (default): None, and a loop pays
+        one pointer compare per step."""
+        from ..obs import configure
+        if not configure(self.config):
+            return None
+        from ..obs.drift import DriftMonitor
+        mon = DriftMonitor.from_model(self, name=name)
+        mon.audit_collectives()
+        return mon
+
     # ------------------------------------------------------------------
     # fit loop (reference keras base_model.py:367-431 / dlrm.cc:166-198)
     # ------------------------------------------------------------------
@@ -2663,6 +2743,10 @@ class FFModel:
 
         All three arguments default from FFConfig (`--checkpoint-dir`,
         `--save-every`, `--keep-last`).
+
+        Every batch reaches the step the same way: the feed
+        (data/feed.py) hands out one StagedStep a dispatch, resident or
+        streamed, and `train_batch_staged` trains it.
         """
         epochs = epochs or self.config.epochs
         bs = batch_size or self.config.batch_size
@@ -2673,10 +2757,10 @@ class FFModel:
         keep_last = (keep_last if keep_last is not None
                      else getattr(self.config, "keep_last", 3))
         if bs != self.config.batch_size:
-            # the per-shape executable cache (train_batch_device) compiles
-            # the step at the requested shape; ops whose shapes bake the
-            # batch dimension (explicit Reshape targets) reject the trace
-            # below with an actionable error. Reference keras fit() takes
+            # the per-shape executable cache compiles the step at the
+            # requested shape; ops whose shapes bake the batch dimension
+            # (explicit Reshape targets) reject the trace in the warm-up
+            # with an actionable error. Reference keras fit() takes
             # whatever batch_size it is given (base_model.py:367-431).
             log_model.warning(
                 "fit(batch_size=%d) differs from the compile-time batch "
@@ -2686,20 +2770,13 @@ class FFModel:
         if n < bs:
             raise ValueError(f"dataset has {n} samples < batch size {bs}")
         num_batches = n // bs
-        # the remainder (n % bs samples) trains as its OWN smaller batch
-        # through the same per-shape cache; if its shape cannot trace or
-        # stage, it is dropped with a loud warning (the reference loop
-        # silently trains only full batches)
-        rem = n - num_batches * bs
-        rem_ok = rem > 0
         if self.params is None:
             self.init_layers()
 
         # --- fused supersteps -------------------------------------------
         # K full batches train as ONE dispatch (lax.scan executable);
-        # batches that can't align to a K boundary — the tail of an
-        # epoch, a mid-group resume position, the odd-shaped remainder —
-        # fall back to exact K=1 steps. K=1 IS the legacy path, bitwise.
+        # what cannot align to a K boundary falls back to exact K=1 steps
+        # (the feed's schedule). K=1 IS the legacy path, bitwise.
         k_super = self.resolve_superstep(bs)
         if k_super > num_batches:
             if getattr(self.config, "superstep", 1) == "auto":
@@ -2719,16 +2796,6 @@ class FFModel:
                 f"superstep boundaries (the K fused steps commit "
                 f"atomically) — pick save_every % K == 0, or "
                 f"--superstep 1 for exact per-step checkpointing")
-
-        def _super_slice(b_, k_):
-            # [K, batch, ...] stacked host views of K contiguous batches
-            # (reshape of a contiguous slice: no copy)
-            sl = slice(b_ * bs, (b_ + k_) * bs)
-            out = {kk: np.asarray(v)[sl].reshape((k_, bs) + v.shape[1:])
-                   for kk, v in inputs.items()}
-            out["label"] = np.asarray(labels)[sl].reshape(
-                (k_, bs) + labels.shape[1:])
-            return out
 
         # --- fault tolerance: rolling checkpoints + auto-resume ---------
         mgr = None
@@ -2764,13 +2831,13 @@ class FFModel:
                         "num_samples": 0, "rollbacks": 0,
                         "recoveries": 0, "expansions": 0,
                         "metrics": self.perf.report()}
-            if (getattr(self, "_anomaly_policy", "none") == "rollback"
+            if (self._anomaly_policy == "rollback"
                     or getattr(self.config, "elastic", "off") == "resume") \
                     and mgr.latest_valid() is None:
                 # rollback/elastic-resume need a target from step one:
                 # seed the directory with the initial state
                 mgr.save(self, {"epoch": start_epoch, "batch": start_batch})
-        elif getattr(self, "_anomaly_policy", "none") == "rollback":
+        elif self._anomaly_policy == "rollback":
             raise ValueError(
                 'anomaly_policy="rollback" needs fit(checkpoint_dir=...) '
                 "(or FFConfig.checkpoint_dir) to roll back to")
@@ -2780,196 +2847,39 @@ class FFModel:
                 "mesh degradation mid-run will have no snapshot to "
                 "resume from and will re-raise")
 
-        # AOT-compile the train step so the timed loop starts warm without
-        # consuming a real optimizer step (the reference warms its Legion
-        # trace during epoch 0 instead, dlrm.cc:178-185)
-        first = {k: v[:bs] for k, v in inputs.items()}
-        first["label"] = labels[:bs]
-        try:
-            staged_first = self._device_batch(first)
-        except Exception as e:
-            if bs != self.config.batch_size:
-                raise ValueError(
-                    f"fit(batch_size={bs}) cannot stage against this "
-                    f"model's input shardings (compiled for batch "
-                    f"{self.config.batch_size}): {e}") from e
-            raise
-        db, hidx = self._split_host_idx(staged_first)
-        self._ensure_step_state()
-        wargs = (self.params, self.opt_state, self.op_state, self._msums,
-                 db, self._step_dev)
-        if hidx is not None:
-            wargs = wargs + (self._host_emb_forward(hidx),)
-        # cache the warmup executable under the SAME key the hot loop
-        # uses, so the first timed step doesn't recompile it
-        execs = getattr(self, "_train_step_execs", None)
-        if execs is None:
-            execs = self._train_step_execs = {}
-        wkey = self._exec_key(db)
-        if wkey not in execs:
-            try:
-                execs[wkey] = self._cached_compile(
-                    "train", wkey,
-                    lambda: self._train_step.lower(*wargs))
-            except Exception as e:
-                if bs != self.config.batch_size:
-                    raise ValueError(
-                        f"fit(batch_size={bs}) cannot compile against this "
-                        f"graph (an op bakes the compile-time batch "
-                        f"{self.config.batch_size} into its shape): {e}"
-                    ) from e
-                raise
-        if k_super > 1:
-            # warm the fused-scan executable too, so the timed loop's
-            # first superstep doesn't pay its (K-body) compile
-            sdb = self._device_superbatch(_super_slice(0, k_super))
-            skey = (k_super,) + self._exec_key(sdb)
-            sexecs = getattr(self, "_superstep_execs", None)
-            if sexecs is None:
-                sexecs = self._superstep_execs = {}
-            if skey not in sexecs:
-                sargs = (self.params, self.opt_state, self.op_state,
-                         self._msums, sdb, self._step_dev)
-                sexecs[skey] = self._cached_compile(
-                    "superstep", skey,
-                    lambda: self._superstep_fn.lower(*sargs))
-
+        # --stage-dataset: "never" forces the streamed feed
+        # (bench_pipeline compares the two); "always" trusts the caller
+        # on capacity. The feed drains (and re-stages,
+        # deterministically) around rollback, recovery and a remainder
+        # whose shape cannot train.
+        from ..data.feed import BatchFeed
+        budget, split = self._staging_room()
+        feed = BatchFeed(
+            inputs, labels, bs, k_super, epochs, self._stage_step,
+            self._stage_superstep,
+            mode=getattr(self.config, "stage_dataset", "auto"),
+            budget=budget, split=split,
+            depth=max(int(getattr(self.config, "prefetch_depth", 2)
+                          or 0), 0),
+            deadline_s=self._worker_deadline_s() or None,
+            on_close=self._host_prefetch_invalidate)
+        self._warm_up(feed)
         if self.config.profiling:
             # per-op timing report (reference --profiling cudaEvent prints,
             # linear.cu:499-531)
             from ..utils.profiling import format_profile, profile_ops
             print(format_profile(profile_ops(self)))
-
-        # stage the whole dataset's batches on device once when it fits —
-        # the reference's design (the ENTIRE dataset lives in zero-copy
-        # memory and the hot loop scatters device-side, dlrm.cc:384-589);
-        # otherwise fall back to per-batch host→device staging
-        # staging budget = per-chip HBM capacity minus what already lives
-        # there (params + optimizer state + op state), with 30% headroom
-        # for activations/workspace. Per-chip cost of a staged input is its
-        # full size when its sharding is replicated, size/ndev when the
-        # sample dim is sharded (matches _build_shardings' input specs).
-        # Off-TPU there is no HBM; keep a modest host-RAM cap so fit() on a
-        # virtual CPU mesh never device_puts a huge dataset a second time.
-        from ..search.cost_model import TPUSpec
-        ndev = max(self.mesh.size, 1)
-
-        def _per_chip(arr, sharded: bool) -> float:
-            return arr.nbytes / ndev if sharded else float(arr.nbytes)
-
-        in_sharded = {
-            t.name: bool(self._out_sharding[t.guid].spec)
-            for t in self.input_tensors}
-        if jax.default_backend() == "tpu":
-            staging_cost = sum(
-                _per_chip(v, in_sharded.get(k, False))
-                for k, v in inputs.items())
-            staging_cost += _per_chip(labels,
-                                      bool(self._label_sharding.spec))
-
-            def _resident_per_chip(leaf) -> float:
-                # per-chip bytes of a (possibly sharded) device array —
-                # .nbytes alone is the GLOBAL logical size
-                try:
-                    shard = leaf.sharding.shard_shape(leaf.shape)
-                    import math as _m
-                    return float(_m.prod(shard)) * leaf.dtype.itemsize
-                except Exception:
-                    return float(getattr(leaf, "nbytes", 0))
-
-            resident = sum(_resident_per_chip(v) for v in jax.tree.leaves(
-                (self.params, self.opt_state, self.op_state)))
-            budget = max(0.0, 0.7 * TPUSpec.detect().hbm_capacity_bytes
-                         - resident)
-        else:
-            # all virtual CPU "chips" share one host's RAM: cap the TOTAL
-            # second copy of the dataset, not the per-chip share
-            staging_cost = float(sum(v.nbytes for v in inputs.values())
-                                 + labels.nbytes)
-            budget = 2e9
-        staged = None
-        staged_rem = None
-        staged_super = None
-        # --stage-dataset: "never" forces the streaming/prefetch path
-        # (bench_pipeline compares the two); "always" trusts the caller
-        # on capacity
-        stage_mode = getattr(self.config, "stage_dataset", "auto")
-        if stage_mode == "never":
-            staging_cost = float("inf")
-        elif stage_mode == "always":
-            staging_cost = 0.0
-        @obstrace.spanned("fit/stage")
-        def _stage_all():
-            # (re)build the device-resident batches against the model's
-            # CURRENT input shardings — called once up front, and again
-            # by elastic recovery (arrays staged on the old mesh must
-            # not feed an executable compiled on the new one; megabatches
-            # are re-staged the same way). With a superstep, aligned full
-            # groups stage as [K, bs, ...] megabatches (one put each) and
-            # only the unaligned tail stages per-batch.
-            nonlocal staged, staged_rem, staged_super, rem_ok
-            staged = {}
-            staged_super = {} if k_super > 1 else None
-            tail0 = 0
-            if k_super > 1:
-                tail0 = (num_batches // k_super) * k_super
-                for g in range(0, tail0, k_super):
-                    staged_super[g] = self._device_superbatch(
-                        _super_slice(g, k_super))
-            for b in range(tail0, num_batches):
-                sl = slice(b * bs, (b + 1) * bs)
-                batch = {k: v[sl] for k, v in inputs.items()}
-                batch["label"] = labels[sl]
-                staged[b] = self._device_batch(batch)
-            staged_rem = None
-            if rem_ok:
-                # the remainder already fit the staging budget (the cost
-                # counted the whole dataset) — stage it once instead of
-                # re-transferring it every epoch
-                batch = {k: v[num_batches * bs:n] for k, v in inputs.items()}
-                batch["label"] = labels[num_batches * bs:n]
-                try:
-                    staged_rem = self._device_batch(batch)
-                except Exception as e:
-                    rem_ok = False
-                    log_model.warning(
-                        "dropping the remainder batch (%d samples): it "
-                        "cannot stage at its own shape (%s)", rem, e)
-
-        if staging_cost <= budget:
-            _stage_all()
+        feed.restage()
+        feed.rewind(start_epoch, start_batch)
 
         from ..utils.profiling import TraceContext
-        # --- unified observability (dlrm_flexflow_tpu/obs/) -----------
-        # --obs on: process-wide metrics + span tracing + the drift
-        # monitor comparing measured step time (and lowered collective
-        # bytes, once) against the simulator's predictions — the
-        # runtime twin of shardcheck FLX513. Off (default): drift_mon
-        # stays None and the loop pays one pointer compare per step.
-        from ..obs import configure as _obs_configure
-        from ..obs import trace as _obstrace
-        drift_mon = None
-        if _obs_configure(self.config):
-            from ..obs.drift import DriftMonitor
-            drift_mon = DriftMonitor.from_model(self, name="fit")
-            drift_mon.audit_collectives()
-        # bound in-flight async steps: XLA CPU's in-process collectives can
-        # starve when many multi-device executions queue up on few host
-        # cores (on TPU the device is the bottleneck; a deep pipeline is
-        # safe) — same throttle as examples/native/dlrm.py
-        throttle = 1 if jax.default_backend() == "cpu" else 32
-        from collections import deque
-        inflight = deque()
-
-        def _throttled(m):
-            # bound the pipeline without draining it: block on the step
-            # issued `throttle` iterations AGO
-            inflight.append(m["loss"])
-            if len(inflight) > throttle:
-                with obstrace.span("fit/throttle"):
-                    jax.block_until_ready(inflight.popleft())
-            return m
-
+        drift_mon = self._drift_monitor("fit")
+        throttled = _Throttle()
+        # with async host-resident tables, the scatter worker chains the
+        # NEXT step's host gather using the item the feed staged ahead
+        peek_idx = (feed.peek_host_idx if getattr(
+            self, "_host_resident_list", None) and getattr(
+            self.config, "host_tables_async", True) else None)
         start = time.time()
         mets = None
         num_samples = 0
@@ -2980,237 +2890,64 @@ class FFModel:
         max_recoveries = getattr(self.config, "max_recoveries", 3)
         elastic_mode = getattr(self.config, "elastic", "off")
 
-        def _maybe_save(next_epoch, next_batch):
-            # position = the NEXT (epoch, batch) to train; snapshots are
-            # written off-thread (the device→host gather is inline)
-            if mgr is not None and save_every and \
-                    self._step % save_every == 0:
-                mgr.save_async(self, {"epoch": next_epoch,
-                                      "batch": next_batch})
-
-        # --- streaming prefetch pipeline ------------------------------
-        # When the dataset is NOT pre-staged, a background staging thread
-        # slices + device_puts (and host-index-splits) up to
-        # `prefetch_depth` future batches while the device trains the
-        # current one (data/prefetch.py) — the reference's DataLoader
-        # tasks staging batch N+1 under batch N's compute. With async
-        # host-resident tables, the scatter worker additionally chains
-        # the NEXT step's host gather using the staged item's indices.
-        # The pipeline drains (and re-stages, deterministically) around
-        # rollback and remainder-shape failures.
-        depth = max(int(getattr(self.config, "prefetch_depth", 2) or 0), 0)
-        use_pipe = staged is None and depth > 0
-        pipe = None
-        nxt = None          # staged item fetched ahead by the peek hook
-        pipe_exc: List[BaseException] = []
-
-        def _host_slice(e, b):
-            if b == "rem":
-                sl = slice(num_batches * bs, n)
-            else:
-                sl = slice(b * bs, (b + 1) * bs)
-            batch = {k: v[sl] for k, v in inputs.items()}
-            batch["label"] = labels[sl]
-            return batch
-
-        def _close_pipe():
-            nonlocal pipe, nxt
-            if pipe is not None:
-                pipe.close()
-                pipe = None
-            nxt = None
-            pipe_exc.clear()
-            self._host_prefetch_invalidate()
-
-        def _build_pipe(e0, b0_):
-            nonlocal pipe
-            _close_pipe()
-            # one schedule entry per DISPATCH: (epoch, batch, k) — k>1
-            # entries stage a whole superstep megabatch in one ring slot
-            # (one device_put feeding K fused steps); unaligned batches
-            # and the remainder stay k=1. The consumer loop walks batches
-            # with the same alignment rule, so the two stay in lockstep.
-            sched = []
-            for e in range(e0, epochs):
-                b = b0_ if e == e0 else 0
-                while b < num_batches:
-                    if (k_super > 1 and b % k_super == 0
-                            and b + k_super <= num_batches):
-                        sched.append((e, b, k_super))
-                        b += k_super
-                    else:
-                        sched.append((e, b, 1))
-                        b += 1
-                if rem_ok:
-                    sched.append((e, "rem", 1))
-            if not sched:
-                return
-            from ..data.prefetch import PrefetchPipeline
-
-            def produce(i):
-                e, b, kk = sched[i]
-                if kk > 1:
-                    return self._stage_superstep(_super_slice(b, kk))
-                return self._stage_step(_host_slice(e, b))
-
-            pipe = PrefetchPipeline(
-                produce, depth=depth, num_items=len(sched), name="fit",
-                deadline_s=self._worker_deadline_s() or None)
-
-        hres_async = bool(getattr(self, "_host_resident_list", None)
-                          and getattr(self.config, "host_tables_async",
-                                      True))
-
-        def _peek_next_host_idx():
-            # runs inside the train step at scatter-launch time (the
-            # device already executes this step): fetch the NEXT staged
-            # item so the async worker can chain its host gather after
-            # this step's scatter. A staging error here must not skip
-            # this step's scatter — defer it to the next consume.
-            nonlocal nxt
-            try:
-                nxt = pipe.get()
-                return nxt.host_idx
-            except IndexError:        # end of schedule
-                return None
-            except BaseException as e:
-                pipe_exc.append(e)
-                return None
-
-        def _next_staged():
-            nonlocal nxt
-            if pipe_exc:
-                raise pipe_exc.pop()
-            if nxt is not None:
-                cur, nxt = nxt, None
-                return cur
-            return pipe.get()
-
-        def _train_streamed():
-            # same in-flight bound as the pre-staged path: the producer
-            # keeps the dispatch queue fed, so the throttle is what
-            # keeps XLA-CPU collectives from starving
-            return _throttled(self.train_batch_staged(
-                _next_staged(),
-                next_host_idx=_peek_next_host_idx if hres_async else None))
-
-        if use_pipe:
-            _build_pipe(start_epoch, start_batch)
-
-        import contextlib
-
-        @contextlib.contextmanager
-        def _pipe_guard():
-            # the staging thread must not outlive fit() on ANY exit path
-            # (an AnomalyError under policy "raise" included)
-            try:
-                yield
-            finally:
-                _close_pipe()
-
-        with TraceContext(self.config.profile_dir or None), _pipe_guard():
+        with TraceContext(self.config.profile_dir or None), feed:
             epoch, b0 = start_epoch, start_batch
             # resume position for the elastic "inplace" path: the batch
             # about to train, plus whether its optimizer step actually
             # applied before the degradation surfaced
-            cur = (start_epoch, start_batch)
-            step0 = self._step
+            cur, step0 = (epoch, b0), self._step
             while epoch < epochs:
                 if b0 == 0:
                     self.reset_metrics()
                 try:
-                    b = b0
-                    while b < num_batches:
-                        # a group of K batches anchored on a K boundary
-                        # trains as ONE fused dispatch; everything else
-                        # (epoch tail, mid-group resume position) is an
-                        # exact K=1 step
-                        k = (k_super if (k_super > 1 and b % k_super == 0
-                                         and b + k_super <= num_batches)
-                             else 1)
-                        cur, step0 = (epoch, b), self._step
-                        _t_drift = (time.perf_counter()
-                                    if drift_mon is not None else 0.0)
-                        if k > 1:
-                            if staged is not None:
-                                mets = _throttled(
-                                    self.train_superstep_device(
-                                        staged_super[b]))
-                            elif pipe is not None:
-                                mets = _train_streamed()
-                            else:
-                                mets = self.train_superstep_device(
-                                    self._device_superbatch(
-                                        _super_slice(b, k)))
-                        elif staged is not None:
-                            db_b = staged.get(b)
-                            if db_b is None:
-                                # a resume position inside a megabatch-
-                                # staged group: stage this one batch on
-                                # the fly (one-off until re-aligned)
-                                sl = slice(b * bs, (b + 1) * bs)
-                                batch = {kk: v[sl]
-                                         for kk, v in inputs.items()}
-                                batch["label"] = labels[sl]
-                                db_b = self._device_batch(batch)
-                            mets = _throttled(self.train_batch_device(db_b))
-                        elif pipe is not None:
-                            mets = _train_streamed()
-                        else:
-                            sl = slice(b * bs, (b + 1) * bs)
-                            batch = {kk: v[sl] for kk, v in inputs.items()}
-                            batch["label"] = labels[sl]
-                            mets = self.train_batch(batch)
-                        num_samples += bs * k
-                        if drift_mon is not None:
-                            # per-step wall clock the dispatch loop
-                            # observed (async pipelining amortized by
-                            # the inflight throttle); a superstep
-                            # spreads its window over its K steps
-                            drift_mon.observe_step(
-                                (time.perf_counter() - _t_drift) / k)
-                        _maybe_save(epoch, b + k)
-                        b += k
-                    if rem_ok:
+                    while (ent := feed.peek(epoch)) is not None:
                         # degradation during the remainder resumes at the
                         # next epoch (the odd-shaped batch is not worth a
                         # dedicated resume position; "resume" mode re-
                         # winds exactly via the snapshot regardless)
-                        cur, step0 = (epoch + 1, 0), None
+                        last = ent.b == num_batches
+                        nxt = ((epoch + 1, 0) if last
+                               else (epoch, ent.b + ent.k))
+                        cur, step0 = ((nxt, None) if last
+                                      else ((epoch, ent.b), self._step))
+                        t_drift = (time.perf_counter()
+                                   if drift_mon is not None else 0.0)
                         try:
-                            if staged_rem is not None:
-                                mets = self.train_batch_device(staged_rem)
-                            elif pipe is not None:
-                                mets = _train_streamed()
-                            else:
-                                sl = slice(num_batches * bs, n)
-                                batch = {k: v[sl]
-                                         for k, v in inputs.items()}
-                                batch["label"] = labels[sl]
-                                mets = self.train_batch(batch)
-                            num_samples += rem
-                            _maybe_save(epoch + 1, 0)
-                        except AnomalyError:
+                            mets = throttled(self.train_batch_staged(
+                                feed.get(), next_host_idx=peek_idx))
+                        except (AnomalyError, MeshDegraded, WorkerStalled,
+                                MeshReturned):
                             raise   # recovery, not a shape problem
                         except Exception as e:
-                            rem_ok = False
-                            log_model.warning(
-                                "dropping the remainder batch (%d "
-                                "samples): it cannot train at its own "
-                                "shape (%s) — pad the dataset or pick a "
-                                "batch size dividing %d", rem, e, n)
-                            if use_pipe:
-                                # the ring may hold later rem items (and
-                                # a dead producer, if staging raised) —
-                                # re-stage the rest without them
-                                _build_pipe(epoch + 1, 0)
+                            if not last:
+                                raise
+                            # the ring may hold later remainders (and a
+                            # dead producer, if staging raised): stage
+                            # the rest without them
+                            feed.drop_remainder(e)
+                            feed.rewind(*nxt)
+                            break
+                        num_samples += feed.rem if last else bs * ent.k
+                        if drift_mon is not None and not last:
+                            # per-step wall clock the dispatch loop
+                            # observed (async pipelining amortized by
+                            # the throttle); a superstep spreads its
+                            # window over its K steps
+                            drift_mon.observe_step(
+                                (time.perf_counter() - t_drift) / ent.k)
+                        # position = the NEXT (epoch, batch) to train;
+                        # snapshots are written off-thread (the
+                        # device→host gather is inline)
+                        if mgr is not None and save_every and \
+                                self._step % save_every == 0:
+                            mgr.save_async(self, {"epoch": nxt[0],
+                                                  "batch": nxt[1]})
                 except AnomalyError as exc:
-                    if (getattr(self, "_anomaly_policy", "none")
-                            != "rollback" or mgr is None
+                    if (self._anomaly_policy != "rollback" or mgr is None
                             or rollbacks >= max_rollbacks):
                         raise
                     rollbacks += 1
-                    inflight.clear()
+                    throttled.clear()
                     mgr.wait()
                     entry = mgr.restore_latest(self)
                     if entry is None:
@@ -3223,24 +2960,21 @@ class FFModel:
                         "(epoch %d, batch %d) — recovery %d/%d",
                         exc.step, exc, entry["step"], epoch, b0,
                         rollbacks, max_rollbacks)
-                    if use_pipe:
-                        # drop staged-ahead batches and re-stage from the
-                        # rewound position (deterministic, so exact)
-                        _build_pipe(epoch, b0)
+                    feed.rewind(epoch, b0)
                     continue
                 except (MeshDegraded, WorkerStalled,
                         MeshReturned) as exc:
                     grow = isinstance(exc, MeshReturned)
                     if elastic_mode == "off" or (
-                            recoveries if not grow else
-                            expansions) >= max_recoveries:
+                            expansions if grow else
+                            recoveries) >= max_recoveries:
                         raise
                     if grow:
                         expansions += 1
                     else:
                         recoveries += 1
-                    inflight.clear()
-                    _close_pipe()
+                    throttled.clear()
+                    feed.close()
                     if mgr is not None:
                         try:
                             mgr.wait()   # land/flush the in-flight save
@@ -3285,13 +3019,10 @@ class FFModel:
                         expansions if grow else recoveries,
                         max_recoveries, elastic_mode, report.surviving,
                         epoch, b0)
-                    if staged is not None:
-                        # re-stage the dataset against the NEW mesh's
-                        # input shardings (old-mesh arrays must not feed
-                        # the recompiled executable)
-                        _stage_all()
-                    if use_pipe:
-                        _build_pipe(epoch, b0)
+                    # arrays staged on the OLD mesh must not feed the
+                    # recompiled executable
+                    feed.restage()
+                    feed.rewind(epoch, b0)
                     continue
                 with obstrace.span("fit/epoch_end"):
                     if verbose and mets is not None:
@@ -3325,7 +3056,7 @@ class FFModel:
                "metrics": self.perf.report()}
         if drift_mon is not None:
             out["drift"] = drift_mon.report()
-            _obstrace.export_to_dir()   # no-op without --obs-trace-dir
+            obstrace.export_to_dir()   # no-op without --obs-trace-dir
         return out
 
     # ------------------------------------------------------------------
@@ -3359,9 +3090,10 @@ class FFModel:
         raising ``StopIteration``/``IndexError`` ends the stream;
         ``steps`` bounds it explicitly (None = until the source ends).
 
-        Batches ride the SAME depth-K prefetch ring as ``fit()`` — the
-        staging thread slices + device_puts batch N+1 while the device
-        trains batch N — and every batch is shown to the publisher's
+        Batches ride the same depth-K prefetch ring as ``fit()``'s
+        streamed feed, under the same throttle — the staging thread
+        slices + device_puts batch N+1 while the device trains batch N —
+        and every batch is shown to the publisher's
         :class:`~..utils.delta.TouchedRowTracker` BEFORE staging, so at
         publish time the per-table touched-row candidates cover every
         trained step. Every ``publish_every`` optimizer steps the
@@ -3414,23 +3146,13 @@ class FFModel:
                 publisher.observe_batch(batch)
             return self._stage_step(batch)
 
-        # --obs on: drift monitor + trace export, same wiring as fit()
-        from ..obs import configure as _obs_configure
-        from ..obs import trace as _obstrace
-        drift_mon = None
-        if _obs_configure(self.config):
-            from ..obs.drift import DriftMonitor
-            drift_mon = DriftMonitor.from_model(self, name="fit_stream")
-            drift_mon.audit_collectives()
-
+        drift_mon = self._drift_monitor("fit_stream")
         depth = max(int(getattr(self.config, "prefetch_depth", 2) or 0),
                     1)
         pipe = PrefetchPipeline(
             produce, depth=depth, num_items=steps, name="fit_stream",
             deadline_s=self._worker_deadline_s() or None)
-        throttle = 1 if jax.default_backend() == "cpu" else 32
-        from collections import deque as _deque
-        inflight = _deque()
+        throttled = _Throttle()
         trained = 0
         publishes = 0
         mets = None
@@ -3443,10 +3165,7 @@ class FFModel:
                     break
                 _t_drift = (time.perf_counter()
                             if drift_mon is not None else 0.0)
-                mets = self.train_batch_staged(staged)
-                inflight.append(mets["loss"])
-                if len(inflight) > throttle:
-                    jax.block_until_ready(inflight.popleft())
+                mets = throttled(self.train_batch_staged(staged))
                 if drift_mon is not None:
                     drift_mon.observe_step(
                         time.perf_counter() - _t_drift)
@@ -3481,5 +3200,5 @@ class FFModel:
                              if publisher is not None else None)}
         if drift_mon is not None:
             out["drift"] = drift_mon.report()
-            _obstrace.export_to_dir()   # no-op without --obs-trace-dir
+            obstrace.export_to_dir()   # no-op without --obs-trace-dir
         return out

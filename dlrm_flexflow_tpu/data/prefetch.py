@@ -33,13 +33,11 @@ Contracts (tests/test_prefetch.py pins all three):
 (``overlap_fraction``), which benchmarks/bench_pipeline.py turns into the
 gather/H2D overlap metric.
 
-Fused supersteps (``FFConfig.superstep``) ride the same ring: ``fit()``'s
-schedule emits one entry per K-step *megabatch* and ``produce`` stages the
-K host batches as ONE stacked ``[K, batch, ...]`` device_put
-(``FFModel._stage_superstep``), so a single ring slot — and a single H2D
-transfer, extending the PR-2 single-put win — feeds K fused training
-steps. :func:`stack_batches` is the host-side stacking helper for
-non-contiguous batch lists (contiguous dataset slices reshape for free).
+``fit()`` drives the ring through :class:`~.feed.BatchFeed`, whose
+schedule gives a K-step superstep ONE slot: ``produce`` stages its K host
+batches as a single stacked ``[K, batch, ...]`` device_put.
+:func:`stack_batches` is the host-side stacking helper for non-contiguous
+batch lists (contiguous data-set slices reshape for free).
 """
 
 from __future__ import annotations
