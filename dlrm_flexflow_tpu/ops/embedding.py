@@ -410,9 +410,10 @@ def _sparse_update_active(op) -> bool:
 
 
 def _dedup_rows(gidx, upd, num_rows: int):
-    """Row-granularity duplicate combination: sort + segment-sum, exactly
-    the sorted-segment trick of the Pallas scatters but in UNPACKED row
-    space (stateful optimizers are nonlinear in the gradient, so duplicate
+    """Row-granularity duplicate combination: sort + segment-sum in
+    UNPACKED row space, on any backend (the Pallas scatters' own dedup,
+    `_dedup_tile_updates`, reaches the same result without XLA's segment
+    ops; stateful optimizers are nonlinear in the gradient, so duplicate
     lookups MUST be pre-summed into one gradient row — dense semantics).
 
     gidx (n,) int row ids (duplicates allowed); upd (n, d).
@@ -481,7 +482,7 @@ def _stateful_update_tiles_packed(view, gidx, upd, d, opt, slab_views,
                                       jnp.float32)
     # one sort/segment pass for both the gradient and the touch counts
     both = jnp.concatenate([tile_upds, tile_ones], axis=1)
-    target, summed, rep, _ = _dedup_tile_updates(tile_rows, both)
+    target, summed, rep, _ = _dedup_tile_updates(tile_rows, both, interpret)
     g_tiles, counts = summed[:, :128], summed[:, 128:]
     touched = counts > 0
     safe = jnp.minimum(jnp.maximum(target, 0), view.shape[0] - 1)

@@ -20,8 +20,12 @@ no atomics and gathers are HBM-bandwidth bound, so the design here is:
 - backward: no atomics — sort the flat indices and segment-sum the incoming
   gradients (indices_are_sorted lets XLA lower it as a linear pass), which
   replaces the reference's atomicAdd scatter.
-- sparse update: the same sort + segment-sum leaves DISTINCT target rows,
-  valid ones first, and one kernel (`_scatter_kernel`) applies them in
+- sparse update: no atomics either, and no XLA scatter (8-13 ns an element
+  on the v5e). A sort makes duplicates adjacent; a second sort compacts each
+  run's target row to the front, valid ones first; one kernel
+  (`_run_sum_kernel`) fetches the update rows in sorted order and sums each
+  run in VMEM (`_dedup_tile_updates`). That leaves DISTINCT target rows,
+  and one kernel (`_scatter_kernel`) applies them in
   blocks of a few hundred (`_scatter_block`), as a read-modify-write
   (`emb_scatter_add`) or, where the caller kept the forward's rows, as pure
   writes (`emb_scatter_write`): the reads of the next block and the writes
@@ -73,6 +77,13 @@ _SCATTER_ROWS = 256
 # and block i-1's writes are both in flight while block i is added; two
 # (the writes waited before the next reads start) cost 6% and 13% more
 _SCATTER_SLOTS = 3
+# run-sum kernel block, in sorted slots a grid step (`_run_block`): one row
+# fetch a slot, as the gather's block, and a (block, block) one-hot product,
+# which grows with the square. Swept on the v5e, kernel time in us (PERF.md,
+# PR 29), 128 / 256 (/ 512 with the product at HIGHEST, which costs 0.9 ns a
+# slot more at 256): 89,856 zipf slots of 128: 584 / 521 (/ +240);
+# 65,536 uniform: 400 / 389 (/ +130)
+_RUN_ROWS = 256
 
 
 def supports(dim: int) -> bool:
@@ -95,55 +106,62 @@ def _gather_block(batch: int, bag: int, k: int) -> int:
     return min(g_max, pl.cdiv(batch, _SUBLANES) * _SUBLANES)
 
 
+def _start_fetches(bag: int, k: int, idx_ref, src_ref, planes, sems, blk):
+    """Start every fetch of block blk into planes[blk % 2]: chunk c of bag
+    slot b of sample s, row idx[(blk*g + s)*bag + b] of the (rows*k, 128)
+    chunk view src_ref in HBM, lands in row s of plane b*k + c (Mosaic
+    takes a one-sublane DMA target only in a buffer one lane tile wide)."""
+    g, slot = planes.shape[2], blk % 2
+
+    def tile(t, carry):
+        # one sublane tile of samples a trip: Mosaic unrolls a fori_loop
+        # fully or not at all, and hundreds of bodies would bloat set-up
+        for u in range(_SUBLANES):
+            s = t * _SUBLANES + u
+            for b in range(bag):
+                # chunk c of source row idx[s, b] is view row idx*k + c
+                row = idx_ref[(blk * g + s) * bag + b] * k
+                for c in range(k):
+                    pltpu.make_async_copy(
+                        src_ref.at[pl.ds(row + c, 1), :],
+                        planes.at[slot, b * k + c, pl.ds(s, 1), :],
+                        sems.at[slot]).start()
+        return carry
+
+    jax.lax.fori_loop(0, g // _SUBLANES, tile, None)
+
+
+def _wait_fetches(planes, sems, slot):
+    """Wait for every fetch into planes[slot]: a wait takes the byte count
+    of its target off the semaphore, so one wait for a whole plane stands
+    for the g fetches that filled it."""
+    for p in range(planes.shape[1]):
+        plane = planes.at[slot, p]
+        pltpu.make_async_copy(plane, plane, sems.at[slot]).wait()
+
+
 def _bag_kernel(bag: int, k: int, idx_ref, table_ref, out_ref, planes, sems):
     """One grid step = one block of g samples (`_gather_block`).
 
-    table_ref is the (rows*k, 128) chunk view resident in HBM. Chunk c of
-    bag slot b of sample s lands in row s of planes[slot, b*k + c], a
-    (g, 128) tile column (Mosaic takes a one-sublane DMA target only in a
-    buffer one lane tile wide). Step i starts every fetch of block i+1 into
-    the other slot BEFORE it waits for block i, whose fetches step i-1
-    started: the fetch queue never drains between blocks. Then the planes
-    are summed over b in float32 into the output block; for bag == 1 that
-    is a copy, bit for bit.
+    table_ref is the (rows*k, 128) chunk view resident in HBM; a sample's
+    bag*k chunks land in as many (g, 128) planes (`_start_fetches`). Step i
+    starts every fetch of block i+1 into the other slot BEFORE it waits for
+    block i, whose fetches step i-1 started: the fetch queue never drains
+    between blocks. Then the planes are summed over b in float32 into the
+    output block; for bag == 1 that is a copy, bit for bit.
     """
     i, n = pl.program_id(0), pl.num_programs(0)
-    g = out_ref.shape[0]
-
-    def start_block(blk):
-        slot = blk % 2
-
-        def tile(t, carry):
-            # one sublane tile of samples a trip: Mosaic unrolls a fori_loop
-            # fully or not at all, and hundreds of bodies would bloat set-up
-            for u in range(_SUBLANES):
-                s = t * _SUBLANES + u
-                for b in range(bag):
-                    # chunk c of table row idx[s, b] is view row idx*k + c
-                    row = idx_ref[(blk * g + s) * bag + b] * k
-                    for c in range(k):
-                        pltpu.make_async_copy(
-                            table_ref.at[pl.ds(row + c, 1), :],
-                            planes.at[slot, b * k + c, pl.ds(s, 1), :],
-                            sems.at[slot]).start()
-            return carry
-
-        jax.lax.fori_loop(0, g // _SUBLANES, tile, None)
 
     @pl.when(i == 0)
     def _():
-        start_block(0)
+        _start_fetches(bag, k, idx_ref, table_ref, planes, sems, 0)
 
     @pl.when(i + 1 < n)
     def _():
-        start_block(i + 1)
+        _start_fetches(bag, k, idx_ref, table_ref, planes, sems, i + 1)
 
     slot = i % 2
-    for p in range(bag * k):
-        # a wait takes the byte count of its target off the semaphore: one
-        # wait for a whole plane stands for the g fetches that filled it
-        plane = planes.at[slot, p]
-        pltpu.make_async_copy(plane, plane, sems.at[slot]).wait()
+    _wait_fetches(planes, sems, slot)
     for c in range(k):
         acc = planes[slot, c].astype(jnp.float32)
         for b in range(1, bag):
@@ -266,7 +284,9 @@ def _scatter_kernel(rmw: bool, idx_ref, cnt_ref, upd_ref, tbl_ref, out_ref,
     forms: read-modify-write (out[row] += upd) and write-only
     (out[row] = upd; the same pipeline without the read stage and the add).
 
-    PRECONDITION (established by _dedup_tile_updates and _valid_prefix):
+    PRECONDITION (established by _dedup_tile_updates, whose second sort
+    leaves each run's row once and the valid ones in front, and
+    _valid_prefix, which counts them):
     the first cnt_ref[0] targets are DISTINCT rows of the view, so every
     row's read-modify-write is independent of every other's, in its block
     and across blocks. The reference needed atomicAdd for this
@@ -453,14 +473,15 @@ def scatter_add_rows(table: jax.Array, indices: jax.Array,
                      updates: jax.Array,
                      interpret: bool = False) -> jax.Array:
     """table.at[indices].add(updates) for (rows, dim) tables — a Pallas
-    in-place RMW kernel with an XLA dedup pre-pass.
+    in-place RMW kernel behind a dedup pre-pass.
 
     XLA's TPU scatter lowers to a serialized update loop that costs
     hundreds of ms for a few thousand rows on a multi-GB table. Here:
     (1) updates are expressed as (view_row, 128-lane tile) pairs — k
     chunks per row for wide tables, rotated d-wide slices for narrow ones;
-    (2) duplicates are combined by sort + segment-sum (the sorted-segment
-    trick that replaces the reference's atomicAdd backward); (3) a Pallas
+    (2) duplicates are combined by `_dedup_tile_updates`: two sorts and a
+    kernel pass that sums each run of equal rows (adjacency after the sort
+    replaces the reference's atomicAdd backward); (3) a Pallas
     kernel streams the distinct tiles through a pipelined
     read-modify-write, touching only the updated bytes of HBM.
 
@@ -550,53 +571,224 @@ def _pack_tile_updates(indices, updates, dim, dtype):
         return tile_rows, out
 
 
-def _dedup_tile_updates(tile_rows, tile_upds):
-    """Combine same-tile updates so a scatter kernel sees DISTINCT rows:
-    sort → segment-sum → per-segment target row → pad to a
-    `_scatter_block` multiple. Returns
-    (target (m,), summed (m, 128), rep (m,), m) where rep[s] is one
-    original position whose update landed in segment s (for callers that
-    need a representative forward tile).
+def _run_block(m: int) -> int:
+    """Sorted slots a grid step of the run-sum kernel covers: _RUN_ROWS, or
+    one lane tile where that holds all m (a slot is a lane of the block's
+    one-hot)."""
+    return min(_RUN_ROWS, pl.cdiv(m, _LANES) * _LANES)
 
-    The sort reads a row id as unsigned, so ids a view can hold come
-    first, ascending as a signed sort puts them, and an id below 0 sorts
-    behind every one of them: the valid targets are a prefix of `target`,
-    each once. Behind them stand ids outside the view, which only the
-    scatter knows (`_valid_prefix` drops them), and then the pads (-1)."""
+
+def _run_sum_kernel(k: int, order_ref, start_ref, end_ref, seg_ref, upd_ref,
+                    out_ref, planes, sums, zeros, carry, fsems, wsem, zsem):
+    """One grid step = one block of b slots of the SORTED order
+    (`_run_block`): out[c, r] = chunk c of the float32 sum of the update
+    rows of run r.
+
+    upd_ref is the (m*k, 128) chunk view of the UNSORTED updates in HBM.
+    Slot s of block i fetches the k chunks of row order[i*b + s] into row s
+    of planes[i % 2], as `_bag_kernel` fetches table rows: step i starts
+    block i+1's fetches before it waits for its own, and the permuted copy
+    of the updates never exists in HBM. seg_ref holds the block's run ids,
+    ascending, runs adjacent; start_ref[i] and end_ref[i] are the first and
+    the last of them. The runs are summed where the rows are: the one-hot
+    (b, b) of `seg - start` times a (b, 128) plane is every run's sum,
+    already compacted to the front of the block, and rows past the block's
+    runs come out 0 (a slot past m carries seg -1 and is in no run). The
+    product keeps every bit of an update and accumulates in float32. A run
+    that crosses into the next block hands its partial sum on in `carry`.
+
+    Block i's b rows of sums are written at out[c, start[i]:start[i] + b],
+    in order: each write waits for the one before it, so where two overlap
+    (block i+1 starts inside block i's rows, at the latest on the zeros
+    behind its runs) the later block's rows stand. Rows from the number of
+    runs on are zeroed, one aligned block a step from a block of zeros, the
+    lowest first and landed before step 0 writes: only that one reaches
+    below the number of runs.
+
+    Like the kernels beside it this runs without Mosaic's bounds checks:
+    order is a permutation of the slots and start[i] + b <= i*b + b. A
+    non-finite update reaches every run of its block (0 * NaN): such a
+    step is the anomaly sentinel's to drop, not the kernel's.
+    """
+    i, n = pl.program_id(0), pl.num_programs(0)
+    b = planes.shape[2]
+
+    @pl.when(i == 0)
+    def _():
+        _start_fetches(1, k, order_ref, upd_ref, planes, fsems, 0)
+        zeros[...] = jnp.zeros_like(zeros)
+        carry[...] = jnp.zeros_like(carry)
+
+    @pl.when(i + 1 < n)
+    def _():
+        _start_fetches(1, k, order_ref, upd_ref, planes, fsems, i + 1)
+
+    # zero block i of the tail, counted from the lowest: n*b rows, the runs
+    # in front, whole blocks of zeros aligned from the end over the rest
+    tail_blocks = (n * b - end_ref[n - 1] - 1 + b - 1) // b
+    zero_row = pl.multiple_of((n - tail_blocks + i) * b, b)
+
+    def zero_fill(c):
+        return pltpu.make_async_copy(
+            zeros, out_ref.at[c, pl.ds(zero_row, b), :], zsem)
+
+    @pl.when(i < tail_blocks)
+    def _():
+        for c in range(k):
+            zero_fill(c).start()
+
+    slot = i % 2
+    _wait_fetches(planes, fsems, slot)
+    run = seg_ref[...] - start_ref[i]                      # (1, b)
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
+              == run).astype(jnp.bfloat16)
+    for c in range(k):
+        # a float32 is the sum of three bfloat16 pieces exactly (8 + 8 + 8
+        # mantissa bits) and a 0/1 is one, so three single-pass products
+        # accumulated in float32 are what HIGHEST's six passes give
+        # (5.8 against 6.7 ns a slot on the v5e, PERF.md, PR 29)
+        hi = planes[slot, c].astype(jnp.bfloat16)
+        rest = planes[slot, c] - hi.astype(jnp.float32)
+        mid = rest.astype(jnp.bfloat16)
+        low = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+        sums[slot, c] = sum(
+            jnp.dot(onehot, piece, preferred_element_type=jnp.float32)
+            for piece in (hi, mid, low))
+
+    @pl.when((i > 0) & (start_ref[i] == end_ref[jnp.maximum(i - 1, 0)]))
+    def _():
+        for c in range(k):
+            sums[slot, c, 0:1, :] = sums[slot, c, 0:1, :] + carry[c]
+
+    for c in range(k):
+        carry[c] = sums[slot, c, pl.ds(end_ref[i] - start_ref[i], 1), :]
+
+    def write(blk, c):
+        return pltpu.make_async_copy(
+            sums.at[blk % 2, c],
+            out_ref.at[c, pl.ds(start_ref[blk], b), :], wsem)
+
+    @pl.when(i < tail_blocks)
+    def _():
+        for c in range(k):
+            zero_fill(c).wait()
+
+    @pl.when(i > 0)
+    def _():
+        for c in range(k):
+            write(i - 1, c).wait()
+
+    for c in range(k):
+        write(i, c).start()
+
+    @pl.when(i + 1 == n)
+    def _():
+        for c in range(k):
+            write(i, c).wait()
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
+def _run_sums(tile_upds, order, seg, rows: int, interpret):
+    """(rows, W) float32: row r the sum of the rows of `tile_upds` whose
+    sorted slot carries run id r, rows past the last run 0. `order` (m,) is
+    the slots' original positions in sorted order, `seg` (m,) their run
+    ids; rows >= m. Jitted: a memory space is constrained only in a traced
+    program, and callers may run op by op."""
+    m, width = tile_upds.shape
+    k = width // _LANES
+    b = _run_block(rows)
+    blocks = pl.cdiv(rows, b)
+    pad = blocks * b - m
+    # a slot past m fetches row 0 and is in no run (-1); the first and the
+    # last run id of a block are those of its real slots
+    order = jnp.pad(order, (0, pad))
+    edged = jnp.pad(seg, (0, pad), mode="edge").reshape(blocks, b)
+    seg = jnp.pad(seg, (0, pad), constant_values=-1)
+    chunks = tile_upds.astype(jnp.float32).reshape(m * k, _LANES)
+    if not interpret:
+        # left to itself XLA keeps a temporary of this size in VMEM, and a
+        # row DMA from there costs 1.7x one from HBM (PERF.md, PR 29); the
+        # interpreter knows no memory spaces
+        chunks = pltpu.with_memory_space_constraint(chunks, pltpu.HBM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(blocks,),
+        in_specs=[
+            pl.BlockSpec((None, 1, b), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((2, k, b, _LANES), jnp.float32),   # rows fetched
+            pltpu.VMEM((2, k, b, _LANES), jnp.float32),   # their run sums
+            pltpu.VMEM((b, _LANES), jnp.float32),         # zeros
+            pltpu.VMEM((k, 1, _LANES), jnp.float32),      # carry
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA(()),
+            pltpu.SemaphoreType.DMA(()),
+        ],
+    )
+    with jax.named_scope("emb_run_sum"):
+        out = pl.pallas_call(
+            functools.partial(_run_sum_kernel, k),
+            # chunk planes: Mosaic takes a block of rows at any row of an
+            # HBM array one lane tile wide, not of a wider one
+            out_shape=jax.ShapeDtypeStruct((k, blocks * b, _LANES),
+                                           jnp.float32),
+            grid_spec=grid_spec,
+            # a step waits for DMAs that the step before it started
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                disable_bounds_checks=True),
+            interpret=interpret,
+            name="emb_run_sum",
+        )(order, edged[:, 0], edged[:, -1], seg.reshape(blocks, 1, b),
+          chunks)
+    return jnp.concatenate(list(out[:, :rows]), axis=1)
+
+
+def _dedup_tile_updates(tile_rows, tile_upds, interpret=False):
+    """Combine same-tile updates so a scatter kernel sees DISTINCT rows.
+    Returns (target (m,), summed (m, W), rep (m,), m), m a `_scatter_block`
+    multiple: target[s] is the view row of run s, summed[s] the float32 sum
+    of its updates (W a multiple of 128), rep[s] the original position of
+    one of its members (for callers that need a representative forward
+    tile: every member read the same pre-update tile).
+
+    After a sort the duplicates are adjacent, so nothing here needs a
+    scatter. The sort reads a row id as unsigned and carries the original
+    positions (in no promised order inside a run: any member represents
+    it, and a sum's order is free): ids a view can hold come first, ascending as a signed sort
+    puts them, and an id below 0 sorts behind every one of them. A run's
+    target is the key at its first slot: a second sort, of the keys with
+    every other slot masked to 0xFFFFFFFF (-1, the pad, and already the
+    largest key), compacts targets and positions to the front. So the valid
+    targets are a prefix of `target`, each once; behind them stand ids
+    outside the view, which only the scatter knows (`_valid_prefix` drops
+    them), and then the pads (-1). The run sums are one kernel pass over
+    the unsorted updates (`_run_sum_kernel`)."""
     with jax.named_scope("dedup"):
         m = tile_rows.shape[0]
-        order = jnp.argsort(
-            jax.lax.bitcast_convert_type(tile_rows, jnp.uint32))
-        srows = tile_rows[order]
-        supds = tile_upds[order]
+        skeys, order = jax.lax.sort(
+            (jax.lax.bitcast_convert_type(tile_rows, jnp.uint32),
+             jnp.arange(m, dtype=jnp.int32)), num_keys=1, is_stable=False)
         first = jnp.concatenate([jnp.ones((1,), jnp.bool_),
-                                 srows[1:] != srows[:-1]])
-        seg = jnp.cumsum(first) - 1                      # (m,) segment ids
-        summed = jax.ops.segment_sum(supds, seg, num_segments=m,
-                                     indices_are_sorted=True)
-        target = jax.ops.segment_max(srows, seg, num_segments=m,
-                                     indices_are_sorted=True)
-        rep = jax.ops.segment_max(order, seg, num_segments=m,
-                                  indices_are_sorted=True)
-        num_unique = seg[-1] + 1
-        valid = jnp.arange(m) < num_unique
-        target = jnp.where(valid, target, -1).astype(jnp.int32)
-        # empty segments get INT_MIN from segment_max; mask to a safe index so
-        # downstream takes never depend on fill behavior (their rows carry
-        # target=-1 and are skipped by the kernels regardless)
-        rep = jnp.where(valid, rep, 0)
-
+                                 skeys[1:] != skeys[:-1]])
+        seg = jnp.cumsum(first.astype(jnp.int32)) - 1    # (m,) run ids
+        tkeys, rep = jax.lax.sort(
+            (jnp.where(first, skeys, jnp.uint32(0xFFFFFFFF)), order),
+            num_keys=1, is_stable=False)
         pad_n = (-m) % _scatter_block(m)
-        if pad_n:
-            target = jnp.pad(target, (0, pad_n), constant_values=-1)
-            summed = jnp.pad(summed, ((0, pad_n), (0, 0)))
-            rep = jnp.pad(rep, (0, pad_n))
-            m += pad_n
-        return target, summed, rep, m
+        target = jnp.pad(jax.lax.bitcast_convert_type(tkeys, jnp.int32),
+                         (0, pad_n), constant_values=-1)
+        rep = jnp.pad(rep, (0, pad_n))
+        m += pad_n
+        return target, _run_sums(tile_upds, order, seg, m, interpret), rep, m
 
 
 def _dedup_and_scatter(view, tile_rows, tile_upds, interpret):
-    target, summed, _, _ = _dedup_tile_updates(tile_rows, tile_upds)
+    target, summed, _, _ = _dedup_tile_updates(tile_rows, tile_upds,
+                                               interpret)
     return _scatter_add_tiles(view, target, summed, interpret)
 
 
@@ -617,7 +809,8 @@ def scatter_write_rows_packed(view: jax.Array, indices: jax.Array,
     """
     tile_rows, tile_upds = _pack_tile_updates(indices, updates, dim,
                                               view.dtype)
-    target, summed, rep, m = _dedup_tile_updates(tile_rows, tile_upds)
+    target, summed, rep, _ = _dedup_tile_updates(tile_rows, tile_upds,
+                                                 interpret)
     # any duplicate's forward tile is the same pre-update value, so the
     # representative original position's tile stands in for the segment
     with jax.named_scope("scatter"):

@@ -32,6 +32,10 @@ def force_tile_path(monkeypatch):
     monkeypatch.setattr(
         ker, "scatter_write_tiles",
         lambda *a, **k: orig_tiles(*a, **{**k, "interpret": True}))
+    orig_dedup = ker._dedup_tile_updates
+    monkeypatch.setattr(
+        ker, "_dedup_tile_updates",
+        lambda rows, upds, interpret=False: orig_dedup(rows, upds, True))
     orig_add = ker.scatter_add_rows
     monkeypatch.setattr(
         ker, "scatter_add_rows",

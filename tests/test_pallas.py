@@ -313,7 +313,7 @@ class TestScatterBlocks:
         tile_rows = rng.randint(-5, 605, (n,)).astype(np.int32)
         tile_upds = rng.randn(n, 128).astype(np.float32)
         target, summed, _, m = embedding_kernel._dedup_tile_updates(
-            jnp.asarray(tile_rows), jnp.asarray(tile_upds))
+            jnp.asarray(tile_rows), jnp.asarray(tile_upds), interpret=True)
         assert m % embedding_kernel._scatter_block(n) == 0
         count = int(embedding_kernel._valid_prefix(target, 600)[0])
         inside = np.unique(tile_rows[(tile_rows >= 0) & (tile_rows < 600)])
@@ -385,6 +385,126 @@ class TestScatterBlocks:
         assert block(40) == 40 and block(33) == 40 and block(1) == 8
         assert (embedding_kernel._SCATTER_SLOTS * block(10**6) * 512
                 <= 2**20)
+
+
+def _dedup_cases():
+    """name -> (m,) tile rows. Blocks are `_RUN_ROWS` = `_SCATTER_ROWS` =
+    256 sorted slots; ids outside a view are ids like any other to the
+    dedup (only the scatter knows the view)."""
+    rng = np.random.RandomState(29)
+    b = embedding_kernel._RUN_ROWS
+    return {
+        "all_distinct": rng.permutation(3 * b + 40),
+        "all_equal": np.full((2 * b + 9,), 77),
+        "run_of_5000_beside_singletons": rng.permutation(np.concatenate(
+            [np.arange(100), np.full((5000,), 100), 101 + np.arange(150)])),
+        # sorted: 0 x 256 | 1 x 128, 2 x 128 | 3 x 512 | 4..: every run
+        # ends where a block does, and one covers two whole blocks
+        "runs_ending_on_block_boundaries": rng.permutation(np.repeat(
+            np.arange(4 + b), [b, b // 2, b // 2, 2 * b] + [1] * b)),
+        "every_id_outside_the_view": np.concatenate(
+            [-1 - rng.randint(0, 30, (200,)), 2**30 + rng.randint(0, 30, (200,)),
+             np.full((150,), -1)]),
+        "under_one_block": rng.randint(0, 20, (37,)),
+        "no_block_multiple": rng.randint(-3, 400, (2 * b + 77,)),
+        "zipf_hot_ids": np.minimum(rng.zipf(1.05, (5 * b,)), 10**6),
+    }
+
+
+class TestDedupTileUpdates:
+    """`_dedup_tile_updates`' contract against a float64 numpy reference:
+    distinct targets ascending (read as unsigned) in front and -1 behind
+    them, float32 run sums, a representative position a run, padded to a
+    `_scatter_block` multiple."""
+
+    @pytest.mark.parametrize("width", [128, 256])
+    @pytest.mark.parametrize("case", sorted(_dedup_cases()))
+    def test_contract(self, case, width):
+        tile_rows = _dedup_cases()[case].astype(np.int32)
+        m = len(tile_rows)
+        upds = np.random.RandomState(m).randn(m, width).astype(np.float32)
+        target, summed, rep, padded = embedding_kernel._dedup_tile_updates(
+            jnp.asarray(tile_rows), jnp.asarray(upds), interpret=True)
+        target, summed, rep = map(np.asarray, (target, summed, rep))
+        assert padded % embedding_kernel._scatter_block(m) == 0
+        assert padded - m < embedding_kernel._scatter_block(m)
+        assert target.shape == rep.shape == (padded,)
+        assert summed.shape == (padded, width) and summed.dtype == np.float32
+
+        want = np.unique(tile_rows.astype(np.uint32)).astype(np.int32)
+        runs = len(want)
+        np.testing.assert_array_equal(target[:runs], want)
+        np.testing.assert_array_equal(target[runs:], -1)
+        member = tile_rows[None, :] == want[:, None]            # (runs, m)
+        sums = member.astype(np.float64) @ upds.astype(np.float64)
+        # float32 accumulation in any order: a few ulps of the sum of sizes
+        room = 4 * np.finfo(np.float32).eps * (
+            member.astype(np.float64) @ np.abs(upds).astype(np.float64))
+        assert np.all(np.abs(summed[:runs] - sums) <= room)
+        np.testing.assert_array_equal(summed[runs:], 0.0)
+        assert np.all((rep >= 0) & (rep < m))
+        # a pad's (-1) representative is read by no one: any position
+        named = want != -1
+        np.testing.assert_array_equal(tile_rows[rep[:runs]][named],
+                                      want[named])
+
+    @pytest.mark.parametrize("width", [128, 256])
+    def test_sums_keep_float32_bits_a_bf16_product_would_drop(self, width):
+        """Eight updates of 1 + 2**-20 sum to 8 + 2**-17 exactly in float32
+        in any order; a one-hot product that rounds the updates to bfloat16
+        gives 8."""
+        rng = np.random.RandomState(8)
+        tile_rows = rng.permutation(np.concatenate(
+            [np.full((8,), 5), 10 + np.arange(292)])).astype(np.int32)
+        upds = rng.randn(300, width).astype(np.float32)
+        upds[tile_rows == 5] = 1.0 + 2.0 ** -20
+        target, summed, _, _ = embedding_kernel._dedup_tile_updates(
+            jnp.asarray(tile_rows), jnp.asarray(upds), interpret=True)
+        assert int(target[0]) == 5
+        np.testing.assert_array_equal(np.asarray(summed[0]),
+                                      np.float32(8.0 + 2.0 ** -17))
+        # a run of one is its update, bit for bit
+        at = {int(t): s for s, t in enumerate(np.asarray(target)[:293])}
+        for pos in (3, 100, 299):
+            if tile_rows[pos] != 5:
+                np.testing.assert_array_equal(
+                    np.asarray(summed[at[int(tile_rows[pos])]]), upds[pos])
+
+    @pytest.mark.parametrize("width", [128, 256])
+    @pytest.mark.parametrize("case", ["all_distinct", "all_equal",
+                                      "crossing_runs", "ragged"])
+    def test_run_sum_pipeline_has_no_race_and_leaves_no_dma(
+            self, monkeypatch, case, width):
+        """`emb_run_sum` under the TPU interpreter (asynchronous DMAs,
+        counted semaphores, a copy started as late as its wait allows), in
+        blocks of 128: the fetches a step ahead, the ordered writes that
+        overlap, the zero blocks of the tail."""
+        from jax._src.pallas.mosaic.interpret import \
+            interpret_pallas_call as interpreter
+        from jax.experimental.pallas import tpu as pltpu
+        monkeypatch.setattr(embedding_kernel, "_RUN_ROWS", 128)
+        rng = np.random.RandomState(len(case))
+        tile_rows = {
+            "all_distinct": rng.permutation(512),
+            "all_equal": np.full((512,), 3),
+            "crossing_runs": np.repeat(np.arange(6),
+                                       [100, 60, 300, 1, 50, 129]),
+            "ragged": rng.randint(0, 150, (333,)),
+        }[case].astype(np.int32)
+        m = len(tile_rows)
+        upds = rng.randn(m, width).astype(np.float32)
+        target, summed, _, _ = embedding_kernel._dedup_tile_updates(
+            jnp.asarray(tile_rows), jnp.asarray(upds),
+            interpret=pltpu.InterpretParams(detect_races=True,
+                                            dma_execution_mode="on_wait"))
+        want = np.unique(tile_rows)
+        member = (tile_rows[None, :] == want[:, None]).astype(np.float64)
+        np.testing.assert_array_equal(np.asarray(target)[:len(want)], want)
+        np.testing.assert_allclose(np.asarray(summed)[:len(want)],
+                                   member @ upds.astype(np.float64),
+                                   rtol=1e-5, atol=1e-4)
+        np.testing.assert_array_equal(np.asarray(summed)[len(want):], 0.0)
+        assert not interpreter.races.races_found
 
 
 class TestShardedScatter:

@@ -193,7 +193,7 @@ def test_every_pallas_call_is_named_and_scoped():
                 assert isinstance(kw.get("name"), ast.Constant), where
                 assert scoped.get(id(node)) == kw["name"].value, where
                 names.append(kw["name"].value)
-    assert sorted(names) == ["emb_gather", "emb_scatter_add",
+    assert sorted(names) == ["emb_gather", "emb_run_sum", "emb_scatter_add",
                              "emb_scatter_write", "interaction_fused",
                              "lstm_bwd", "lstm_fwd", "topk"]
 

@@ -8,6 +8,7 @@ fixture: only the worker that is given the file loads the TPU's library.
 """
 
 import os
+import re
 
 import pytest
 
@@ -91,6 +92,61 @@ def test_emb_scatter_write_compiles_for_v5e(one_chip, no_compile_cache,
             sds((n, 128))).compile()
     assert "emb_scatter_write" in compiled.as_text()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture
+def sds(one_chip):
+    def make(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return make
+
+
+# an HLO scatter instruction (`%scatter.1 = f32[...] scatter(...)`), not the
+# kernels' names or the `scatter` scope in an instruction's metadata
+_XLA_SCATTER = re.compile(r"\sscatter\(")
+
+
+# (slots, W): the terabyte cells' two batches (dlrm_random's 65,536 packed
+# tiles compile in the guard below: a sort of that length takes the TPU's
+# compiler half a minute here), then the stateful update's gradient and
+# touch counts side by side, in blocks and in one block of a lane tile
+@pytest.mark.parametrize("m,width", [
+    (89856, 128), (3328, 128), (1000, 256), (40, 256)])
+def test_emb_run_sum_compiles_for_v5e(sds, no_compile_cache, m, width):
+    text = jax.jit(embedding_kernel._dedup_tile_updates).lower(
+        sds((m,), jnp.int32), sds((m, width))).compile().as_text()
+    assert "emb_run_sum" in text and "tpu_custom_call" in text
+    assert not _XLA_SCATTER.search(text)
+
+
+@pytest.mark.parametrize("form", ["add", "write_packed"])
+def test_sparse_update_holds_no_xla_scatter(sds, no_compile_cache, form):
+    """The guard that the dedup's segment ops do not come back: XLA's
+    scatter costs 8-13 ns an element here (PERF.md, PR 29), and the whole
+    sparse update is sorts, element-wise passes and three kernels."""
+    if form == "add":
+        n = 89856
+        lowered = jax.jit(embedding_kernel.scatter_add_rows).lower(
+            sds((11739136, 128)), sds((n,), jnp.int32), sds((n, 128)))
+    else:
+        n = 65536
+        lowered = jax.jit(
+            lambda v, i, u, t: embedding_kernel.scatter_write_rows_packed(
+                v, i, u, t, 64)
+        ).lower(sds((4000000, 128)), sds((n,), jnp.int32), sds((n, 64)),
+                sds((n, 128)))
+    text = lowered.compile().as_text()
+    assert "emb_run_sum" in text
+    assert not _XLA_SCATTER.search(text)
+    assert _XLA_SCATTER.search("  %scatter.1 = f32[8,128]{1,0} scatter(%a)")
+    # the rows `emb_run_sum` fetches one DMA each stay in HBM: XLA's own
+    # choice for a temporary of this size is VMEM (`S(1)` in a layout),
+    # from where a row DMA costs 1.7x (PERF.md, PR 29)
+    operands = re.search(r"%emb_run_sum\.\d+ = \S+ custom-call\(([^)]*)\)",
+                         text).group(1)
+    updates = operands.split(", ")[-1]
+    made = re.search(rf"^\s*{re.escape(updates)} = (\S+) ", text, re.M)
+    assert "S(1)" not in made.group(1), made.group(0)
 
 
 def test_expert_walk_compiles_for_v5e(one_chip, no_compile_cache):
