@@ -39,18 +39,31 @@ def canonical_loss(name: str) -> str:
     return name
 
 
-def sparse_categorical_crossentropy(logits, labels):
+def row_weights(weights, rows: int):
+    """`weights`, one a row of a sample, laid over the `rows` rows of a
+    batch: fp32 (rows,)."""
+    w = jnp.asarray(weights, jnp.float32).reshape(-1)
+    return jnp.tile(w, rows // w.size)
+
+
+def sparse_categorical_crossentropy(logits, labels, weights=None):
     """labels: any int shape whose element count equals the number of logit
     rows (e.g. [batch], [batch, 1], or [batch, seq] against folded
     [batch*seq, classes] logits as in NMT); logits: float[..., classes].
 
     Reference kernel sparse_categorical_crossentropy_loss_backward writes
     softmax(logits) - onehot(label); grad of this fn reproduces it.
+
+    `weights`, one a row of a sample (`compile(loss_weights=)`), make the
+    loss mean(w_r * nll_r): a sum of terms, each a mean over its own rows
+    (0 masks a row). Weights of mean 1 keep an unweighted loss's scale.
     """
     logits2 = logits.reshape(-1, logits.shape[-1])
     labels = labels.astype(jnp.int32).reshape(-1)
     logp = jax.nn.log_softmax(logits2.astype(jnp.float32), axis=-1)
     nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
+    if weights is not None:
+        nll = nll * row_weights(weights, nll.shape[0])
     return jnp.mean(nll)
 
 

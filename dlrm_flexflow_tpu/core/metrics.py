@@ -20,6 +20,8 @@ from typing import Dict, List
 
 import jax.numpy as jnp
 
+from .losses import row_weights
+
 METRICS_ACCURACY = "accuracy"
 METRICS_CATEGORICAL_CROSSENTROPY = "categorical_crossentropy"
 METRICS_SPARSE_CATEGORICAL_CROSSENTROPY = "sparse_categorical_crossentropy"
@@ -52,9 +54,12 @@ def canonical_metrics(names: List[str]) -> List[str]:
     return out
 
 
-def compute_metrics(metrics: List[str], loss_type: str, preds, labels) -> Dict[str, jnp.ndarray]:
+def compute_metrics(metrics: List[str], loss_type: str, preds, labels,
+                    weights=None) -> Dict[str, jnp.ndarray]:
     """Per-batch *sums* (plus count) so epochs accumulate exactly like the
-    reference's PerfMetrics::update (metrics_functions.cc)."""
+    reference's PerfMetrics::update (metrics_functions.cc). `weights` (one
+    a row of a sample, as the loss takes them) weigh `sparse_cce`, so that
+    the reported metric is the loss that was trained."""
     out: Dict[str, jnp.ndarray] = {}
     preds32 = preds.astype(jnp.float32)
     labels32 = labels.astype(jnp.float32)
@@ -80,8 +85,10 @@ def compute_metrics(metrics: List[str], loss_type: str, preds, labels) -> Dict[s
             lab = labels.astype(jnp.int32).reshape(-1)
             logp = jnp.log(jnp.clip(preds32.reshape(-1, preds32.shape[-1]),
                                     1e-12, None))
+            picked = jnp.take_along_axis(logp, lab[:, None], axis=-1)
             out["sparse_cce"] = -jnp.sum(
-                jnp.take_along_axis(logp, lab[:, None], axis=-1))
+                picked if weights is None
+                else picked[:, 0] * row_weights(weights, lab.shape[0]))
         elif m == METRICS_CATEGORICAL_CROSSENTROPY:
             logp = jnp.log(jnp.clip(preds32, 1e-12, None))
             out["cce"] = -jnp.sum(labels32 * logp)

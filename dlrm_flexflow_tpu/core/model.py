@@ -25,6 +25,7 @@ compile-once/execute-many.
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 import time
 from collections import deque
@@ -301,10 +302,10 @@ class FFModel:
                                   causal, name).outputs[0]
 
     def rms_norm(self, input_tensor, eps=1e-6, to_compute_dtype=False,
-                 name=None):
+                 zero_centered=True, name=None):
         from ..ops.norm import RMSNorm
         return RMSNorm(self, input_tensor, eps, to_compute_dtype,
-                       name).outputs[0]
+                       zero_centered, name).outputs[0]
 
     def gated_delta_net(self, x, num_k_heads, num_v_heads, head_k_dim,
                         head_v_dim, conv_width=4, eps=1e-6,
@@ -327,15 +328,33 @@ class FFModel:
                               rotary_dim, rope_theta, eps,
                               kernel_initializer, name).outputs[0]
 
+    def latent_attention(self, x, num_heads, q_rank, kv_rank, nope_dim,
+                         rope_dim, v_dim, rope_theta=1e6, eps=1e-5,
+                         kernel_initializer=None, name=None):
+        """Causal multi-head latent attention, expanded form (see
+        ops/attention.LatentAttention)."""
+        from ..ops.attention import LatentAttention
+        return LatentAttention(self, x, num_heads, q_rank, kv_rank, nope_dim,
+                               rope_dim, v_dim, rope_theta, eps,
+                               kernel_initializer, name).outputs[0]
+
+    def gated_mlp(self, x, hidden_dim, kernel_initializer=None, name=None):
+        """A dense SwiGLU feed-forward part (see ops/linear.GatedMLP)."""
+        from ..ops.linear import GatedMLP
+        return GatedMLP(self, x, hidden_dim, kernel_initializer,
+                        name).outputs[0]
+
     def moe(self, x, num_experts, top_k, expert_dim, shared_dim,
             experts_held=None, expert_offset=0, norm_topk=True,
-            kernel_initializer=None, name=None):
+            scoring="softmax", routed_scale=1.0, shared_gate=True,
+            balance_rate=0.0, kernel_initializer=None, name=None):
         """Sparse experts, the share of them this chip holds (see
         ops/moe.MoE): the router scores all `num_experts`, the op computes
         experts `expert_offset .. expert_offset + experts_held - 1`."""
         from ..ops.moe import MoE
         return MoE(self, x, num_experts, top_k, expert_dim, shared_dim,
-                   experts_held, expert_offset, norm_topk,
+                   experts_held, expert_offset, norm_topk, scoring,
+                   routed_scale, shared_gate, balance_rate,
                    kernel_initializer, name).outputs[0]
 
     def lstm_stack(self, input_tensor, hidden, num_layers, name=None):
@@ -422,8 +441,16 @@ class FFModel:
                 metrics: Sequence[str] = ("mean_squared_error",),
                 mesh: Optional[Mesh] = None,
                 strategies: Optional[StrategyMap] = None,
-                final_tensor: Optional[Tensor] = None):
+                final_tensor: Optional[Tensor] = None,
+                loss_weights=None):
         """Resolve strategy + build the jitted train/eval steps.
+
+        `loss_weights`: one weight a logit row of a sample, for a loss of
+        several terms over one logits tensor (`losses.
+        sparse_categorical_crossentropy`): a model that predicts through
+        one head twice (multi-token prediction) concatenates the two
+        passes' rows and weighs them 1 and lambda, 0 where a row has no
+        target. The loss and the reported `sparse_cce` are mean(w * nll).
 
         Mirrors reference FFModel::compile (model.cc:1003-1080): [load or
         search strategies] → per-op partitioning/weights → label tensor →
@@ -436,6 +463,12 @@ class FFModel:
             weight_decay=self.config.weight_decay)
         self.loss_type = losses_mod.canonical_loss(loss_type)
         self.metrics = metrics_mod.canonical_metrics(list(metrics))
+        if loss_weights is not None and self.loss_type != (
+                losses_mod.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY):
+            raise ValueError("loss_weights weigh the rows of "
+                             "sparse_categorical_crossentropy only")
+        self.loss_weights = (None if loss_weights is None else
+                             np.asarray(loss_weights, np.float32).reshape(-1))
         self.mesh = mesh if mesh is not None else make_mesh(
             num_devices=self.config.num_devices)
         ndev = int(np.prod([self.mesh.shape[a] for a in self.mesh.axis_names]))
@@ -503,6 +536,13 @@ class FFModel:
         else:
             lshape, ldtype = preds.shape, jnp.float32
         self.label_tensor = Tensor(lshape, ldtype, name="label")
+        if self.loss_weights is not None and (
+                preds.shape[0] % (self.config.batch_size
+                                  * self.loss_weights.size)):
+            raise ValueError(
+                f"{self.loss_weights.size} loss weights a sample do not lay "
+                f"over {preds.shape[0]} logit rows of a batch of "
+                f"{self.config.batch_size}")
 
         self._build_shardings()
         self._build_steps()
@@ -1006,6 +1046,9 @@ class FFModel:
         self._anomaly_policy = policy
         sentinel = policy != "none"
         plain_loss = losses_mod.loss_fn(self.loss_type)
+        row_w = self.loss_weights
+        if row_w is not None:
+            plain_loss = functools.partial(plain_loss, weights=row_w)
 
         def loss_f(logits, labels):
             with jax.named_scope("ff.loss"):
@@ -1216,8 +1259,8 @@ class FFModel:
                     mpreds = jax.nn.softmax(preds.astype(jnp.float32), axis=-1)
                 else:
                     mpreds = preds
-                mets = metrics_mod.compute_metrics(metric_names, loss_type,
-                                                   mpreds, batch["label"])
+                mets = metrics_mod.compute_metrics(
+                    metric_names, loss_type, mpreds, batch["label"], row_w)
                 # accumulate running sums ON DEVICE inside the step (the
                 # reference accumulates in device memory with atomics and folds
                 # once per epoch, metrics_functions.cu:57-135; host-side
@@ -1447,7 +1490,8 @@ class FFModel:
     def expert_stats(self) -> Dict[str, Dict[str, np.ndarray]]:
         """{expert op: {"tokens", "pairs" (experts held,), "rows"}}: the
         expert ops' cumulative counters (ops/moe.py), read from the op
-        state. The step never reads them back; this call does."""
+        state; for an op with a balance bias also "load" (all experts,)
+        and the "bias" itself. The step reads back the bias alone."""
         return {name: {k: np.asarray(v) for k, v in st.items()}
                 for name, st in (self.op_state or {}).items()
                 if "pairs" in st}
@@ -1460,6 +1504,12 @@ class FFModel:
             for e, n in enumerate(st["pairs"]):
                 yield ("ff_moe_pairs_total",
                        {"op": name, "expert": str(e)}, int(n))
+            for e, n in enumerate(st.get("load", ())):
+                yield ("ff_moe_load_total",
+                       {"op": name, "expert": str(e)}, int(n))
+            if "bias" in st:
+                yield ("ff_moe_bias_abs_max", {"op": name},
+                       float(np.abs(st["bias"]).max()))
 
     def _init_params_sharded(self, op_keys):
         """Parameters of the LARGE ops (>= _SHARDED_INIT_BYTES), each born

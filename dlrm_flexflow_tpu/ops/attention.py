@@ -18,7 +18,10 @@ Self-attention: pass the same tensor as q, k, v.
 `GatedAttention` is the block attention of a sparse language model
 (Qwen3-Next): grouped KV heads, a per-head RMS norm on q and k, rotary
 embedding on the leading part of each head, and a sigmoid gate on the
-output. Both ops go through one `attend` core, which picks the route: the
+output. `LatentAttention` is the multi-head latent attention of the
+DeepSeek-V3 / GLM-4.7 line: queries and keys/values through low-rank
+bottlenecks, one rotary key shared by every head, a value head of its own
+width. All three go through one `attend` core, which picks the route: the
 ring, jax's shipped flash kernel, query blocks through XLA, or the dense
 scores, by whether the scores fit beside what the model keeps resident.
 """
@@ -33,14 +36,16 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..core.initializers import DEFAULT_KERNEL_INIT, ZeroInitializer
+from ..core.initializers import (ConstantInitializer, DEFAULT_KERNEL_INIT,
+                                 ZeroInitializer)
 from ..core.op import Op, ParamDef
 from ..parallel.pconfig import ParallelConfig
 
 
 def _online_softmax_block(q, k, v, m_prev, num_prev, den_prev, mask):
-    """One K/V block of flash-style attention. q:(b,h,sq,hd) k/v:(b,h,sk,hd);
-    m/num/den are fp32 running stats. mask:(sq,sk) additive (0 or -inf)."""
+    """One K/V block of flash-style attention. q:(b,h,sq,hd) k:(b,h,sk,hd)
+    v:(b,h,sk,vd); m/num/den are fp32 running stats. mask:(sq,sk) additive
+    (0 or -inf)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32)
     s = s * (1.0 / math.sqrt(q.shape[-1])) + mask
@@ -67,10 +72,11 @@ def _group_heads(q, kv_heads: int):
 
 def _attention_local(q, k, v, causal, q_offset=0, k_offset=0):
     """Dense attention on local blocks (single shard or within-block).
-    q (b, h, sq, hd); k, v (b, hk, sk, hd) with hk dividing h: each K/V
-    head serves h // hk query heads, without being repeated in memory."""
+    q (b, h, sq, hd); k (b, hk, sk, hd), v (b, hk, sk, vd) with hk dividing
+    h: each K/V head serves h // hk query heads, without being repeated in
+    memory."""
     b, h, sq, hd = q.shape
-    hk, sk = k.shape[1], k.shape[2]
+    hk, sk, vd = k.shape[1], k.shape[2], v.shape[3]
     if causal:
         qpos = q_offset + jnp.arange(sq)[:, None]
         kpos = k_offset + jnp.arange(sk)[None, :]
@@ -82,11 +88,11 @@ def _attention_local(q, k, v, causal, q_offset=0, k_offset=0):
     qg = _group_heads(q, hk).reshape(b, hk, g * sq, hd)
     mask = jnp.tile(mask, (g, 1))
     m0 = jnp.full((b, hk, g * sq), -jnp.inf, jnp.float32)
-    num0 = jnp.zeros((b, hk, g * sq, hd), jnp.float32)
+    num0 = jnp.zeros((b, hk, g * sq, vd), jnp.float32)
     den0 = jnp.zeros((b, hk, g * sq), jnp.float32)
     m, num, den = _online_softmax_block(qg, k, v, m0, num0, den0, mask)
     out = num / jnp.maximum(den, 1e-20)[..., None]
-    return out.reshape(b, h, sq, hd)
+    return out.reshape(b, h, sq, vd)
 
 
 BLOCK_Q = 1024      # query rows a step of the blockwise route attends with
@@ -111,7 +117,7 @@ def _attention_blockwise(q, k, v, causal, block_q: int):
         return None, one(qi, i).astype(q.dtype)
 
     _, out = lax.scan(body, None, (qb, jnp.arange(nb)))
-    return out.transpose(1, 2, 0, 3, 4).reshape(b, h, sq, hd)
+    return out.transpose(1, 2, 0, 3, 4).reshape(b, h, sq, v.shape[3])
 
 
 def _resident_bytes(model) -> float:
@@ -162,18 +168,34 @@ def _flash_gate(model, op_name, q, k) -> bool:
 
 
 def attend(model, op_name, q, k, v, causal: bool):
-    """softmax(q k^T / sqrt(hd)) v on one shard: q (b, h, s, hd); k, v
-    (b, hk, s, hd), hk dividing h. The one place both attention ops pick
-    their route. Returns q's dtype."""
+    """softmax(q k^T / sqrt(hd)) v on one shard: q (b, h, s, hd); k (b, hk,
+    s, hd), v (b, hk, s, vd), hk dividing h. The one place the attention
+    ops pick their route. The value head may have a width of its own
+    (latent attention): the dense and the blockwise route take it as it
+    is; the flash kernel wants one width (whole lane tiles above 128), so
+    what is narrower is padded with zeros (a zero feature adds nothing to
+    a score, a zero value column gives a zero output column, which is cut
+    off again) and the scale stays that of the true `hd`. At GLM-4.7's
+    published 256 / 256 nothing is padded. Returns q's dtype."""
     h, hk, sq = q.shape[1], k.shape[1], q.shape[2]
-    if _flash_gate(model, op_name, q, k):
+    hd, vd = q.shape[3], v.shape[3]
+    if vd % 64 == 0 and _flash_gate(model, op_name, q, k):
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             flash_attention)
         if hk != h:     # the kernel wants one K/V head a query head
             k, v = (jnp.repeat(t, h // hk, axis=1) for t in (k, v))
-        return flash_attention(
-            q, k, v, causal=causal,
-            sm_scale=1.0 / math.sqrt(q.shape[-1])).astype(q.dtype)
+        # one width for q, k and v, and above one lane tile whole tiles
+        # (the kernel refuses 192)
+        w = max(hd, vd)
+        w = w if w <= 128 else -(-w // 128) * 128
+
+        def widen(t):
+            return t if t.shape[3] == w else jnp.pad(
+                t, ((0, 0),) * 3 + ((0, w - t.shape[3]),))
+
+        out = flash_attention(widen(q), widen(k), widen(v), causal=causal,
+                              sm_scale=1.0 / math.sqrt(hd))
+        return (out if vd == w else out[..., :vd]).astype(q.dtype)
     if not _scores_fit(model, q, k) and sq % BLOCK_Q == 0 and sq > BLOCK_Q:
         return _attention_blockwise(q, k, v, causal, BLOCK_Q)
     return _attention_local(q, k, v, causal).astype(q.dtype)
@@ -440,6 +462,108 @@ class GatedAttention(Op):
         h, hk, hd = self.num_heads, self.num_kv_heads, self.head_dim
         proj = 2.0 * s * d * (2 * h * hd + 2 * hk * hd + h * hd)
         return proj + 2.0 * s * s * h * hd      # the causal half of 4 s^2
+
+    def mxu_utilization_factor(self) -> float:
+        return 0.25
+
+
+class LatentAttention(Op):
+    """Causal multi-head latent attention (MLA) in its expanded, training
+    form, as DeepSeek-V3 and GLM-4.7 have it. No bias anywhere; RMS norms
+    scale by `w` (init 1).
+
+        c_q = RMSNorm(x W_qa)                     (q_rank)
+        q   = c_q W_qb          a head: [q_nope | q_rope]
+        [c_kv | k_rope] = x W_kva                 (kv_rank | rope_dim)
+        c_kv W_kvb              a head: [k_nope | v],  c_kv = RMSNorm(c_kv)
+        q_h = [q_nope_h | R(q_rope_h)],  k_h = [k_nope_h | R(k_rope)]
+
+    The rotary key is ONE head that every head shares; the value head has
+    its own width `v_dim` (`attend` takes it as it is, or pads for the
+    flash kernel). The absorbed form, which attends in the latent space,
+    is a decoder's: training gains nothing from it."""
+
+    type_name = "LatentAttention"
+    recompute = True     # the backward recomputes the block's insides
+
+    def __init__(self, model, x, num_heads: int, q_rank: int, kv_rank: int,
+                 nope_dim: int, rope_dim: int, v_dim: int,
+                 rope_theta: float = 1e6, eps: float = 1e-5,
+                 kernel_initializer=None, name: Optional[str] = None):
+        if x.num_dims != 3:
+            raise ValueError("attention expects (batch, seq, dim) inputs")
+        if rope_dim % 2:
+            raise ValueError("rope_dim must be even")
+        super().__init__(model, [x], name)
+        self.num_heads = int(num_heads)
+        self.q_rank, self.kv_rank = int(q_rank), int(kv_rank)
+        self.nope_dim, self.rope_dim = int(nope_dim), int(rope_dim)
+        self.v_dim = int(v_dim)
+        self.rope_theta, self.eps = float(rope_theta), float(eps)
+        self.kernel_initializer = kernel_initializer or DEFAULT_KERNEL_INIT()
+        self.outputs = [self._make_output(x.shape, x.dtype)]
+
+    def param_defs(self) -> Dict[str, ParamDef]:
+        d, h = self.inputs[0].shape[-1], self.num_heads
+        init, one, f32 = self.kernel_initializer, ConstantInitializer(1.0), \
+            jnp.float32
+        return {
+            "wq_a": ParamDef((d, self.q_rank), f32, init),
+            "q_norm": ParamDef((self.q_rank,), f32, one),
+            "wq_b": ParamDef(
+                (self.q_rank, h * (self.nope_dim + self.rope_dim)), f32, init),
+            "wkv_a": ParamDef((d, self.kv_rank + self.rope_dim), f32, init),
+            "kv_norm": ParamDef((self.kv_rank,), f32, one),
+            "wkv_b": ParamDef(
+                (self.kv_rank, h * (self.nope_dim + self.v_dim)), f32, init),
+            "wo": ParamDef((h * self.v_dim, d), f32, init),
+        }
+
+    def apply(self, params, xs, *, training=False, rng=None):
+        from .norm import rms_norm
+        (x,) = xs
+        b, s, _ = x.shape
+        h, nope, rope = self.num_heads, self.nope_dim, self.rope_dim
+        cdt = self.model.compute_dtype
+
+        def mm(a, w):
+            return jnp.dot(a.astype(cdt), params[w].astype(cdt),
+                           preferred_element_type=jnp.float32)
+
+        with jax.named_scope("q_proj"):
+            c_q = rms_norm(mm(x, "wq_a"), params["q_norm"], self.eps, False)
+            q = mm(c_q, "wq_b").reshape(b, s, h, nope + rope)
+        with jax.named_scope("kv_proj"):
+            ckr = mm(x, "wkv_a")
+            c_kv = rms_norm(ckr[..., :self.kv_rank], params["kv_norm"],
+                            self.eps, False)
+            kv = mm(c_kv, "wkv_b").reshape(b, s, h, nope + self.v_dim)
+        with jax.named_scope("rope"):
+            cos, sin = rotary_tables(s, rope, self.rope_theta)
+            q = jnp.concatenate(
+                [q[..., :nope], apply_rotary(q[..., nope:], cos, sin)],
+                axis=-1).astype(cdt)
+            k_rope = apply_rotary(ckr[:, :, None, self.kv_rank:], cos, sin)
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, h, rope))],
+                axis=-1).astype(cdt)
+            v = kv[..., nope:].astype(cdt)
+        with jax.named_scope("attend"):
+            attn = attend(self.model, self.name, q.transpose(0, 2, 1, 3),
+                          k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+                          True).transpose(0, 2, 1, 3)    # (b, s, h, v_dim)
+        with jax.named_scope("out_proj"):
+            out = mm(attn.reshape(b, s, h * self.v_dim), "wo")
+        return [out.astype(x.dtype)]
+
+    def flops_per_sample(self) -> float:
+        _, s, d = self.outputs[0].shape
+        h, qk = self.num_heads, self.nope_dim + self.rope_dim
+        proj = 2.0 * s * (d * self.q_rank + self.q_rank * h * qk
+                          + d * (self.kv_rank + self.rope_dim)
+                          + self.kv_rank * h * (self.nope_dim + self.v_dim)
+                          + h * self.v_dim * d)
+        return proj + s * s * h * (qk + self.v_dim)   # the causal half
 
     def mxu_utilization_factor(self) -> float:
         return 0.25

@@ -17,6 +17,7 @@ from __future__ import annotations
 import math
 from typing import Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 
 from ..core.initializers import DEFAULT_BIAS_INIT, DEFAULT_KERNEL_INIT
@@ -100,3 +101,43 @@ class Linear(Op):
     def flops_per_sample(self) -> float:
         rows = math.prod(self.outputs[0].shape[1:-1]) if self.outputs[0].num_dims > 2 else 1
         return 2.0 * rows * self.in_dim * self.out_dim
+
+
+class GatedMLP(Op):
+    """A dense SwiGLU feed-forward part: down(silu(x W_gate) * (x W_up)),
+    no bias: the leading dense layers of an expert model. Products in the
+    compute dtype with fp32 accumulation, as the expert op's."""
+
+    type_name = "GatedMLP"
+    recompute = True     # the backward recomputes the block's insides
+
+    def __init__(self, model, input_tensor, hidden_dim: int,
+                 kernel_initializer=None, name: Optional[str] = None):
+        super().__init__(model, [input_tensor], name)
+        self.dim = int(input_tensor.shape[-1])
+        self.hidden_dim = int(hidden_dim)
+        self.kernel_initializer = kernel_initializer or DEFAULT_KERNEL_INIT()
+        self.outputs = [self._make_output(input_tensor.shape,
+                                          input_tensor.dtype)]
+
+    def param_defs(self) -> Dict[str, ParamDef]:
+        d, f, init = self.dim, self.hidden_dim, self.kernel_initializer
+        return {"w_gate": ParamDef((d, f), jnp.float32, init),
+                "w_up": ParamDef((d, f), jnp.float32, init),
+                "w_down": ParamDef((f, d), jnp.float32, init)}
+
+    def apply(self, params, xs, *, training=False, rng=None):
+        (x,) = xs
+        cdt = self.model.compute_dtype
+
+        def mm(a, w):
+            return jnp.dot(a, params[w].astype(cdt),
+                           preferred_element_type=jnp.float32)
+
+        xc = x.astype(cdt)
+        h = (jax.nn.silu(mm(xc, "w_gate")) * mm(xc, "w_up")).astype(cdt)
+        return [mm(h, "w_down").astype(x.dtype)]
+
+    def flops_per_sample(self) -> float:
+        rows = math.prod(self.outputs[0].shape[1:-1])
+        return 6.0 * rows * self.dim * self.hidden_dim

@@ -1,13 +1,22 @@
 """Sparse mixture of experts, as one chip of an expert-parallel group runs it.
 
 The router scores every token against ALL `num_experts` experts (fp32),
-keeps the `top_k` largest probabilities and, with `norm_topk`, divides them
-by their sum. This op holds `experts_held` of the experts, those numbered
+keeps the `top_k` largest scores and, with `norm_topk`, divides them by
+their sum. The model says which router it has (`scoring`):
+
+    softmax  scores are softmax(x W_r); chosen = top-k of them (Qwen3-Next)
+    sigmoid  scores are sigmoid(x W_r); chosen = top-k of score + bias, the
+             weights the bare scores of the chosen, normalised and scaled
+             by `routed_scale` (DeepSeek-V3's `noaux_tc`, GLM-4.7's)
+
+This op holds `experts_held` of the experts, those numbered
 `expert_offset` .. `expert_offset + experts_held - 1`, and computes their
 part of the result:
 
     routed = sum over e in (top-k and held) of w_e * down_e(silu(gate_e x) * up_e x)
     out    = routed + sigmoid(x . w_sg) * shared_expert(x)
+
+(`shared_gate=False`: the shared expert is added as it is.)
 
 The weights w_e are normalised over all `top_k` chosen experts, held here
 or not; what the experts held elsewhere would add is theirs to compute (on
@@ -37,6 +46,15 @@ writes over them.
 Counters (cumulative, in `op_state`, never read back by the step):
 `tokens` seen, `pairs` routed to each held expert, `rows` the chunks
 computed. rows - sum(pairs) carried no pair.
+
+With `balance_rate` gamma > 0 the op also keeps, in `op_state`, the
+router's correction `bias` (num_experts,) and a cumulative `load`, the
+pairs routed to EVERY expert, held here or not. No gradient trains the
+bias; the step does: after its forward, b_e += gamma * sign(mean(c) - c_e)
+with c the step's own load on this chip's tokens (a deployment would sum c
+over its data-parallel ranks first). The next step reads it back: it is
+the one `op_state` entry that changes the result. An op that does not ask
+has neither, and compiles to what it compiled to without them.
 """
 
 from __future__ import annotations
@@ -197,7 +215,9 @@ class MoE(Op):
     def __init__(self, model, x, num_experts: int, top_k: int,
                  expert_dim: int, shared_dim: int,
                  experts_held: Optional[int] = None, expert_offset: int = 0,
-                 norm_topk: bool = True, kernel_initializer=None,
+                 norm_topk: bool = True, scoring: str = "softmax",
+                 routed_scale: float = 1.0, shared_gate: bool = True,
+                 balance_rate: float = 0.0, kernel_initializer=None,
                  name: Optional[str] = None):
         held = num_experts if experts_held is None else experts_held
         if not 0 < top_k <= num_experts:
@@ -207,11 +227,18 @@ class MoE(Op):
             raise ValueError(
                 f"experts {expert_offset}..{expert_offset + held - 1} are "
                 f"not among the {num_experts} the router scores")
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router scoring {scoring!r}")
+        if balance_rate and scoring != "sigmoid":
+            raise ValueError("the balance bias corrects sigmoid scores")
         super().__init__(model, [x], name)
         self.num_experts, self.top_k = int(num_experts), int(top_k)
         self.expert_dim, self.shared_dim = int(expert_dim), int(shared_dim)
         self.experts_held, self.expert_offset = int(held), int(expert_offset)
         self.norm_topk = bool(norm_topk)
+        self.scoring, self.routed_scale = scoring, float(routed_scale)
+        self.shared_gate = bool(shared_gate)
+        self.balance_rate = float(balance_rate)
         self.kernel_initializer = kernel_initializer or DEFAULT_KERNEL_INIT()
         self.tokens = 1
         for n in x.shape[:-1]:
@@ -223,7 +250,7 @@ class MoE(Op):
         d = self.inputs[0].shape[-1]
         n, f, fs = self.experts_held, self.expert_dim, self.shared_dim
         init, f32 = self.kernel_initializer, jnp.float32
-        return {
+        defs = {
             "router": ParamDef((d, self.num_experts), f32, init),
             "w_gate": ParamDef((n, d, f), f32, init),
             "w_up": ParamDef((n, d, f), f32, init),
@@ -231,23 +258,40 @@ class MoE(Op):
             "shared_gate": ParamDef((d, fs), f32, init),
             "shared_up": ParamDef((d, fs), f32, init),
             "shared_down": ParamDef((fs, d), f32, init),
-            "shared_router": ParamDef((d,), f32, init),
         }
+        if self.shared_gate:
+            defs["shared_router"] = ParamDef((d,), f32, init)
+        return defs
 
     def state_defs(self) -> Dict[str, ParamDef]:
         zero, i32 = ZeroInitializer(), jnp.int32
-        return {"tokens": ParamDef((), i32, zero),
+        defs = {"tokens": ParamDef((), i32, zero),
                 "pairs": ParamDef((self.experts_held,), i32, zero),
                 "rows": ParamDef((), i32, zero)}
+        if self.balance_rate:
+            defs["bias"] = ParamDef((self.num_experts,), jnp.float32, zero)
+            defs["load"] = ParamDef((self.num_experts,), i32, zero)
+        return defs
 
-    def route(self, params, xt):
-        """(weights (T, k) fp32, experts (T, k) int32) of every token."""
+    def route(self, params, xt, bias=None):
+        """(weights (T, k) fp32, experts (T, k) int32) of every token.
+        `bias` (num_experts,), the sigmoid router's correction, moves the
+        choice and never the weights."""
         logits = jnp.dot(xt.astype(jnp.float32), params["router"],
                          precision=lax.Precision.HIGHEST)
-        top_p, top_e = lax.top_k(jax.nn.softmax(logits, axis=-1), self.top_k)
+        if self.scoring == "softmax":
+            top_p, top_e = lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                     self.top_k)
+            if self.norm_topk:
+                top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            return top_p, top_e
+        scores = jax.nn.sigmoid(logits)
+        _, top_e = lax.top_k(scores if bias is None else scores + bias,
+                             self.top_k)
+        top_p = jnp.take_along_axis(scores, top_e, axis=-1)
         if self.norm_topk:
-            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-        return top_p, top_e
+            top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
+        return top_p * self.routed_scale, top_e
 
     def apply_with_state(self, params, state, xs, *, training=False,
                          rng=None):
@@ -257,7 +301,11 @@ class MoE(Op):
         held, cdt, f32 = self.experts_held, self.model.compute_dtype, \
             jnp.float32
         with jax.named_scope("router"):
-            pair_w, pair_e = self.route(params, xt)
+            # without a bias `route` is called as it always was: tests and
+            # the benchmark's fault injections replace that form
+            pair_w, pair_e = (self.route(params, xt, state["bias"])
+                              if self.balance_rate
+                              else self.route(params, xt))
         with jax.named_scope("dispatch"):
             local = pair_e.reshape(-1) - self.expert_offset
             # the pairs of experts held elsewhere get the key `held` and
@@ -278,14 +326,24 @@ class MoE(Op):
 
             h = _silu_mul(mm(xc, params["shared_gate"]),
                           mm(xc, params["shared_up"])).astype(cdt)
-            gate = jax.nn.sigmoid(jnp.sum(
-                xt.astype(f32) * params["shared_router"], axis=-1,
-                keepdims=True))
-            shared = gate * mm(h, params["shared_down"])
+            if self.shared_gate:
+                gate = jax.nn.sigmoid(jnp.sum(
+                    xt.astype(f32) * params["shared_router"], axis=-1,
+                    keepdims=True))
+                shared = gate * mm(h, params["shared_down"])
+            else:
+                shared = mm(h, params["shared_down"])
         new_state = {"tokens": state["tokens"] + xt.shape[0],
                      "pairs": state["pairs"] + counts,
                      "rows": state["rows"] + self.chunk_rows * _walk_plan(
                          self.chunk_rows, counts)[2]}
+        if self.balance_rate:
+            with jax.named_scope("balance"):
+                load = jnp.sum(pair_e.reshape(-1, 1) == jnp.arange(
+                    self.num_experts), axis=0, dtype=jnp.int32)
+                new_state["load"] = state["load"] + load
+                new_state["bias"] = state["bias"] + self.balance_rate * \
+                    jnp.sign(jnp.mean(load.astype(f32)) - load)
         return [(routed + shared).reshape(x.shape).astype(x.dtype)], new_state
 
     def apply(self, params, xs, *, training=False, rng=None):

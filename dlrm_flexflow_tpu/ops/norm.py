@@ -6,8 +6,9 @@ no bias: `x / sqrt(mean(x^2) + eps) * scale`. The statistics are fp32
 whatever the activation dtype; the output keeps the input's dtype.
 
 The op stores the scale as `1 + w` with `w` initialised 0 (the Qwen3-Next
-convention: weight decay then pulls the scale to 1, not to 0); `rms_norm`,
-which the block ops call on their insides, also takes a plain scale.
+convention: weight decay then pulls the scale to 1, not to 0) or, with
+`zero_centered=False`, as `w` initialised 1 (GLM's, DeepSeek's); `rms_norm`,
+which the block ops call on their insides, takes either.
 `to_compute_dtype` hands the result on in the model's compute dtype: the
 norm before a wide head, whose logits then take half the bytes.
 """
@@ -19,7 +20,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ..core.initializers import ZeroInitializer
+from ..core.initializers import ConstantInitializer, ZeroInitializer
 from ..core.op import Op, ParamDef
 
 
@@ -37,23 +38,27 @@ class RMSNorm(Op):
     type_name = "RMSNorm"
 
     def __init__(self, model, input_tensor, eps: float = 1e-6,
-                 to_compute_dtype: bool = False, name: Optional[str] = None):
+                 to_compute_dtype: bool = False, zero_centered: bool = True,
+                 name: Optional[str] = None):
         super().__init__(model, [input_tensor], name)
         self.dim = int(input_tensor.shape[-1])
         self.eps = float(eps)
         self.to_compute_dtype = bool(to_compute_dtype)
+        self.zero_centered = bool(zero_centered)
         self.outputs = [self._make_output(input_tensor.shape,
                                           input_tensor.dtype)]
 
     def param_defs(self) -> Dict[str, ParamDef]:
-        return {"weight": ParamDef((self.dim,), jnp.float32,
-                                   ZeroInitializer())}
+        init = (ZeroInitializer() if self.zero_centered
+                else ConstantInitializer(1.0))
+        return {"weight": ParamDef((self.dim,), jnp.float32, init)}
 
     def apply(self, params, xs, *, training=False, rng=None):
         (x,) = xs
         dtype = (self.model.compute_dtype if self.to_compute_dtype
                  else x.dtype)
-        return [rms_norm(x, params["weight"], self.eps, True).astype(dtype)]
+        return [rms_norm(x, params["weight"], self.eps,
+                         self.zero_centered).astype(dtype)]
 
     def hbm_io_factor(self) -> float:
         # one pass over the activation, fused with its neighbours

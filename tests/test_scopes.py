@@ -10,6 +10,7 @@ import glob
 import os
 import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -37,6 +38,22 @@ MODELS = {
 PLUMBING = {"parameter", "constant", "broadcast", "bitcast", "copy",
             "tuple", "get-tuple-element", "iota"}
 STEP_COUNTER = "jit(train_step)/add"
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """A test that ran earlier in this process may have switched JAX's
+    persistent cache on (`use_compile_cache`, through an example's main):
+    a step that took over a second to compile on a loaded machine is then
+    LOADED the next time, with the scopes it was stored under, and these
+    tests read the scopes of the step they have just built."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
 
 
 def _fit(kind, epochs=1, **cfg_kw):
@@ -105,10 +122,7 @@ def test_every_instruction_of_the_step_has_a_path(kind):
                for p in paths), sorted(p for p in paths if emb in p)
 
 
-def test_every_instruction_of_the_qwen3_next_step_has_a_path():
-    """The census on the language model (ISSUE 26): block ops recomputed
-    in the backward, the expert walk's loops, Adam; and the scopes inside
-    the three block ops."""
+def _qwen3_next(model):
     from dlrm_flexflow_tpu.models.qwen3_next import (Qwen3NextConfig,
                                                      build_qwen3_next)
     cfg = Qwen3NextConfig(
@@ -118,26 +132,58 @@ def test_every_instruction_of_the_qwen3_next_step_has_a_path():
         num_attention_heads=2, num_key_value_heads=1, head_dim=16,
         num_experts=8, num_experts_per_tok=2, moe_intermediate_size=16,
         shared_expert_intermediate_size=16, experts_held=4)
-    model = ff.FFModel(ff.FFConfig(batch_size=2, seed=2))
     build_qwen3_next(model, cfg, 32)
+    tokens = (jax.numpy.arange(4 * 33).reshape(4, 33) % 64).astype("int32")
+    inside = {"l0_moe": ["router", "dispatch", "experts", "combine",
+                         "shared"],
+              "l0_delta": ["proj", "conv", "scan", "gate_norm"],
+              "l3_attn": ["qk_norm_rope", "attend", "gate"]}
+    return {}, {"tokens": tokens[:, :-1]}, tokens[:, 1:], inside
+
+
+def _glm4_moe_lite(model):
+    """ISSUE 30: latent attention's five scopes in every block, the dense
+    block, the balance update, the module's ops under `ff.mtp_*`."""
+    from dlrm_flexflow_tpu.models.glm4_moe_lite import (
+        Glm4MoeLiteConfig, build_glm4_moe_lite, loss_weights, mtp_labels)
+    cfg = Glm4MoeLiteConfig(
+        vocab_size=64, hidden_size=32, num_hidden_layers=2,
+        num_attention_heads=2, q_lora_rank=12, kv_lora_rank=8,
+        qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=8,
+        intermediate_size=48, moe_intermediate_size=16, n_routed_experts=8,
+        num_experts_per_tok=2, experts_held=4)
+    build_glm4_moe_lite(model, cfg, 32)
+    ids, labels = mtp_labels(
+        (np.arange(4 * 33).reshape(4, 33) % 64).astype("int32"))
+    mla = ["q_proj", "kv_proj", "rope", "attend", "out_proj"]
+    inside = {"l0_mla": mla, "l1_mla": mla, "mtp_mla": mla, "l0_mlp": [],
+              "l1_moe": ["router", "dispatch", "experts", "combine",
+                         "shared", "balance"],
+              "mtp_moe": ["router", "experts", "balance"]}
+    return ({"loss_weights": loss_weights(32, cfg.mtp_loss_weight)},
+            {"tokens": ids}, labels, inside)
+
+
+@pytest.mark.parametrize("build", [_qwen3_next, _glm4_moe_lite],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_every_instruction_of_a_language_model_step_has_a_path(build):
+    """The census on the language models (ISSUEs 26, 30): block ops
+    recomputed in the backward, the expert walk's loops, Adam; and the
+    scopes inside the block ops."""
+    model = ff.FFModel(ff.FFConfig(batch_size=2, seed=2))
+    compile_kw, x, y, inside = build(model)
     model.compile(ff.AdamOptimizer(alpha=1e-3),
                   "sparse_categorical_crossentropy",
                   ["sparse_categorical_crossentropy"],
-                  mesh=make_mesh(devices=jax.devices()[:1]))
+                  mesh=make_mesh(devices=jax.devices()[:1]), **compile_kw)
     model.init_layers()
-    tokens = (jax.numpy.arange(4 * 33).reshape(4, 33) % 64).astype("int32")
-    model.fit({"tokens": tokens[:, :-1]}, tokens[:, 1:], epochs=1,
-              verbose=False)
+    model.fit(x, y, epochs=1, verbose=False)
     paths = set(_census().values())
     found = {m for p in paths for m in re.findall(r"ff\.[\w.]+", p)}
     wanted = {f"ff.{op.name}" for op in model.ops
               if type(op).__name__ not in ("InputOp", "Reshape")}
     wanted |= {"ff.update.embed", "ff.optimizer", "ff.loss", "ff.metrics"}
     assert wanted <= found, wanted - found
-    inside = {"l0_moe": ["router", "dispatch", "experts", "combine",
-                         "shared"],
-              "l0_delta": ["proj", "conv", "scan", "gate_norm"],
-              "l3_attn": ["qk_norm_rope", "attend", "gate"]}
     for op, subs in inside.items():
         for sub in subs:
             assert any(re.search(rf"ff\.{op}\b.*/{sub}\b", p)
@@ -146,6 +192,12 @@ def test_every_instruction_of_the_qwen3_next_step_has_a_path():
         assert any(f"transpose(jvp(ff.{op}))" in p for p in paths), op
         assert any("checkpoint" in p or "remat" in p
                    for p in paths if f"ff.{op}" in p), op
+    if compile_kw:
+        # both loss terms are one weighted sum under `ff.loss`, forward and
+        # backward, and the module's ops have names of their own
+        assert any("ff.loss" in p and "transpose" in p for p in paths)
+        assert {"ff.mtp_enorm", "ff.mtp_hnorm", "ff.mtp_eh_proj",
+                "ff.mtp_final_norm", "ff.mtp_rows"} <= found
 
 
 def test_scopes_are_metadata_only(monkeypatch):

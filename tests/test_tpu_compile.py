@@ -189,3 +189,117 @@ def test_chunked_delta_rule_compiles_for_v5e(one_chip, no_compile_cache):
         sds((b, s, h, d)), sds((b, s, h, d)), sds((b, s, h, d)),
         sds((b, s, h), jnp.float32), sds((b, s, h), jnp.float32)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+# configuration -> (the most the compiler may count for the whole step, in
+# bytes: arguments + outputs - aliased + temporaries; flash kernel calls).
+# GLM-4.7-Flash (ISSUE 30) counted 13,859,009,024 when it was added: six
+# blocks of latent attention through the flash route, forward, recomputed,
+# dq and dkv. Qwen3-Next's bound is what its step counted BEFORE the expert
+# op learned a second router: an op that does not ask for the sigmoid
+# router, the bias or the ungated shared expert compiles to what it did.
+LM_STEPS = {"glm_4_7_flash": (16_000_000_000, 24),
+            "qwen3_next_80b_a3b": (14_474_101_248, 4)}
+
+
+@pytest.mark.parametrize("name", sorted(LM_STEPS))
+def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
+                                              monkeypatch, name):
+    """The whole train step at the benchmark's published widths, built as
+    the cell's family builds it, lowered on shapes for the described chip
+    (the gates that ask the attached backend are told it is the TPU, here
+    in the test)."""
+    import json
+    import numpy as np
+    import dlrm_flexflow_tpu as ff
+    from dlrm_flexflow_tpu.ops import attention, embedding
+    from dlrm_flexflow_tpu.parallel.mesh import make_mesh
+    from perfbench import manifest as mf
+    monkeypatch.setattr(
+        embedding, "_pallas_common",
+        lambda model, op_name, width_ok: bool(width_ok)
+        and model.config.use_pallas)
+    monkeypatch.setattr(attention, "_hbm_bytes", lambda: 15.75 * 2**30)
+    config = mf.load_config(mf.load(), name)
+    family = mf.load_family(config["family"])
+    mcfg = family.model_config(config, family.held_table_rows(config, 1)[0])
+    seq, opt = int(config["seq_len"]), config["optimizer"]
+    model = ff.FFModel(ff.FFConfig.parse_args(
+        ["-b", "1", "--compute-dtype", config["compute_dtype"]]))
+    kw = {}
+    if name == "glm_4_7_flash":
+        from dlrm_flexflow_tpu.models.glm4_moe_lite import (
+            Glm4MoeLiteConfig, build_glm4_moe_lite, loss_weights)
+        build_glm4_moe_lite(model, Glm4MoeLiteConfig.from_dict(mcfg), seq)
+        kw["loss_weights"] = loss_weights(seq, config["mtp_loss_weight"])
+    else:
+        from dlrm_flexflow_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                                         build_qwen3_next)
+        build_qwen3_next(model, Qwen3NextConfig.from_dict(mcfg), seq)
+    model.compile(
+        ff.AdamOptimizer(alpha=opt["alpha"], beta1=opt["beta1"],
+                         beta2=opt["beta2"], epsilon=opt["epsilon"]),
+        config["loss"], [config["loss"]],
+        mesh=make_mesh(devices=[one_chip._device]), **kw)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def of(defs):
+        return {n: sds(d.shape, d.dtype) for n, d in defs.items()}
+
+    params = {op.name: of(op.param_defs()) for op in model.ops
+              if op.param_defs()}
+    assert sum(int(np.prod(a.shape)) for sub in params.values()
+               for a in sub.values()) == sum(
+        family.parameter_counts(config).values())
+    opt_state = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(model.optimizer.init_state, params))
+    op_state = {op.name: of(op.state_defs()) for op in model.ops
+                if hasattr(op, "state_defs")}
+    batch = {t.name: sds(t.shape, t.dtype) for t in model.input_tensors}
+    batch["label"] = sds(model.label_tensor.shape, model.label_tensor.dtype)
+    compiled = model._train_step.lower(
+        params, opt_state, op_state,
+        {k: sds((), jnp.float32) for k in model._msums_keys}, batch,
+        sds((), jnp.int32)).compile()
+    ma = compiled.memory_analysis()
+    most, kernels = LM_STEPS[name]
+    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes) <= most
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    # the balance update is in the step that asked for it, and in no other
+    assert ("/balance/" in text) == (name == "glm_4_7_flash")
+
+
+@pytest.mark.parametrize("hd,vd", [(192, 128), (128, 192), (256, 256)])
+def test_attend_flash_route_compiles_for_v5e(one_chip, no_compile_cache,
+                                             monkeypatch, hd, vd):
+    """`attend`'s flash route with a value head of its own width (latent
+    attention, ISSUE 30): jax's kernel wants one width for q, k and v, in
+    whole lane tiles above 128 (it refuses 192 when it is traced), so the
+    route pads; forward, dkv and dq are three kernels."""
+    import dlrm_flexflow_tpu as ff
+    from dlrm_flexflow_tpu.ops import attention, embedding
+    monkeypatch.setattr(embedding, "_pallas_common",
+                        lambda model, op_name, width_ok: bool(width_ok))
+    monkeypatch.setattr(attention, "_scores_fit", lambda *a: False)
+
+    class Model:
+        ops, optimizer, mesh = [], ff.AdamOptimizer(), None
+        config = ff.FFConfig()
+
+    def sds(width):
+        return jax.ShapeDtypeStruct((1, 4, 1024, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        out = attention.attend(Model(), "attn", q, k, v, True)
+        assert out.shape == (1, 4, 1024, vd)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sds(hd), sds(hd), sds(vd)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
