@@ -372,10 +372,62 @@ def kernel_checks(dry):
             raise AssertionError("top-k ids differ from the exact scan")
         return float(np.max(np.abs(gs - ws))), 0.0   # bit-identical contract
 
+    def _flash_case(hd, vd):
+        """`attend`'s flash route at the blocks `_flash_blocks` chooses
+        (ISSUE 31) against the dense scores, forward and the three
+        gradients, causal, on a model that fills the chip (so the scores
+        do not fit and the gate opens). Returns the worse of the forward's
+        error over 1.6e-2 (a bf16 ulp of an output past 2; PR 30 read 4e-3)
+        and a gradient's, relative to its largest element, over 1.5e-2
+        (read 7e-3). The dry run's gate stays shut: it walks the
+        blockwise route."""
+        import dlrm_flexflow_tpu as ff
+        from dlrm_flexflow_tpu.ops import attention as at
+
+        class Full:
+            param_bytes = staticmethod(at._hbm_bytes)
+
+        class Model:
+            ops, optimizer, mesh = [Full], ff.AdamOptimizer(), None
+            config = ff.FFConfig()
+
+        h, s = (2, 2 * at.BLOCK_Q) if dry else (4, 2048)
+        q, k, v = (jnp.asarray(rng.randn(1, h, s, d_), jnp.bfloat16)
+                   for d_ in (hd, hd, vd))
+        if not dry and not at._flash_gate(Model, "attn", q, k):
+            raise AssertionError("the flash gate stayed shut on the chip")
+
+        def both(fn):
+            def loss(q, k, v):
+                out = fn(q, k, v).astype(jnp.float32)
+                return jnp.sum(out * jnp.cos(out)), out
+            (_, out), grads = jax.jit(jax.value_and_grad(
+                loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+            return out, grads
+
+        got, got_g = both(lambda q, k, v: at.attend(Model, "attn", q, k, v,
+                                                    True))
+        want, want_g = both(lambda q, k, v: at._attention_local(q, k, v,
+                                                                True))
+        fwd = err(got, want)
+        rel = max(err(g, w_) / float(jnp.max(jnp.abs(
+            jnp.asarray(w_, jnp.float32)))) for g, w_ in zip(got_g, want_g))
+        log(f"[flash {hd}/{vd}] blocks "
+            f"{at._flash_blocks(1, s, s, at._flash_width(hd, vd))[1]} "
+            f"forward {fwd:.3g} gradients {rel:.3g}")
+        return max(fwd / 1.6e-2, rel / 1.5e-2), 1.0
+
+    def flash_attend_256():
+        return _flash_case(256, 256)
+
+    def flash_attend_192_128():
+        return _flash_case(192, 128)
+
     return [(f.__name__, f) for f in (
         gather_d128, scatter_rmw_packed_d64, scatter_write_packed_d64,
         scatter_rmw_d128, fused_interaction_terabyte,
-        lstm_resident_fwd_bwd, topk_d128_k100)]
+        lstm_resident_fwd_bwd, topk_d128_k100, flash_attend_256,
+        flash_attend_192_128)]
 
 
 def phase_kernels(dry, report):

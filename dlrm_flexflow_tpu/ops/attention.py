@@ -156,7 +156,9 @@ def _flash_gate(model, op_name, q, k) -> bool:
     Shares the common Pallas routing policy (TPU backend, opt-in, single
     chip, not host-offloaded — a Mosaic call can't run under compute_on)
     and adds the shapes/dtypes validated on hardware (bf16, head_dim %64,
-    seq %512). Taken only where the scores do not fit (`_scores_fit`)."""
+    seq %512). Taken only where the scores do not fit (`_scores_fit`).
+    Everything admitted here gets blocks from `_flash_blocks`, which walks
+    a ladder down to 512: a sequence the gate admits always has a rung."""
     from .embedding import _pallas_gate
     if not _pallas_gate(model, op_name, True):
         return False
@@ -165,6 +167,83 @@ def _flash_gate(model, op_name, q, k) -> bool:
             and sq % 512 == 0 and sk % 512 == 0):
         return False
     return not _scores_fit(model, q, k)
+
+
+# What Mosaic grants one kernel on the v5e (its scoped VMEM; jax's flash
+# kernel passes no limit of its own, so this is the budget its blocks fit).
+FLASH_VMEM_BYTES = 16 * 2**20
+
+# The three flash kernels, their blocks in `BlockSizes`' order: the field of
+# each block, the most it gets, the sequence it tiles (0 the queries, 1 the
+# keys), where its major block stands (itself, for a major), and the VMEM
+# the kernel needs at head width w.
+# The most: what the sweep on the v5e chose at (1, 20, 8192, 256) and (1,
+# 16, 8192, 256), bf16, causal, within 5% of the fastest at a width of 128
+# too (`benchmarks/flash_block_sweep.py`; the table is in PERF.md, PR 31).
+# dq's key blocks stay at 128 on purpose: jax broadcasts `di` to (b, h, sq,
+# block_k_major_dq) fp32 in HBM, which at 128 is the array `l` and `m`
+# already need; 512 is 1 ms of 9.5 faster at the first shape, for 250 MB.
+# The bytes: the pipeline's tiles twice over (bf16 q, k, v, o, do, dk, dv,
+# dq; fp32 l, m, di at 128 lanes), the fp32 accumulators, and the fp32
+# score tiles Mosaic keeps on its stack. That last term is fitted, from
+# above, to what the compiler counted when it refused a block for a
+# described v5e: the forward's unrolled minor steps do not share their
+# score tiles, the backward kernels' do.
+_FLASH_KERNELS = {
+    "fwd": (("block_q", "block_k_major", "block_k"),
+            (1024, 1024, 1024), (0, 1, 1), (0, 1, 1),
+            lambda w, bq, bkm, bk:
+            12 * bq * w + 3072 * bq + 8 * bkm * w + 8 * bq * bkm),
+    "dkv": (("block_q_major_dkv", "block_q_dkv", "block_k_major_dkv",
+             "block_k_dkv"),
+            (1024, 512, 1024, 1024), (0, 0, 1, 1), (0, 0, 2, 2),
+            lambda w, bqm, bq, bkm, bk:
+            8 * bqm * w + 3072 * bqm + 24 * bkm * w + 7 * bq * bk),
+    "dq": (("block_q_dq", "block_k_major_dq", "block_k_dq"),
+           (2048, 128, 128), (0, 1, 1), (0, 1, 1),
+           lambda w, bq, bkm, bk:
+           16 * bq * w + 3072 * bq + 8 * bkm * w + 5 * bq * bk),
+}
+
+
+def _flash_width(hd, vd):
+    """The one width jax's flash kernel gets for q, k and v: the wider of
+    the two heads, and above one lane tile whole tiles (it refuses 192)."""
+    w = max(hd, vd)
+    return w if w <= 128 else -(-w // 128) * 128
+
+
+def _flash_blocks(b, sq, sk, w):
+    """The blocks jax's flash kernel runs with, from what `attend` can see
+    and nothing else: (batch, query length, key length, padded head width)
+    -> (BlockSizes, the scope name that says them). jax's default is 128
+    everywhere, where the kernel is all grid-step overhead (0.3 us a step
+    against 0.085 us of products; PERF.md, PR 31). One rule for every
+    caller and each kernel of `_FLASH_KERNELS`: a major block is the most
+    it may get, halved until it divides its sequence (the gate admits
+    multiples of 512, so that ends there at the latest); a minor block is
+    the most it may get or its major, the smaller; and while the kernel
+    needs more than `FLASH_VMEM_BYTES` the wider major is halved (the key
+    side on a tie). A wider head gets smaller blocks, a narrower one no
+    larger: past the sweep's choice the causal diagonal wastes more than
+    the saved grid steps give. `b` does not enter yet: the kernel's batch
+    block stays 1."""
+    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
+    del b
+    chosen, names = {"block_b": 1}, []
+    for kernel, (fields, most, tiles, major, need) in _FLASH_KERNELS.items():
+        blocks = list(most)
+        for i in set(major):
+            while (sq, sk)[tiles[i]] % blocks[i]:
+                blocks[i] //= 2
+        while True:
+            blocks = [min(most[i], blocks[m]) for i, m in enumerate(major)]
+            if need(w, *blocks) <= FLASH_VMEM_BYTES:
+                break
+            blocks[max(set(major), key=lambda i: (blocks[i], tiles[i]))] //= 2
+        chosen.update(zip(fields, blocks))
+        names.append("_".join([kernel, *map(str, blocks)]))
+    return BlockSizes(**chosen), "flash_" + ".".join(names)
 
 
 def attend(model, op_name, q, k, v, causal: bool):
@@ -176,7 +255,9 @@ def attend(model, op_name, q, k, v, causal: bool):
     what is narrower is padded with zeros (a zero feature adds nothing to
     a score, a zero value column gives a zero output column, which is cut
     off again) and the scale stays that of the true `hd`. At GLM-4.7's
-    published 256 / 256 nothing is padded. Returns q's dtype."""
+    published 256 / 256 nothing is padded. The kernel's blocks come from
+    `_flash_blocks(b, sq, sk, w)`, w the padded width, and the scope
+    around the call names them. Returns q's dtype."""
     h, hk, sq = q.shape[1], k.shape[1], q.shape[2]
     hd, vd = q.shape[3], v.shape[3]
     if vd % 64 == 0 and _flash_gate(model, op_name, q, k):
@@ -184,17 +265,17 @@ def attend(model, op_name, q, k, v, causal: bool):
             flash_attention)
         if hk != h:     # the kernel wants one K/V head a query head
             k, v = (jnp.repeat(t, h // hk, axis=1) for t in (k, v))
-        # one width for q, k and v, and above one lane tile whole tiles
-        # (the kernel refuses 192)
-        w = max(hd, vd)
-        w = w if w <= 128 else -(-w // 128) * 128
+        w = _flash_width(hd, vd)
 
         def widen(t):
             return t if t.shape[3] == w else jnp.pad(
                 t, ((0, 0),) * 3 + ((0, w - t.shape[3]),))
 
-        out = flash_attention(widen(q), widen(k), widen(v), causal=causal,
-                              sm_scale=1.0 / math.sqrt(hd))
+        blocks, scope = _flash_blocks(q.shape[0], sq, k.shape[2], w)
+        with jax.named_scope(scope):
+            out = flash_attention(widen(q), widen(k), widen(v),
+                                  causal=causal, sm_scale=1.0 / math.sqrt(hd),
+                                  block_sizes=blocks)
         return (out if vd == w else out[..., :vd]).astype(q.dtype)
     if not _scores_fit(model, q, k) and sq % BLOCK_Q == 0 and sq > BLOCK_Q:
         return _attention_blockwise(q, k, v, causal, BLOCK_Q)

@@ -272,15 +272,34 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
     assert text.count('custom_call_target="tpu_custom_call"') == kernels
     # the balance update is in the step that asked for it, and in no other
     assert ("/balance/" in text) == (name == "glm_4_7_flash")
+    # the blocks the flash kernels run with, as a scope under `attend`
+    # (ISSUE 31): both models attend at heads of 256 over 8,192 tokens
+    scope = attention._flash_blocks(1, seq, seq, 256)[1]
+    assert f"/attend/{scope}/jit(flash_attention)/pallas_call" in text
 
 
-@pytest.mark.parametrize("hd,vd", [(192, 128), (128, 192), (256, 256)])
+# (heads, query length, key length, q/k width, v width, causal): the three
+# width pairs of ISSUE 30 at a short sequence; then ISSUE 31's: both
+# language-model cells' shapes, a sequence the largest block does not
+# divide, one block a sequence (the kernel's single-step form), unequal
+# lengths, a non-causal case and three lane tiles of width
+@pytest.mark.parametrize("h,sq,sk,hd,vd,causal", [
+    (4, 1024, 1024, 192, 128, True), (4, 1024, 1024, 128, 192, True),
+    (4, 1024, 1024, 256, 256, True),
+    (20, 8192, 8192, 256, 256, True), (16, 8192, 8192, 256, 256, True),
+    (4, 1536, 1536, 128, 128, True), (4, 512, 512, 64, 64, True),
+    (4, 2048, 4096, 128, 128, False), (4, 4096, 4096, 128, 128, False),
+    (4, 4096, 4096, 384, 384, True)])
 def test_attend_flash_route_compiles_for_v5e(one_chip, no_compile_cache,
-                                             monkeypatch, hd, vd):
-    """`attend`'s flash route with a value head of its own width (latent
-    attention, ISSUE 30): jax's kernel wants one width for q, k and v, in
-    whole lane tiles above 128 (it refuses 192 when it is traced), so the
-    route pads; forward, dkv and dq are three kernels."""
+                                             monkeypatch, h, sq, sk, hd, vd,
+                                             causal):
+    """`attend`'s flash route, forward and gradient, at the blocks
+    `_flash_blocks` chooses: Mosaic refuses for the described chip what
+    does not fit its scoped VMEM, which interpret mode and the CPU never
+    see. With a value head of its own width (latent attention, ISSUE 30)
+    the route pads: jax's kernel wants one width for q, k and v, in whole
+    lane tiles above 128 (it refuses 192 when it is traced). Forward, dkv
+    and dq are three kernels, and the compiled text names their blocks."""
     import dlrm_flexflow_tpu as ff
     from dlrm_flexflow_tpu.ops import attention, embedding
     monkeypatch.setattr(embedding, "_pallas_common",
@@ -291,15 +310,28 @@ def test_attend_flash_route_compiles_for_v5e(one_chip, no_compile_cache,
         ops, optimizer, mesh = [], ff.AdamOptimizer(), None
         config = ff.FFConfig()
 
-    def sds(width):
-        return jax.ShapeDtypeStruct((1, 4, 1024, width), jnp.bfloat16,
+    def sds(s, width):
+        return jax.ShapeDtypeStruct((1, h, s, width), jnp.bfloat16,
                                     sharding=one_chip)
 
     def loss(q, k, v):
-        out = attention.attend(Model(), "attn", q, k, v, True)
-        assert out.shape == (1, 4, 1024, vd)
+        out = attention.attend(Model(), "attn", q, k, v, causal)
+        assert out.shape == (1, h, sq, vd)
         return jnp.sum(out.astype(jnp.float32))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        sds(hd), sds(hd), sds(vd)).compile().as_text()
+        sds(sq, hd), sds(sk, hd), sds(sk, vd)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+    sizes, scope = attention._flash_blocks(
+        1, sq, sk, attention._flash_width(hd, vd))
+    # the forward under the scope `attend` opens, the backward kernels
+    # under jax's own names besides
+    assert re.search(rf"jvp\({re.escape(scope)}\)/jit\(flash_attention\)"
+                     r"/pallas_call", text)
+    assert (f"flash_mha_bwd_dkv_block_q_major={sizes.block_q_major_dkv}"
+            f"_block_q={sizes.block_q_dkv}"
+            f"_block_k_major={sizes.block_k_major_dkv}"
+            f"_block_k={sizes.block_k_dkv}/pallas_call") in text
+    assert (f"flash_mha_bwd_dq_block_q_major={sizes.block_q_dq}"
+            f"_block_k_major={sizes.block_k_major_dq}"
+            f"_block_k={sizes.block_k_dq}/pallas_call") in text
