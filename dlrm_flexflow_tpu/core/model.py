@@ -317,16 +317,29 @@ class FFModel:
                              head_v_dim, conv_width, eps,
                              kernel_initializer, name).outputs[0]
 
+    def mamba2(self, x, num_heads, head_dim, n_groups, state_size,
+               conv_width=4, chunk_size=128, eps=1e-5, dt_min=1e-3,
+               dt_max=0.1, dt_floor=1e-4, kernel_initializer=None,
+               name=None):
+        """The state-space mixer of a hybrid language model (see
+        ops/mamba.Mamba2)."""
+        from ..ops.mamba import Mamba2
+        return Mamba2(self, x, num_heads, head_dim, n_groups, state_size,
+                      conv_width, chunk_size, eps, dt_min, dt_max, dt_floor,
+                      kernel_initializer, name).outputs[0]
+
     def gated_attention(self, x, num_heads, num_kv_heads, head_dim,
                         rotary_dim, rope_theta=1e7, eps=1e-6,
-                        kernel_initializer=None, name=None):
-        """Causal grouped-query self-attention with q/k norm, partial
-        rotary embedding and a sigmoid output gate (see
-        ops/attention.GatedAttention)."""
+                        kernel_initializer=None, name=None, gate=True,
+                        qk_norm=True):
+        """Causal grouped-query self-attention with, as the model says,
+        q/k norm, partial rotary embedding (`rotary_dim` 0: none) and a
+        sigmoid output gate (see ops/attention.GatedAttention)."""
         from ..ops.attention import GatedAttention
         return GatedAttention(self, x, num_heads, num_kv_heads, head_dim,
                               rotary_dim, rope_theta, eps,
-                              kernel_initializer, name).outputs[0]
+                              kernel_initializer, name, gate,
+                              qk_norm).outputs[0]
 
     def latent_attention(self, x, num_heads, q_rank, kv_rank, nope_dim,
                          rope_dim, v_dim, rope_theta=1e6, eps=1e-5,
@@ -347,7 +360,8 @@ class FFModel:
     def moe(self, x, num_experts, top_k, expert_dim, shared_dim,
             experts_held=None, expert_offset=0, norm_topk=True,
             scoring="softmax", routed_scale=1.0, shared_gate=True,
-            balance_rate=0.0, kernel_initializer=None, name=None):
+            balance_rate=0.0, kernel_initializer=None, name=None,
+            activation="swiglu"):
         """Sparse experts, the share of them this chip holds (see
         ops/moe.MoE): the router scores all `num_experts`, the op computes
         experts `expert_offset .. expert_offset + experts_held - 1`."""
@@ -355,7 +369,7 @@ class FFModel:
         return MoE(self, x, num_experts, top_k, expert_dim, shared_dim,
                    experts_held, expert_offset, norm_topk, scoring,
                    routed_scale, shared_gate, balance_rate,
-                   kernel_initializer, name).outputs[0]
+                   kernel_initializer, name, activation).outputs[0]
 
     def lstm_stack(self, input_tensor, hidden, num_layers, name=None):
         """N stacked LSTM layers in ONE scan (see ops/rnn.LSTMStack:

@@ -15,10 +15,13 @@ LSTM grid). This op is the designed-in TPU upgrade: long-context scaling via
 
 Self-attention: pass the same tensor as q, k, v.
 
-`GatedAttention` is the block attention of a sparse language model
-(Qwen3-Next): grouped KV heads, a per-head RMS norm on q and k, rotary
-embedding on the leading part of each head, and a sigmoid gate on the
-output. `LatentAttention` is the multi-head latent attention of the
+`GatedAttention` is the block attention of a sparse language model:
+grouped KV heads, a projection to `num_heads * head_dim` whatever the
+hidden size, no bias, and what the model says of three more parts, a
+per-head RMS norm on q and k, rotary embedding on the leading part of each
+head, a sigmoid gate on the output (Qwen3-Next has all three, Nemotron-H
+none: plain grouped-query attention). `LatentAttention` is the multi-head
+latent attention of the
 DeepSeek-V3 / GLM-4.7 line: queries and keys/values through low-rank
 bottlenecks, one rotary key shared by every head, a value head of its own
 width. All three go through one `attend` core, which picks the route: the
@@ -460,13 +463,18 @@ def apply_rotary(x, cos, sin):
 
 
 class GatedAttention(Op):
-    """Causal self-attention as Qwen3-Next's full-attention layers have it:
-    `wq` makes, per query head, a query and a gate (head-major, [query |
-    gate] inside a head); K/V have `num_kv_heads` heads, each serving
-    num_heads / num_kv_heads query heads; q and k take a per-head RMS norm
-    (scale 1 + w) and rotary embedding on their first `rotary_dim`
-    features; the attended values are multiplied by sigmoid(gate) before
-    the output projection. No bias anywhere."""
+    """Causal grouped-query self-attention: K/V have `num_kv_heads` heads,
+    each serving num_heads / num_kv_heads query heads. No bias anywhere.
+    As Qwen3-Next's full-attention layers have it (the defaults): `wq`
+    makes, per query head, a query and a gate (head-major, [query | gate]
+    inside a head); q and k take a per-head RMS norm (scale 1 + w) and
+    rotary embedding on their first `rotary_dim` features; the attended
+    values are multiplied by sigmoid(gate) before the output projection.
+    The model says which of the three it has: `gate=False` (`wq` makes the
+    queries alone), `qk_norm=False` (no `q_norm`, `k_norm`), `rotary_dim=0`
+    (no position embedding). With none of them this is the plain
+    grouped-query attention of Nemotron-H, whose state-space layers carry
+    the position."""
 
     type_name = "GatedAttention"
     recompute = True     # the backward recomputes the block's insides
@@ -474,7 +482,8 @@ class GatedAttention(Op):
     def __init__(self, model, x, num_heads: int, num_kv_heads: int,
                  head_dim: int, rotary_dim: int, rope_theta: float = 1e7,
                  eps: float = 1e-6, kernel_initializer=None,
-                 name: Optional[str] = None):
+                 name: Optional[str] = None, gate: bool = True,
+                 qk_norm: bool = True):
         if x.num_dims != 3:
             raise ValueError("attention expects (batch, seq, dim) inputs")
         if num_heads % num_kv_heads != 0:
@@ -488,6 +497,7 @@ class GatedAttention(Op):
         self.rotary_dim = int(rotary_dim)
         self.rope_theta = float(rope_theta)
         self.eps = float(eps)
+        self.gate, self.qk_norm = bool(gate), bool(qk_norm)
         self.kernel_initializer = kernel_initializer or DEFAULT_KERNEL_INIT()
         self.outputs = [self._make_output(x.shape, x.dtype)]
 
@@ -495,14 +505,18 @@ class GatedAttention(Op):
         d = self.inputs[0].shape[-1]
         h, hk, hd = self.num_heads, self.num_kv_heads, self.head_dim
         init = self.kernel_initializer
-        return {
-            "wq": ParamDef((d, h * hd * 2), jnp.float32, init),
+        defs = {
+            "wq": ParamDef((d, h * hd * (2 if self.gate else 1)),
+                           jnp.float32, init),
             "wk": ParamDef((d, hk * hd), jnp.float32, init),
             "wv": ParamDef((d, hk * hd), jnp.float32, init),
             "q_norm": ParamDef((hd,), jnp.float32, ZeroInitializer()),
             "k_norm": ParamDef((hd,), jnp.float32, ZeroInitializer()),
             "wo": ParamDef((h * hd, d), jnp.float32, init),
         }
+        if not self.qk_norm:
+            del defs["q_norm"], defs["k_norm"]
+        return defs
 
     def apply(self, params, xs, *, training=False, rng=None):
         from .norm import rms_norm
@@ -516,32 +530,41 @@ class GatedAttention(Op):
             return jnp.dot(xc, w.astype(cdt),
                            preferred_element_type=jnp.float32)
 
-        qg = proj(params["wq"]).reshape(b, s, h, 2 * hd)
-        q, gate = qg[..., :hd], qg[..., hd:]
-        k = proj(params["wk"]).reshape(b, s, hk, hd)
-        v = proj(params["wv"]).reshape(b, s, hk, hd).astype(cdt)
-        with jax.named_scope("qk_norm_rope"):
-            cos, sin = rotary_tables(s, self.rotary_dim, self.rope_theta)
-            q = apply_rotary(rms_norm(q, params["q_norm"], self.eps, True),
-                             cos, sin).astype(cdt)
-            k = apply_rotary(rms_norm(k, params["k_norm"], self.eps, True),
-                             cos, sin).astype(cdt)
+        with jax.named_scope("qkv_proj"):
+            q = proj(params["wq"]).reshape(b, s, h, -1)
+            q, gate = q[..., :hd], q[..., hd:]      # no gate: an empty slice
+            k = proj(params["wk"]).reshape(b, s, hk, hd)
+            v = proj(params["wv"]).reshape(b, s, hk, hd).astype(cdt)
+        if self.qk_norm or self.rotary_dim:
+            with jax.named_scope("qk_norm_rope"):
+                if self.qk_norm:
+                    q = rms_norm(q, params["q_norm"], self.eps, True)
+                    k = rms_norm(k, params["k_norm"], self.eps, True)
+                if self.rotary_dim:
+                    cos, sin = rotary_tables(s, self.rotary_dim,
+                                             self.rope_theta)
+                    q, k = (apply_rotary(t, cos, sin) for t in (q, k))
+        q, k = q.astype(cdt), k.astype(cdt)
         with jax.named_scope("attend"):
             attn = attend(self.model, self.name, q.transpose(0, 2, 1, 3),
                           k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
                           True)
             attn = attn.transpose(0, 2, 1, 3)            # (b, s, h, hd)
-        with jax.named_scope("gate"):
-            gated = (attn.astype(jnp.float32)
-                     * jax.nn.sigmoid(gate)).astype(cdt)
-        out = jnp.dot(gated.reshape(b, s, h * hd), params["wo"].astype(cdt),
-                      preferred_element_type=jnp.float32)
+        if self.gate:
+            with jax.named_scope("gate"):
+                attn = (attn.astype(jnp.float32)
+                        * jax.nn.sigmoid(gate)).astype(cdt)
+        with jax.named_scope("out_proj"):
+            out = jnp.dot(attn.reshape(b, s, h * hd),
+                          params["wo"].astype(cdt),
+                          preferred_element_type=jnp.float32)
         return [out.astype(x.dtype)]
 
     def flops_per_sample(self) -> float:
         _, s, d = self.outputs[0].shape
         h, hk, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        proj = 2.0 * s * d * (2 * h * hd + 2 * hk * hd + h * hd)
+        proj = 2.0 * s * d * ((3 if self.gate else 2) * h * hd
+                              + 2 * hk * hd)
         return proj + 2.0 * s * s * h * hd      # the causal half of 4 s^2
 
     def mxu_utilization_factor(self) -> float:

@@ -63,15 +63,16 @@ def l2_normalize(x, eps: float = 1e-6):
                            + eps)
 
 
-def causal_depthwise_conv(x, w):
-    """x (b, s, c), w (c, width): y_t = sum_j w[:, j] x_(t - width + 1 + j),
-    positions before the sequence read as zero. fp32 out."""
+def causal_depthwise_conv(x, w, bias=None):
+    """x (b, s, c), w (c, width): y_t = sum_j w[:, j] x_(t - width + 1 + j)
+    (+ bias (c,), where the model has one), positions before the sequence
+    read as zero. fp32 out."""
     width = w.shape[1]
     s = x.shape[1]
     xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
     w32 = w.astype(jnp.float32)
-    return sum(xp[:, j:j + s, :] * w32[None, None, :, j]
-               for j in range(width))
+    y = sum(xp[:, j:j + s, :] * w32[None, None, :, j] for j in range(width))
+    return y if bias is None else y + bias.astype(jnp.float32)
 
 
 def gated_delta_rule_stepwise(q, k, v, g, beta):

@@ -13,10 +13,16 @@ This op holds `experts_held` of the experts, those numbered
 `expert_offset` .. `expert_offset + experts_held - 1`, and computes their
 part of the result:
 
-    routed = sum over e in (top-k and held) of w_e * down_e(silu(gate_e x) * up_e x)
+    routed = sum over e in (top-k and held) of w_e * E_e(x)
     out    = routed + sigmoid(x . w_sg) * shared_expert(x)
 
-(`shared_gate=False`: the shared expert is added as it is.)
+(`shared_gate=False`: the shared expert is added as it is.) The model says
+which form its experts have (`activation`), routed and shared alike:
+
+    swiglu   E(x) = down(silu(gate x) * up x), three matrices (Qwen3-Next,
+             GLM-4.7)
+    relu2    E(x) = down(relu(up x)^2), two: no `w_gate`, no `shared_gate`
+             weight (Nemotron-H)
 
 The weights w_e are normalised over all `top_k` chosen experts, held here
 or not; what the experts held elsewhere would add is theirs to compute (on
@@ -27,7 +33,7 @@ whole layer.
 Dropless: every (token, held expert) pair is computed, at any imbalance.
 The pairs are sorted by expert, so a held expert's pairs are one stretch of
 rows, and the stretches are walked a chunk of `chunk_rows` rows at a time:
-a chunk gathers its rows' tokens, multiplies them with ITS expert's three
+a chunk gathers its rows' tokens, multiplies them with ITS expert's
 matrices (plain products, one expert a chunk), scales by the pair weights
 and writes its rows, side by side, into a buffer of all sorted pairs; the
 result is each token's sum over the rows of its held pairs (a gather by the
@@ -80,8 +86,26 @@ from ..core.op import Op, ParamDef
 CHUNK_ROWS = 512
 
 
+# an expert's form -> its matrices, as `w_<name>` / `shared_<name>`
+FORMS = {"swiglu": ("gate", "up", "down"), "relu2": ("up", "down")}
+
+
 def _silu_mul(g, u):
     return jax.nn.silu(g) * u
+
+
+def _ffn(cdt, act, xs, ws):
+    """One expert of form `act` on rows xs (rows, D) in `cdt`; ws its fp32
+    matrices, (gate, up, down) (D, F), (D, F), (F, D) for "swiglu", (up,
+    down) for "relu2". -> (rows, D) fp32."""
+    def mm(a, w):
+        return jnp.dot(a, w.astype(cdt), preferred_element_type=jnp.float32)
+
+    if act == "swiglu":
+        h = _silu_mul(mm(xs, ws[0]), mm(xs, ws[1]))
+    else:
+        h = jnp.square(jax.nn.relu(mm(xs, ws[0])))
+    return mm(h.astype(cdt), ws[-1])
 
 
 def _walk_plan(rows, counts):
@@ -104,14 +128,10 @@ def _chunk(rows, j, plan, counts, order):
     return e, lax.dynamic_slice(order, (first,), (rows,)), first, valid
 
 
-def _expert_ffn(cdt, xs, wg, wu, wd, w_row):
-    """One expert on a chunk's rows: xs (rows, D) in `cdt`; wg, wu (D, F),
-    wd (F, D) fp32; w_row (rows,) -> the rows' weighted outputs, fp32."""
-    def mm(a, w):
-        return jnp.dot(a, w.astype(cdt), preferred_element_type=jnp.float32)
-
-    h = _silu_mul(mm(xs, wg), mm(xs, wu)).astype(cdt)
-    return mm(h, wd) * w_row[:, None]
+def _expert_ffn(cdt, act, xs, ws, w_row):
+    """One expert on a chunk's rows, each scaled by its pair's weight
+    w_row (rows,): the rows' weighted outputs, fp32."""
+    return _ffn(cdt, act, xs, ws) * w_row[:, None]
 
 
 def _sum_pairs(rows_buf, pos, held_pair, top_k):
@@ -140,14 +160,14 @@ def _add_slab(acc, e, g):
     return lax.dynamic_update_slice(acc, slab + g[None], at)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _routed(rows, top_k, cdt, xt, wg, wu, wd, pair_w, order, counts,
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _routed(rows, top_k, cdt, act, xt, ws, pair_w, order, counts,
             held_pair):
-    """The held experts' part of the result. xt (T, D); w* (held, ...)
-    fp32; pair_w (T * k,) fp32; order (T * k + rows,) pair indices sorted
-    by held expert, the pairs of experts held elsewhere last, then
-    padding; counts (held,) pairs a held expert; held_pair (T * k,) bool.
-    -> (T, D) fp32."""
+    """The held experts' part of the result. xt (T, D); ws the experts'
+    matrices as `_ffn` takes them, each (held, ...) fp32; pair_w (T * k,)
+    fp32; order (T * k + rows,) pair indices sorted by held expert, the
+    pairs of experts held elsewhere last, then padding; counts (held,)
+    pairs a held expert; held_pair (T * k,) bool. -> (T, D) fp32."""
     plan = _walk_plan(rows, counts)
 
     def trip(j, buf):
@@ -155,7 +175,7 @@ def _routed(rows, top_k, cdt, xt, wg, wu, wd, pair_w, order, counts,
         with jax.named_scope("dispatch"):
             xs = jnp.take(xt, idx // top_k, axis=0).astype(cdt)
         with jax.named_scope("experts"):
-            y = _expert_ffn(cdt, xs, wg[e], wu[e], wd[e],
+            y = _expert_ffn(cdt, act, xs, tuple(w[e] for w in ws),
                             jnp.take(pair_w, idx))
         return lax.dynamic_update_slice(buf, y.astype(cdt), (first, 0))
 
@@ -166,43 +186,42 @@ def _routed(rows, top_k, cdt, xt, wg, wu, wd, pair_w, order, counts,
                           held_pair, top_k)
 
 
-def _routed_fwd(rows, top_k, cdt, xt, wg, wu, wd, pair_w, order, counts,
+def _routed_fwd(rows, top_k, cdt, act, xt, ws, pair_w, order, counts,
                 held_pair):
-    out = _routed(rows, top_k, cdt, xt, wg, wu, wd, pair_w, order, counts,
+    out = _routed(rows, top_k, cdt, act, xt, ws, pair_w, order, counts,
                   held_pair)
-    return out, (xt, wg, wu, wd, pair_w, order, counts, held_pair)
+    return out, (xt, ws, pair_w, order, counts, held_pair)
 
 
-def _routed_bwd(rows, top_k, cdt, res, ct):
-    xt, wg, wu, wd, pair_w, order, counts, held_pair = res
+def _routed_bwd(rows, top_k, cdt, act, res, ct):
+    xt, ws, pair_w, order, counts, held_pair = res
     plan = _walk_plan(rows, counts)
 
     def trip(j, carry):
-        dwg, dwu, dwd, dx_buf, dw_buf = carry
+        dws, dx_buf, dw_buf = carry
         e, idx, first, valid = _chunk(rows, j, plan, counts, order)
         tok = idx // top_k
         with jax.named_scope("dispatch"):
             xs = jnp.take(xt, tok, axis=0).astype(cdt)
             dy = jnp.where(valid[:, None], jnp.take(ct, tok, axis=0), 0.0)
         with jax.named_scope("experts"):
-            _, vjp = jax.vjp(partial(_expert_ffn, cdt), xs, wg[e], wu[e],
-                             wd[e], jnp.take(pair_w, idx))
-            dxs, dg, du, dd, dw_row = vjp(dy)
-        return (_add_slab(dwg, e, dg), _add_slab(dwu, e, du),
-                _add_slab(dwd, e, dd),
+            _, vjp = jax.vjp(partial(_expert_ffn, cdt, act), xs,
+                             tuple(w[e] for w in ws), jnp.take(pair_w, idx))
+            dxs, dw, dw_row = vjp(dy)
+        return (tuple(_add_slab(acc, e, g) for acc, g in zip(dws, dw)),
                 lax.dynamic_update_slice(dx_buf, dxs.astype(cdt), (first, 0)),
                 lax.dynamic_update_slice(dw_buf, dw_row, (first,)))
 
-    dwg, dwu, dwd, dx_buf, dw_buf = lax.fori_loop(
+    dws, dx_buf, dw_buf = lax.fori_loop(
         0, plan[2], trip,
-        (jnp.zeros_like(wg), jnp.zeros_like(wu), jnp.zeros_like(wd),
+        (tuple(jnp.zeros_like(w) for w in ws),
          jnp.zeros((order.size, xt.shape[1]), cdt),
          jnp.zeros((order.size,), jnp.float32)))
     with jax.named_scope("combine"):
         pos = _sorted_position(order, held_pair.size)
         dxt = _sum_pairs(dx_buf, pos, held_pair, top_k).astype(xt.dtype)
         dpair_w = jnp.where(held_pair, jnp.take(dw_buf, pos), 0.0)
-    return dxt, dwg, dwu, dwd, dpair_w, None, None, None
+    return dxt, dws, dpair_w, None, None, None
 
 
 _routed.defvjp(_routed_fwd, _routed_bwd)
@@ -218,7 +237,7 @@ class MoE(Op):
                  norm_topk: bool = True, scoring: str = "softmax",
                  routed_scale: float = 1.0, shared_gate: bool = True,
                  balance_rate: float = 0.0, kernel_initializer=None,
-                 name: Optional[str] = None):
+                 name: Optional[str] = None, activation: str = "swiglu"):
         held = num_experts if experts_held is None else experts_held
         if not 0 < top_k <= num_experts:
             raise ValueError("top_k must lie in 1..num_experts")
@@ -231,6 +250,8 @@ class MoE(Op):
             raise ValueError(f"unknown router scoring {scoring!r}")
         if balance_rate and scoring != "sigmoid":
             raise ValueError("the balance bias corrects sigmoid scores")
+        if activation not in FORMS:
+            raise ValueError(f"unknown expert form {activation!r}")
         super().__init__(model, [x], name)
         self.num_experts, self.top_k = int(num_experts), int(top_k)
         self.expert_dim, self.shared_dim = int(expert_dim), int(shared_dim)
@@ -239,6 +260,7 @@ class MoE(Op):
         self.scoring, self.routed_scale = scoring, float(routed_scale)
         self.shared_gate = bool(shared_gate)
         self.balance_rate = float(balance_rate)
+        self.activation = activation
         self.kernel_initializer = kernel_initializer or DEFAULT_KERNEL_INIT()
         self.tokens = 1
         for n in x.shape[:-1]:
@@ -259,6 +281,8 @@ class MoE(Op):
             "shared_up": ParamDef((d, fs), f32, init),
             "shared_down": ParamDef((fs, d), f32, init),
         }
+        if "gate" not in FORMS[self.activation]:
+            del defs["w_gate"], defs["shared_gate"]
         if self.shared_gate:
             defs["shared_router"] = ParamDef((d,), f32, init)
         return defs
@@ -315,24 +339,18 @@ class MoE(Op):
             # a chunk reads whole: the last one may read into the padding
             order = jnp.pad(order, (0, self.chunk_rows))
             counts = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
-        routed = _routed(self.chunk_rows, self.top_k, cdt, xt,
-                         params["w_gate"], params["w_up"], params["w_down"],
+        names = FORMS[self.activation]
+        routed = _routed(self.chunk_rows, self.top_k, cdt, self.activation,
+                         xt, tuple(params[f"w_{n}"] for n in names),
                          pair_w.reshape(-1), order, counts, key < held)
         with jax.named_scope("shared"):
-            xc = xt.astype(cdt)
-
-            def mm(a, w):
-                return jnp.dot(a, w.astype(cdt), preferred_element_type=f32)
-
-            h = _silu_mul(mm(xc, params["shared_gate"]),
-                          mm(xc, params["shared_up"])).astype(cdt)
+            shared = _ffn(cdt, self.activation, xt.astype(cdt),
+                          tuple(params[f"shared_{n}"] for n in names))
             if self.shared_gate:
                 gate = jax.nn.sigmoid(jnp.sum(
                     xt.astype(f32) * params["shared_router"], axis=-1,
                     keepdims=True))
-                shared = gate * mm(h, params["shared_down"])
-            else:
-                shared = mm(h, params["shared_down"])
+                shared = gate * shared
         new_state = {"tokens": state["tokens"] + xt.shape[0],
                      "pairs": state["pairs"] + counts,
                      "rows": state["rows"] + self.chunk_rows * _walk_plan(
@@ -353,6 +371,7 @@ class MoE(Op):
         tokens = self.tokens / max(self.outputs[0].shape[0], 1)
         d = self.outputs[0].shape[-1]
         pairs = self.top_k * self.experts_held / self.num_experts
+        products = len(FORMS[self.activation])
         return tokens * (2.0 * d * self.num_experts
-                         + 6.0 * d * (pairs * self.expert_dim
-                                      + self.shared_dim))
+                         + 2.0 * products * d * (pairs * self.expert_dim
+                                                 + self.shared_dim))

@@ -8,7 +8,10 @@ whatever the activation dtype; the output keeps the input's dtype.
 The op stores the scale as `1 + w` with `w` initialised 0 (the Qwen3-Next
 convention: weight decay then pulls the scale to 1, not to 0) or, with
 `zero_centered=False`, as `w` initialised 1 (GLM's, DeepSeek's); `rms_norm`,
-which the block ops call on their insides, takes either.
+which the block ops call on their insides, takes either, and a
+`group_size`: the mean square is then taken over each run of that many
+features (Mamba-2's gated norm: 8 groups of 512), the scale still one a
+feature.
 `to_compute_dtype` hands the result on in the model's compute dtype: the
 norm before a wide head, whose logits then take half the bytes.
 """
@@ -24,12 +27,18 @@ from ..core.initializers import ConstantInitializer, ZeroInitializer
 from ..core.op import Op, ParamDef
 
 
-def rms_norm(x, w, eps: float, zero_centered: bool):
-    """`x` (..., d) normalised over its last axis in fp32, times the
-    scale; returns fp32 (the caller casts)."""
+def rms_norm(x, w, eps: float, zero_centered: bool,
+             group_size: Optional[int] = None):
+    """`x` (..., d) normalised over its last axis (over each group of
+    `group_size` features of it, where given) in fp32, times the scale;
+    returns fp32 (the caller casts)."""
     x32 = x.astype(jnp.float32)
+    if group_size is not None:
+        x32 = x32.reshape(x.shape[:-1] + (-1, group_size))
     y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1,
                                      keepdims=True) + eps)
+    if group_size is not None:
+        y = y.reshape(x.shape)
     scale = w.astype(jnp.float32)
     return y * (1.0 + scale if zero_centered else scale)
 

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import dlrm_flexflow_tpu as ff
 from dlrm_flexflow_tpu.core import losses
 from dlrm_flexflow_tpu.models import glm4_moe_lite_reference as ref
+from dlrm_flexflow_tpu.models import nemotron_h_reference as nemotron_ref
 from dlrm_flexflow_tpu.models import qwen3_next_reference as qwen_ref
 from dlrm_flexflow_tpu.models.glm4_moe_lite import (Glm4MoeLiteConfig,
                                                     build_glm4_moe_lite,
@@ -279,13 +280,18 @@ def test_the_model_trains_through_fit_like_the_reference():
 
 
 def _moe_layer(router, held, offset, x, whole=None):
-    """One expert op alone under either router, holding `held` experts
-    from `offset`; weights cut out of the uncut layer's where given."""
+    """One expert op alone under either router (`relu2`: the sigmoid
+    router over experts of two matrices, Nemotron-H's), holding `held`
+    experts from `offset`; weights cut out of the uncut layer's where
+    given."""
     model = ff.FFModel(ff.FFConfig(batch_size=x.shape[0], seed=3))
     t = model.create_tensor(x.shape, name="x")
     kw = {"softmax": {},
           "sigmoid": dict(scoring="sigmoid", routed_scale=1.8,
-                          shared_gate=False, balance_rate=1e-3)}[router]
+                          shared_gate=False, balance_rate=1e-3),
+          "relu2": dict(scoring="sigmoid", routed_scale=2.5,
+                        shared_gate=False, balance_rate=1e-3,
+                        activation="relu2")}[router]
     model.moe(t, CFG.n_routed_experts, CFG.num_experts_per_tok,
               CFG.moe_intermediate_size, CFG.moe_intermediate_size,
               experts_held=held, expert_offset=offset, name="moe", **kw)
@@ -295,33 +301,43 @@ def _moe_layer(router, held, offset, x, whole=None):
                               op.init_params(jax.random.PRNGKey(11)))
     else:
         params = dict(whole, **{k: whole[k][offset:offset + held]
-                                for k in ("w_gate", "w_up", "w_down")})
+                                for k in ("w_gate", "w_up", "w_down")
+                                if k in whole})
     state = {k: jnp.zeros(d.shape, d.dtype)
              for k, d in op.state_defs().items()}
-    if router == "sigmoid":
+    if router != "softmax":
         state["bias"] = BIAS
     return op, params, state
 
 
 @pytest.mark.parametrize("held", [16, 2])
-@pytest.mark.parametrize("router", ["softmax", "sigmoid"])
+@pytest.mark.parametrize("router", ["softmax", "sigmoid", "relu2"])
 def test_the_shares_add_up_to_the_uncut_layer(x, router, held):
-    """Under either router: the routed parts of all 16 / held ranks, each
-    with its own offset, plus the shared expert counted once == that
-    router's reference for the whole layer."""
+    """Under either router and either form of expert: the routed parts of
+    all 16 / held ranks, each with its own offset, plus the shared expert
+    counted once == that model's reference for the whole layer."""
     n = CFG.n_routed_experts
     _, whole, _ = _moe_layer(router, n, 0, x)
     xt = x.reshape(-1, x.shape[-1])
     with jax.default_matmul_precision("highest"):
-        shared = ref.swiglu(xt, whole["shared_gate"], whole["shared_up"],
-                            whole["shared_down"])
+        if router == "relu2":
+            assert sorted(whole) == ["router", "shared_down", "shared_up",
+                                     "w_down", "w_up"]
+            shared = nemotron_ref.relu2_mlp(xt, whole["shared_up"],
+                                            whole["shared_down"])
+            want = nemotron_ref.moe(whole, xt, dict(
+                asdict(CFG), expert_offset=0, routed_scaling_factor=2.5),
+                BIAS)[0]
+        else:
+            shared = ref.swiglu(xt, whole["shared_gate"], whole["shared_up"],
+                                whole["shared_down"])
         if router == "softmax":
             cfg = dict(num_experts_per_tok=CFG.num_experts_per_tok,
                        norm_topk_prob=True, expert_offset=0)
             want = qwen_ref.moe(whole, xt, cfg)[0]
             shared = shared * jax.nn.sigmoid(
                 xt @ whole["shared_router"])[:, None]
-        else:
+        elif router == "sigmoid":
             want = ref.moe(whole, xt, dict(asdict(CFG), expert_offset=0,
                                            routed_scaling_factor=1.8),
                            BIAS)[0]

@@ -137,7 +137,8 @@ def _qwen3_next(model):
     inside = {"l0_moe": ["router", "dispatch", "experts", "combine",
                          "shared"],
               "l0_delta": ["proj", "conv", "scan", "gate_norm"],
-              "l3_attn": ["qk_norm_rope", "attend", "gate"]}
+              "l3_attn": ["qkv_proj", "qk_norm_rope", "attend", "gate",
+                          "out_proj"]}
     return {}, {"tokens": tokens[:, :-1]}, tokens[:, 1:], inside
 
 
@@ -164,7 +165,29 @@ def _glm4_moe_lite(model):
             {"tokens": ids}, labels, inside)
 
 
-@pytest.mark.parametrize("build", [_qwen3_next, _glm4_moe_lite],
+def _nemotron_h(model):
+    """ISSUE 32: a block of one part; the state-space mixer's five scopes,
+    plain attention's three, the two-matrix experts' walk and balance."""
+    from dlrm_flexflow_tpu.models.nemotron_h import (NemotronHConfig,
+                                                     build_nemotron_h)
+    cfg = NemotronHConfig(
+        vocab_size=64, hidden_size=32, num_hidden_layers=3,
+        hybrid_override_pattern="ME*", mamba_num_heads=4, mamba_head_dim=8,
+        n_groups=2, ssm_state_size=8, chunk_size=16, num_attention_heads=2,
+        num_key_value_heads=1, head_dim=16, moe_intermediate_size=16,
+        moe_shared_expert_intermediate_size=16, n_routed_experts=8,
+        num_experts_per_tok=2, experts_held=4)
+    build_nemotron_h(model, cfg, 32)
+    tokens = (jax.numpy.arange(4 * 33).reshape(4, 33) % 64).astype("int32")
+    inside = {"l0_mamba": ["in_proj", "conv", "ssd", "gate_norm",
+                           "out_proj"],
+              "l1_moe": ["router", "dispatch", "experts", "combine",
+                         "shared", "balance"],
+              "l2_attn": ["qkv_proj", "attend", "out_proj"]}
+    return {}, {"tokens": tokens[:, :-1]}, tokens[:, 1:], inside
+
+
+@pytest.mark.parametrize("build", [_qwen3_next, _glm4_moe_lite, _nemotron_h],
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_every_instruction_of_a_language_model_step_has_a_path(build):
     """The census on the language models (ISSUEs 26, 30): block ops

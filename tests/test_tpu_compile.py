@@ -152,7 +152,8 @@ def test_sparse_update_holds_no_xla_scatter(sds, no_compile_cache, form):
 def test_expert_walk_compiles_for_v5e(one_chip, no_compile_cache):
     """The expert op's walk over its sorted pairs at Qwen3-Next's widths,
     forward and backward: loops with no static trip count, and a chunk's
-    rows, not the worst case's 81,920, set the size of the products."""
+    rows, not the worst case's 81,920, set the size of the products. (The
+    two-matrix form walks inside the Nemotron step, below.)"""
     from dlrm_flexflow_tpu.ops import moe
     T, D, F, held, k = 8192, 2048, 512, 32, 10
 
@@ -160,8 +161,9 @@ def test_expert_walk_compiles_for_v5e(one_chip, no_compile_cache):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def loss(xt, wg, wu, wd, pair_w, order, counts, held_pair):
-        return jnp.sum(moe._routed(moe.CHUNK_ROWS, k, jnp.bfloat16, xt, wg,
-                                   wu, wd, pair_w, order, counts, held_pair))
+        return jnp.sum(moe._routed(moe.CHUNK_ROWS, k, jnp.bfloat16, "swiglu",
+                                   xt, (wg, wu, wd), pair_w, order, counts,
+                                   held_pair))
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         sds((T, D)), sds((held, D, F)), sds((held, D, F)),
@@ -198,8 +200,19 @@ def test_chunked_delta_rule_compiles_for_v5e(one_chip, no_compile_cache):
 # dq and dkv. Qwen3-Next's bound is what its step counted BEFORE the expert
 # op learned a second router: an op that does not ask for the sigmoid
 # router, the bias or the ungated shared expert compiles to what it did.
-LM_STEPS = {"glm_4_7_flash": (16_000_000_000, 24),
-            "qwen3_next_80b_a3b": (14_474_101_248, 4)}
+# Nemotron-3-Nano (ISSUE 32) counted 13,249,963,008: four Mamba-2 layers
+# whose chunked recurrence is all XLA, one attention layer through the flash
+# route at heads of 128. The third number is what the step compiled to
+# BEFORE that issue touched `ops/moe.py`, `ops/attention.py`, `ops/norm.py`
+# and `causal_depthwise_conv` (lines of the optimized text that define an
+# instruction; the parent's text, instruction for instruction, but for
+# numbering and the source locations inside the Mosaic kernels' bodies): an
+# op that does not ask for the two-matrix expert, for attention without its
+# gate, norm and rotary, for a grouped norm or a convolution's bias compiles
+# to what it did, to the byte and to the instruction.
+LM_STEPS = {"glm_4_7_flash": (13_859_009_024, 24, 26_863),
+            "nemotron_3_nano_30b_a3b": (14_000_000_000, 4, None),
+            "qwen3_next_80b_a3b": (14_474_101_248, 4, 30_644)}
 
 
 @pytest.mark.parametrize("name", sorted(LM_STEPS))
@@ -232,6 +245,10 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
             Glm4MoeLiteConfig, build_glm4_moe_lite, loss_weights)
         build_glm4_moe_lite(model, Glm4MoeLiteConfig.from_dict(mcfg), seq)
         kw["loss_weights"] = loss_weights(seq, config["mtp_loss_weight"])
+    elif name == "nemotron_3_nano_30b_a3b":
+        from dlrm_flexflow_tpu.models.nemotron_h import (NemotronHConfig,
+                                                         build_nemotron_h)
+        build_nemotron_h(model, NemotronHConfig.from_dict(mcfg), seq)
     else:
         from dlrm_flexflow_tpu.models.qwen3_next import (Qwen3NextConfig,
                                                          build_qwen3_next)
@@ -265,17 +282,30 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
         {k: sds((), jnp.float32) for k in model._msums_keys}, batch,
         sds((), jnp.int32)).compile()
     ma = compiled.memory_analysis()
-    most, kernels = LM_STEPS[name]
-    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
-            - ma.alias_size_in_bytes + ma.temp_size_in_bytes) <= most
+    most, kernels, instructions = LM_STEPS[name]
+    counted = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+               - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    if instructions is None:
+        assert counted <= most
+    else:
+        assert counted == most
+        assert sum(" = " in line for line in text.splitlines()
+                   ) == instructions
     # the balance update is in the step that asked for it, and in no other
-    assert ("/balance/" in text) == (name == "glm_4_7_flash")
+    assert ("/balance/" in text) == (name != "qwen3_next_80b_a3b")
     # the blocks the flash kernels run with, as a scope under `attend`
-    # (ISSUE 31): both models attend at heads of 256 over 8,192 tokens
-    scope = attention._flash_blocks(1, seq, seq, 256)[1]
+    # (ISSUE 31): at heads of 256 over 8,192 tokens, Nemotron's at 128
+    scope = attention._flash_blocks(
+        1, seq, seq, mcfg.get("v_head_dim") or mcfg["head_dim"])[1]
     assert f"/attend/{scope}/jit(flash_attention)/pallas_call" in text
+    if name == "nemotron_3_nano_30b_a3b":
+        # the recurrence is batched products and no loop: the only `while`s
+        # of the step are the expert walks'
+        assert not re.search(r"ff\.l\d_mamba[^\n]*while", text)
+        for sub in ("in_proj", "conv", "ssd", "gate_norm", "out_proj"):
+            assert re.search(rf"ff\.l0_mamba\)/{sub}/", text), sub
 
 
 # (heads, query length, key length, q/k width, v width, causal): the three
