@@ -8,17 +8,20 @@ TPU-native redesign: the reference accumulates per-partition metrics with
 device atomics into a `PerfMetrics` struct returned as a Legion future, then
 folds futures in a CPU task (model.cc:1182-1205) so metrics never block the
 train loop. Here metrics are computed inside the jitted train step as sharded
-reductions (XLA inserts the cross-chip psum) and returned as device arrays;
+reductions (XLA inserts the cross-chip psum) and returned, with the loss, as
+ONE device array a dispatch (`pack_step_scalars`, read through `StepMetrics`);
 asynchronous dispatch gives the same never-blocks property — the host only
-syncs when it prints (utils/logging.py).
+syncs when it reads a value (fit's loss print, an anomaly policy).
 """
 
 from __future__ import annotations
 
+from collections.abc import Mapping
 from dataclasses import dataclass, field
-from typing import Dict, List
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax.numpy as jnp
+import numpy as np
 
 from .losses import row_weights
 
@@ -102,6 +105,88 @@ def compute_metrics(metrics: List[str], loss_type: str, preds, labels,
             out["mae"] = jnp.sum(
                 jnp.abs(preds32 - labels32).reshape(batch, -1).sum(-1))
     return out
+
+
+def _exact_in_float32(dtype) -> bool:
+    """Whether a float32 holds every value of `dtype` exactly: a bool, a
+    float of at most 32 bits, an integer of at most 16."""
+    dtype = np.dtype(dtype)
+    return (dtype == np.bool_
+            or (jnp.issubdtype(dtype, jnp.floating) and dtype.itemsize <= 4)
+            or (jnp.issubdtype(dtype, jnp.integer) and dtype.itemsize <= 2))
+
+
+def pack_step_scalars(scalars: Dict[str, Any],
+                      keys: Sequence[str]) -> jnp.ndarray:
+    """The step's scalars (the `compute_metrics` sums, the loss, under a
+    sentinel its flag and the gradient norm) as ONE float32 vector in the
+    order of `keys`: a step program hands back one fresh buffer where it
+    handed back one a scalar (each a device allocation the host waits
+    for, ROADMAP S1). Traced inside the step; `keys` is the model's, so
+    `StepMetrics` splits the vector on the host by the same order.
+    Refused here, when the step is traced: a key missing or unasked for,
+    a value that is no scalar, a dtype a float32 does not hold exactly
+    (an int32 count would come back rounded)."""
+    if set(scalars) != set(keys):
+        raise ValueError(
+            f"the step computed {sorted(scalars)}, the model's step "
+            f"vector carries {list(keys)}")
+    out = []
+    for k in keys:
+        v = jnp.asarray(scalars[k])
+        if v.shape != ():
+            raise TypeError(f"step metric {k!r} has shape {v.shape}: only "
+                            f"scalars ride the step vector")
+        if not _exact_in_float32(v.dtype):
+            raise TypeError(f"step metric {k!r} is {v.dtype}: a float32 "
+                            f"does not hold it exactly")
+        out.append(v.astype(jnp.float32))
+    return jnp.stack(out)
+
+
+class StepMetrics(Mapping):
+    """What one dispatch hands back, under the keys it always had
+    (`loss`, the metric sums, under a sentinel `anomaly` and `grad_norm`;
+    after a superstep also `per_step` and `superstep`): a read-only
+    mapping over the step program's ONE output vector (`[n]`, after a
+    superstep `[K, n]`). The vector comes to the host in one transfer the
+    first time a value is read, and is kept; a step nobody looks at costs
+    nothing. Indexing the device array a key would dispatch a program a
+    key. Values are numpy scalars (`per_step`: `[K]` arrays), `anomaly`
+    as a bool; `vector` is the device array itself, for whoever has to
+    wait for the step without reading it (`_Throttle`)."""
+
+    __slots__ = ("vector", "_index", "_last_of", "_extra", "_host")
+
+    def __init__(self, vector, index: Dict[str, int],
+                 last_of: Optional["StepMetrics"] = None, **extra):
+        self.vector = vector
+        self._index = index
+        # a superstep's boundary-facing scalars are its LAST step's: the
+        # last row of `last_of`'s host copy, the same one transfer
+        self._last_of = last_of
+        self._extra = extra
+        self._host = None
+
+    def host(self) -> np.ndarray:
+        """The vector on the host: the transfer happens here, once."""
+        if self._host is None:
+            self._host = (np.asarray(self.vector) if self._last_of is None
+                          else self._last_of.host()[-1])
+        return self._host
+
+    def __getitem__(self, key):
+        if key in self._extra:
+            return self._extra[key]
+        v = self.host()[..., self._index[key]][()]
+        return v != 0 if key == "anomaly" else v
+
+    def __iter__(self):
+        yield from self._index
+        yield from self._extra
+
+    def __len__(self):
+        return len(self._index) + len(self._extra)
 
 
 @dataclass

@@ -42,6 +42,7 @@ from ..parallel.pconfig import ParallelConfig, StrategyMap
 from ..parallel.sharding import AxisAssigner
 from ..parallel.distributed import MeshDegraded, MeshReturned, put_global
 from ..analysis import sanitizer as _san
+from ..obs import metrics as obsmetrics
 from ..obs import trace as obstrace
 from ..utils.watchdog import StallReport, WorkerStalled
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
@@ -126,20 +127,23 @@ class _Throttle:
     collectives can starve when many multi-device executions queue up on
     few host cores (on TPU the device is the bottleneck; a deep pipeline
     is safe). Bounds the pipeline without draining it: a call blocks on
-    the step issued `bound` calls AGO."""
+    the step issued `bound` calls AGO, through that dispatch's metrics
+    vector (`StepMetrics.vector`). It holds `bound` of them across later
+    dispatches, which is why the vector is a fresh output of the step
+    program and rides in no donated carry; it reads none."""
 
     def __init__(self):
         self.bound = 1 if jax.default_backend() == "cpu" else 32
-        self._losses = deque()
+        self._vectors = deque()
 
     def clear(self):
-        self._losses.clear()
+        self._vectors.clear()
 
     def __call__(self, mets):
-        self._losses.append(mets["loss"])
-        if len(self._losses) > self.bound:
+        self._vectors.append(mets.vector)
+        if len(self._vectors) > self.bound:
             with obstrace.span("fit/throttle"):
-                jax.block_until_ready(self._losses.popleft())
+                jax.block_until_ready(self._vectors.popleft())
         return mets
 
 
@@ -1287,15 +1291,24 @@ class FFModel:
                                  for k, v in mets.items()}
                 else:
                     new_msums = {k: msums[k] + v for k, v in mets.items()}
+                # what the host may look at after the step, as ONE fresh
+                # output: every other output is a carry that takes over
+                # its input's buffer (a fresh 4-byte output is a device
+                # allocation the dispatch waits for, ROADMAP S1)
                 mets["loss"] = loss
                 if sentinel:
                     mets["anomaly"] = ~step_ok
                     mets["grad_norm"] = gnorm
-            if host_cts is not None:
-                mets["_host_cts"] = host_cts
+                vec = jax.lax.with_sharding_constraint(
+                    metrics_mod.pack_step_scalars(mets, self._step_keys),
+                    NamedSharding(self.mesh, PartitionSpec()))
             # the step counter stays device-resident across calls (feeding
             # a fresh host int every step would be one H2D transfer/step)
-            return new_params, new_opt, st2, new_msums, step + 1, mets
+            # and is donated like the other carries
+            outs = (new_params, new_opt, st2, new_msums, step + 1, vec)
+            # host-resident tables only: their cotangents leave for the
+            # wrapper's host scatter as a seventh output
+            return outs if host_cts is None else outs + (host_cts,)
 
         def eval_step(params, op_state, batch, host_emb=None):
             env, _ = self._forward_env(params, op_state, batch, False, None,
@@ -1315,18 +1328,18 @@ class FFModel:
             to K sequential dispatches of the same batches."""
             def body(carry, bk):
                 p, o, st, ms, sp = carry
-                p, o, st, ms, sp, mets = train_step(p, o, st, ms, bk, sp)
-                return (p, o, st, ms, sp), mets
+                p, o, st, ms, sp, vec = train_step(p, o, st, ms, bk, sp)
+                return (p, o, st, ms, sp), vec
 
+            # the K steps' vectors stacked [K, n]: the boundary policies
+            # read the per-step columns (metrics, anomaly flags), the
+            # boundary-facing scalars (fit's loss print) are its LAST row
+            # (`StepMetrics`, on the host)
             (p, o, st, ms, sp), stacked = jax.lax.scan(
                 body, (params, opt_state, op_state, msums, step), sbatch)
-            # boundary-facing scalars (fit's loss print, the throttle)
-            # are the LAST step's values; per-step [K] arrays (metrics,
-            # anomaly flags) ride alongside for the boundary policies
-            last = jax.tree.map(lambda a: a[-1], stacked)
-            return p, o, st, ms, sp, last, stacked
+            return p, o, st, ms, sp, stacked
 
-        donate = (0, 1, 2, 3)
+        donate = (0, 1, 2, 3, 5)
         self._train_step = jax.jit(train_step, donate_argnums=donate)
         self._superstep_fn = jax.jit(train_superstep, donate_argnums=donate)
         self._eval_step = jax.jit(eval_step)
@@ -1340,6 +1353,12 @@ class FFModel:
             dummy_labels = jnp.zeros(dummy_preds.shape, jnp.float32)
         self._msums_keys = sorted(metrics_mod.compute_metrics(
             metric_names, loss_type, dummy_preds, dummy_labels).keys())
+        # the columns of the step program's metrics vector, fixed here so
+        # the host splits what the trace packed (an executable loaded
+        # from a cache was never traced in this process)
+        self._step_keys = tuple(self._msums_keys) + ("loss",) + (
+            ("anomaly", "grad_norm") if sentinel else ())
+        self._step_index = {k: i for i, k in enumerate(self._step_keys)}
 
     def _zero_msums(self):
         # committed replicated: the AOT executable cache requires inputs
@@ -1639,8 +1658,11 @@ class FFModel:
             [puts[k][0] for k in names], [puts[k][1] for k in names])))
 
     def train_batch(self, batch: Dict[str, np.ndarray]):
-        """One fused train step (forward+backward+update). Returns metrics
-        dict of device scalars (async — don't block)."""
+        """One fused train step (forward+backward+update). Returns the
+        step's `StepMetrics`: a read-only mapping (`loss`, the metric
+        sums, under a sentinel `anomaly` and `grad_norm`) over one device
+        vector. The dispatch is async; the first value read waits for the
+        step and brings the whole vector to the host, once."""
         return self.train_batch_device(self._device_batch(batch))
 
     def _ensure_step_state(self):
@@ -1759,6 +1781,17 @@ class FFModel:
                 if cache is not None:
                     cache.put(ckey, exec_)
         obstrace.note_program(kind, exec_)
+        if kind in ("train", "superstep") and obsmetrics.enabled():
+            # once a compile, nothing a step: did every carry take over
+            # its input's buffer, and is the metrics vector all that is
+            # left (1)? Reading the program's text is the cost, so only
+            # under --obs on
+            fresh = obstrace.fresh_outputs(exec_)
+            if fresh is not None:
+                obsmetrics.gauge(
+                    "ff_step_fresh_outputs",
+                    "outputs of the newest step program that alias no "
+                    "input", ("kind",)).set(fresh, kind=kind)
         return exec_
 
     def _executable(self, kind: str, execs: Dict, key, fn, args):
@@ -2069,7 +2102,7 @@ class FFModel:
         # once in K steps, so the span can carry what a trace reader
         # divides a fused span by
         (self.params, self.opt_state, self.op_state, self._msums,
-         self._step_dev, last, stacked) = self._run_executable(
+         self._step_dev, stacked) = self._run_executable(
             "superstep", self._superstep_execs,
             (k,) + self._exec_key(sbatch), self._superstep_fn,
             self._step_args(sbatch), "train/superstep",
@@ -2077,11 +2110,11 @@ class FFModel:
         step0 = self._step
         self._step += k
         self.perf.sums = dict(self._msums)
-        mets = dict(last)
-        mets["per_step"] = stacked
-        mets["superstep"] = k
-        self._check_anomaly(step0, stacked)
-        return mets
+        per_step = metrics_mod.StepMetrics(stacked, self._step_index)
+        self._check_anomaly(step0, per_step)
+        return metrics_mod.StepMetrics(stacked, self._step_index,
+                                       last_of=per_step,
+                                       per_step=per_step, superstep=k)
 
     @obstrace.spanned("train/dispatch")
     def _train_dispatch(self, device_batch: Dict, host_idx,
@@ -2095,18 +2128,21 @@ class FFModel:
         hres = host_idx is not None
         if hres:
             args = args + (self._host_emb_input(host_idx),)
-        (self.params, self.opt_state, self.op_state, self._msums,
-         self._step_dev, mets) = self._run_executable(
+        outs = self._run_executable(
             "train", self._train_step_execs,
             self._exec_key(device_batch), self._train_step, args,
             "train/step")
+        (self.params, self.opt_state, self.op_state, self._msums,
+         self._step_dev, vec) = outs[:6]
+        mets = metrics_mod.StepMetrics(vec, self._step_index)
         self._step += 1
-        policy = self._anomaly_policy
-        # the sentinel flag (device bool) guards the host-table scatter on
-        # every policy: NaN cotangents scattered into host tables could not
-        # be undone by skip_step's on-device suppression
-        anomaly_flag = mets.get("anomaly") if policy != "none" else None
+        # the sentinel flag guards the host-table scatter on every policy:
+        # NaN cotangents scattered into host tables could not be undone
+        # by skip_step's on-device suppression. Read where the scatter
+        # runs (the worker, after the step), never at dispatch
+        sentinel = self._anomaly_policy != "none"
         if hres:
+            cts = outs[6]
             if getattr(self.config, "host_tables_async", True):
                 # pipelined (double-buffering): the cotangent readback +
                 # host scatter run on a worker thread, overlapping the
@@ -2125,7 +2161,6 @@ class FFModel:
                 # first.
                 self._host_drain()
                 import threading
-                cts = mets.pop("_host_cts")
                 step = self._step - 1   # capture NOW: the thread may run
                 # after the next call's increment
                 nh = (next_host_idx() if callable(next_host_idx)
@@ -2151,8 +2186,7 @@ class FFModel:
                             # replaced the tables underneath it — a late
                             # scatter would corrupt the restored state
                             return
-                        if (anomaly_flag is None
-                                or not bool(np.asarray(anomaly_flag))):
+                        if not (sentinel and mets["anomaly"]):
                             self._host_emb_update(host_idx, cts, step)
                     except BaseException as e:   # re-raised at drain
                         self._host_scatter_exc = e
@@ -2163,16 +2197,13 @@ class FFModel:
             else:
                 # exact ordering: the cotangent readback is the step's
                 # true completion
-                cts = mets.pop("_host_cts")
-                if (anomaly_flag is None
-                        or not bool(np.asarray(anomaly_flag))):
+                if not (sentinel and mets["anomaly"]):
                     self._host_emb_update(host_idx, cts, self._step - 1)
         # the running sums live on device; PerfMetrics syncs at report().
         # shallow-copy so perf.reset()/report() mutating perf.sums can
         # never corrupt the jit carry
         self.perf.sums = dict(self._msums)
-        if anomaly_flag is not None:
-            self._check_anomaly(self._step - 1, mets)
+        self._check_anomaly(self._step - 1, mets)
         return mets
 
     @property

@@ -244,6 +244,33 @@ def note_program(kind: str, executable) -> None:
     _PROGRAMS[kind] = executable
 
 
+_ALIASED_OUTPUT = re.compile(r"\{([\d, ]*)\}: \((\d+), \{[\d, ]*\}")
+
+
+def aliased_outputs(executable) -> Optional[Dict[str, int]]:
+    """{output index: parameter number} of a compiled program's alias
+    table, the first line of its text (`input_output_alias={ {0}: (0, {},
+    may-alias), ... }`): the outputs that take over an input's buffer. An
+    index as the text writes it ("11"; "" for a result that is no tuple).
+    None for an executable that gives no text (see `program_scopes`)."""
+    try:
+        header = executable.as_text().split("\n", 1)[0]
+    except Exception:   # noqa: BLE001 - a diagnostic, never fatal
+        return None
+    return {out: int(param)
+            for out, param in _ALIASED_OUTPUT.findall(header)}
+
+
+def fresh_outputs(executable) -> Optional[int]:
+    """How many of a compiled program's outputs take over no input's
+    buffer. The runtime allocates a device buffer for each before the
+    call returns, which is what a short step's dispatch waits for
+    (ROADMAP S1): a step program should read 1, its metrics vector."""
+    aliased = aliased_outputs(executable)
+    return (None if aliased is None
+            else executable.out_tree.num_leaves - len(aliased))
+
+
 def hlo_scopes(hlo_text: str) -> Dict[str, str]:
     """{instruction name: op_name path} of one optimized HLO module's
     text ("" for an instruction that carries none). The trace calls a

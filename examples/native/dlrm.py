@@ -188,7 +188,8 @@ def main(argv=None):
                 prev = step
                 step += adv
                 if step // throttle != prev // throttle:
-                    jax.block_until_ready(mets["loss"])
+                    # wait for the step, read nothing
+                    jax.block_until_ready(mets.vector)
         jax.block_until_ready(model.params)
     elapsed = time.time() - t0
     n_samples = cfg.epochs * num_batches * cfg.batch_size
