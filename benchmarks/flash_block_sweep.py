@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu import flash_attention as fa
 
+from dlrm_flexflow_tpu.obs.trace import program_memory
+
 
 def major_minor(ladder, n):
     """(major, minor) pairs of the ladder: major divides n, minor divides
@@ -159,7 +161,7 @@ def main():
                     run_on = arrays
                 compiled = jax.jit(fn).lower(*ins).compile()
                 rec["compile_s"] = round(time.perf_counter() - t0, 2)
-                rec["temp_bytes"] = compiled.memory_analysis().temp_size_in_bytes
+                rec["temp_bytes"] = program_memory(compiled)["temp"]
                 if run_on is not None:
                     jax.block_until_ready(compiled(*run_on))
                     times = []

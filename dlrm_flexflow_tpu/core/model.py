@@ -1767,32 +1767,60 @@ class FFModel:
         `fresh=True` skips the lookup — the GSPMD
         recompile-on-sharding-disagree fallback must not re-load the
         very entry that just disagreed. Every step executable, built or
-        loaded, comes from here, so this is where obs.trace learns which
-        program's scope map `program_scopes()` answers with."""
+        loaded, comes from here, so this is where obs.trace gets its
+        record of the program: the executable, the seconds lowering and
+        compiling (or loading) took and which of the two it was. Nothing
+        is read from the executable for it."""
         cache = getattr(self, "_compile_cache", None)
         with obstrace.span(f"compile/{kind}"):
             exec_ = None
+            t0 = t1 = time.perf_counter()
             if cache is not None:
                 ckey = cache.exec_key(kind, self, shape_key)
                 if not fresh:
                     exec_ = cache.get(ckey, self.mesh.devices.flat)
-            if exec_ is None:
-                exec_ = lower().compile()
-                if cache is not None:
-                    cache.put(ckey, exec_)
-        obstrace.note_program(kind, exec_)
-        if kind in ("train", "superstep") and obsmetrics.enabled():
+            loaded = exec_ is not None
+            if not loaded:
+                t0 = time.perf_counter()
+                lowered = lower()
+                t1 = time.perf_counter()
+                exec_ = lowered.compile()
+            t2 = time.perf_counter()
+            if cache is not None and not loaded:
+                cache.put(ckey, exec_)
+        obstrace.note_program(kind, exec_, key=shape_key, lower_s=t1 - t0,
+                              compile_s=t2 - t1, loaded=loaded)
+        if kind in obstrace.STEP_KINDS and obsmetrics.enabled():
             # once a compile, nothing a step: did every carry take over
             # its input's buffer, and is the metrics vector all that is
             # left (1)? Reading the program's text is the cost, so only
-            # under --obs on
+            # under --obs on; what the program needs and what it cost to
+            # build is read from the records when someone scrapes
             fresh = obstrace.fresh_outputs(exec_)
             if fresh is not None:
                 obsmetrics.gauge(
                     "ff_step_fresh_outputs",
                     "outputs of the newest step program that alias no "
                     "input", ("kind",)).set(fresh, kind=kind)
+            obsmetrics.register_collector(obstrace.collect_step_programs)
         return exec_
+
+    def step_memory(self) -> Dict[str, Dict[str, int]]:
+        """{"train" | "superstep": `obs.trace.program_memory` of this
+        model's newest step program of that kind}: the bytes of HBM the
+        compiler counts for a step (`counted` = argument + output - alias
+        + temp), which the runtime's `peak_bytes_in_use` does not show.
+        A kind without a program yet, or whose executable gives no
+        analysis, is left out. `ff_step_hbm_bytes` under `--obs on`."""
+        out = {}
+        for kind, attr in (("train", "_train_step_execs"),
+                           ("superstep", "_superstep_execs")):
+            execs = getattr(self, attr, None)    # None before compile()
+            memory = (obstrace.program_memory(list(execs.values())[-1])
+                      if execs else None)
+            if memory is not None:
+                out[kind] = memory
+        return out
 
     def _executable(self, kind: str, execs: Dict, key, fn, args):
         """The AOT executable of the jitted `fn` for `key`: the one

@@ -1,6 +1,7 @@
 """Structured tracing: named spans on the profiler's clock and, with
 ``--obs on``, in a bounded in-memory ring exported as Chrome-trace /
-Perfetto JSON; and the scope map of the compiled step programs.
+Perfetto JSON; and the record of the compiled step programs, with what
+each can be asked (its scope map, the memory the compiler counts for it).
 
 ``utils/profiling.py`` covers the two reference layers (per-op timing,
 whole-run xprof capture); what neither shows is the CROSS-SUBSYSTEM
@@ -39,6 +40,12 @@ traced under a ``jax.named_scope("ff.<op name>")`` (core/model.py), which
 XLA keeps as each instruction's ``op_name``. :func:`program_scopes` reads
 that back from the newest compiled step programs, so "what is
 ``fusion.7``" has an answer: ``jit(train_step)/.../ff.update.emb/dedup/sort``.
+
+Every step executable ``FFModel._cached_compile`` builds or loads leaves
+one record (:func:`programs`: the executable, the seconds lowering and
+compiling took, built or loaded). :func:`program_memory` answers from it
+what the runtime's ``peak_bytes_in_use`` does not show: the bytes of HBM
+the compiler counts for a step, temporaries included.
 """
 
 from __future__ import annotations
@@ -49,8 +56,8 @@ import os
 import re
 import threading
 import time
-from collections import deque
-from typing import Any, Dict, List, Optional
+from collections import OrderedDict, deque
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 from jax.profiler import TraceAnnotation
 
@@ -226,22 +233,51 @@ def instant(name: str, cat: str = "", **args) -> None:
 
 
 # ---------------------------------------------------------------------
-# the scope map: which op of the model an instruction of a step program is
+# the record of the step programs, and what a program can be asked: which
+# op of the model an instruction is, which outputs alias an input, the
+# memory the compiler counts
 # ---------------------------------------------------------------------
-# kind -> the newest executable `_cached_compile` handed over, built or
-# loaded. A kind is one jitted function (train -> jit_train_step), so
-# this is the newest per module; nothing is read from an executable until
-# someone asks, so a program that cannot give its text costs a step nothing
-_PROGRAMS: Dict[str, Any] = {}
+# one record a step executable `_cached_compile` handed over, built or
+# loaded: newest last, one a (kind, key), the oldest out past the bound (an
+# elastic job that recompiles for ever must not pin executables). A kind is
+# one jitted function (train -> jit_train_step), a key the batch signature
+# it was built for. Nothing is read from an executable until someone asks,
+# so a program that can give neither its text nor its memory analysis
+# costs a compile nothing
+class Program(NamedTuple):
+    kind: str
+    key: Any
+    executable: Any
+    lower_s: float       # tracing and lowering; 0 for a loaded program
+    compile_s: float     # `.compile()`, or the CompileCache's load
+    loaded: bool         # the model's own CompileCache handed it over
+
+
+PROGRAMS_KEPT = 32
+_PROGRAMS: "OrderedDict[Tuple[str, Any], Program]" = OrderedDict()
+_PROGRAMS_LOCK = threading.Lock()
 _MODULE = re.compile(r"HloModule ([^\s,]+)")
 _INSTRUCTION = re.compile(
     r'^\s*(?:ROOT )?%?([\w.\-]+) = (?:.*\bop_name="([^"]*)")?')
 
 
-def note_program(kind: str, executable) -> None:
-    """Keep the newest compiled program of each kind: one dict store a
-    compile."""
-    _PROGRAMS[kind] = executable
+def note_program(kind: str, executable, key: Any = None,
+                 lower_s: float = 0.0, compile_s: float = 0.0,
+                 loaded: bool = False) -> None:
+    """Keep one record of a compiled program: one small store a compile."""
+    rec = Program(kind, key, executable, lower_s, compile_s, loaded)
+    with _PROGRAMS_LOCK:
+        _PROGRAMS.pop((kind, key), None)
+        _PROGRAMS[(kind, key)] = rec
+        while len(_PROGRAMS) > PROGRAMS_KEPT:
+            _PROGRAMS.popitem(last=False)
+
+
+def programs(kind: Optional[str] = None) -> List[Program]:
+    """The records, oldest first; of one kind where one is named."""
+    with _PROGRAMS_LOCK:
+        recs = list(_PROGRAMS.values())
+    return [r for r in recs if kind is None or r.kind == kind]
 
 
 _ALIASED_OUTPUT = re.compile(r"\{([\d, ]*)\}: \((\d+), \{[\d, ]*\}")
@@ -284,12 +320,14 @@ def hlo_scopes(hlo_text: str) -> Dict[str, str]:
 
 
 def program_scopes() -> Dict[str, Dict[str, str]]:
-    """{module name: {instruction name: op_name path}} of the noted step
-    programs, e.g. ``program_scopes()["jit_train_step"]["fusion.7"]`` ->
+    """{module name: {instruction name: op_name path}} of the newest noted
+    program of each kind, e.g.
+    ``program_scopes()["jit_train_step"]["fusion.7"]`` ->
     ``"jit(train_step)/jit(main)/ff.update.emb/dedup/sort"``. Parsed from
     the executables' text on request only."""
     out = {}
-    for kind, executable in _PROGRAMS.items():
+    newest = {rec.kind: rec.executable for rec in programs()}
+    for kind, executable in newest.items():
         try:
             text = executable.as_text()
         except Exception as e:   # noqa: BLE001 - a diagnostic, never fatal
@@ -302,6 +340,51 @@ def program_scopes() -> Dict[str, Dict[str, str]]:
         if module:
             out[module.group(1)] = hlo_scopes(text)
     return out
+
+
+MEMORY_PARTS = ("argument", "output", "alias", "temp", "generated_code")
+
+
+def program_memory(executable) -> Optional[Dict[str, int]]:
+    """Bytes of device memory a compiled program needs, by the compiler's
+    own count (`executable.memory_analysis()`): its arguments, its outputs,
+    the part of the outputs that takes over an argument's buffer (`alias`),
+    the temporaries XLA's schedule keeps live at once (`temp`), the code,
+    and `counted` = argument + output - alias + temp: what must be free on
+    the chip for the program to run. The runtime's `peak_bytes_in_use`
+    leaves the temporaries out. None for an executable that gives no
+    analysis (a deserialized one may not); read on request only."""
+    try:
+        ma = executable.memory_analysis()
+        out = {part: int(getattr(ma, f"{part}_size_in_bytes"))
+               for part in MEMORY_PARTS}
+    except Exception:   # noqa: BLE001 - a diagnostic, never fatal
+        return None
+    out["counted"] = (out["argument"] + out["output"] - out["alias"]
+                      + out["temp"])
+    return out
+
+
+STEP_KINDS = ("train", "superstep")
+
+
+def collect_step_programs():
+    """Registry collector (obs.metrics): what the newest step program of
+    each kind needs and what the step programs cost to build, read from
+    the records at scrape time."""
+    for kind in STEP_KINDS:
+        recs = programs(kind)
+        if not recs:
+            continue
+        memory = program_memory(recs[-1].executable)
+        for part, n in (memory or {}).items():
+            yield "ff_step_hbm_bytes", {"kind": kind, "part": part}, n
+        yield ("ff_step_compile_seconds", {"kind": kind, "phase": "lower"},
+               sum(r.lower_s for r in recs))
+        yield ("ff_step_compile_seconds", {"kind": kind, "phase": "compile"},
+               sum(r.compile_s for r in recs))
+        yield ("ff_step_programs_loaded_total", {"kind": kind},
+               sum(r.loaded for r in recs))
 
 
 # ---------------------------------------------------------------------

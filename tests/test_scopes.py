@@ -69,7 +69,7 @@ def _fit(kind, epochs=1, **cfg_kw):
 
 
 def _step_text():
-    return trace._PROGRAMS["train"].as_text()
+    return trace.programs("train")[-1].executable.as_text()
 
 
 def _opcodes(hlo_text):

@@ -286,10 +286,9 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
         params, opt_state, op_state,
         {k: sds((), jnp.float32) for k in model._msums_keys}, batch,
         sds((), jnp.int32)).compile()
-    ma = compiled.memory_analysis()
+    from dlrm_flexflow_tpu.obs import trace as obstrace
     most, kernels, instructions = LM_STEPS[name]
-    counted = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-               - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    counted = obstrace.program_memory(compiled)["counted"]
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == kernels
     if instructions is None:
@@ -300,7 +299,6 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
     # every carry takes over its input's buffer, the counter too; the one
     # output the runtime allocates a dispatch is the metrics vector
     # (ISSUE 33), by the TPU compiler's own alias table at real widths
-    from dlrm_flexflow_tpu.obs import trace as obstrace
     assert obstrace.fresh_outputs(compiled) == 1
     # the balance update is in the step that asked for it, and in no other
     assert ("/balance/" in text) == (name != "qwen3_next_80b_a3b")
