@@ -89,15 +89,29 @@ class FFConfig:
     # lax.scan over K pre-staged batches, core/model.py _train_superstep)
     # so a single host→device dispatch trains K steps — amortizing the
     # per-step dispatch overhead that dominates small-batch DLRM
-    # (ROADMAP S1). 1 = the exact legacy per-step
-    # dispatch; "auto" picks K from the megabatch bytes against a
-    # staging budget (search/cost_model.py HBM capacity on TPU, a host
-    # RAM cap elsewhere). Checkpoints/save_every snap to superstep
-    # boundaries (fit() validates save_every % K == 0); host-resident-
-    # table models fall back to K=1 with a one-time warning (their
-    # per-step host gather/scatter cannot run inside the scan). Set
-    # with --superstep {K,auto}.
-    superstep: "int | str" = 1
+    # (ROADMAP S1). "auto" (the default): fit() decides by itself. A
+    # batch shape starts on the per-step path; at PROBE_DISPATCHES (32)
+    # of its full-batch dispatches fit() asks, without waiting, whether
+    # the device had already finished the step issued a quarter of the
+    # throttle's bound before (8 dispatches on a TPU: the host learns
+    # of a finished step about a millisecond late; core/model.py
+    # _Pace); where HOST_PACED_SHARE (90%) of them found it through the
+    # host sets the pace, and fit() goes on in
+    # supersteps of the largest power of two K <= 16 whose megabatch
+    # fits a staging budget (5% of a chip's HBM, 128 MB of host RAM
+    # elsewhere). The verdict is kept on the model, once a shape. Auto
+    # never fuses: an epoch shorter than the probe plus one K, a loop
+    # that waits for its input, host-resident tables, more than one
+    # process; with save_every set it halves K until every snapshot of
+    # the per-step run is still written, at its step (K divides
+    # save_every, the step the fit starts from and an epoch's steps;
+    # FFModel._auto_superstep), and never raises. 1 = the exact
+    # per-step dispatch, no probe. An integer K is honoured as given, no
+    # probe: checkpoints/save_every snap to superstep boundaries (fit()
+    # rejects save_every % K != 0); host-resident-table models fall back
+    # to K=1 with a one-time warning (their per-step host gather/scatter
+    # cannot run inside the scan). Set with --superstep {K,auto}.
+    superstep: "int | str" = "auto"
     # fit(): whether to pre-stage the WHOLE dataset on device when it fits
     # the HBM budget ("auto"), always ("always" — trusts the caller on
     # capacity), or never ("never" — forces the streaming/prefetch path;

@@ -146,6 +146,83 @@ class _Throttle:
                 jax.block_until_ready(self._vectors.popleft())
         return mets
 
+    @property
+    def lag(self) -> int:
+        """How many dispatches back fit()'s pace probe asks: a quarter
+        of the bound (8 on a TPU, 1 on the CPU)."""
+        return max(1, self.bound // 4)
+
+    def behind(self):
+        """The metrics vector of the dispatch issued `lag` dispatches
+        ago; None while fewer are held."""
+        return (self._vectors[-self.lag]
+                if len(self._vectors) >= self.lag else None)
+
+
+# Who sets the pace of a batch shape's steps, as fit() finds out by
+# itself under `FFConfig.superstep == "auto"`: at a dispatch it asks,
+# without waiting, whether the device had already finished the step it
+# issued `_Throttle.lag` dispatches ago (`is_ready()` on that step's
+# metrics vector: 0.3 us, 5 us the first time an array is asked). Where
+# the device sets the pace the queue is as deep as the throttle allows
+# and that step is far from done; where the host does, the device is
+# through with everything but the newest few. Not the step before: the
+# host learns of a finished step late. On the v5e, a 0.116 ms step under
+# a 0.46 ms dispatch (`dlrm_terabyte.b128_local`; PERF.md §6, PR 35) read
+# ready at 0% of the dispatches one later, 43-56% two later and 100%
+# from three on (0.5-1.4 ms after it was issued), and three device-paced
+# loops read 0% at every lag from 1 to 31 once their queue had filled. A
+# quarter of the bound leaves room on both sides: eight dispatches for
+# the news to arrive, and a queue that has to be a quarter full before a
+# loop reads device-paced.
+# PROBE_DISPATCHES such answers make a verdict (as many as the throttle
+# lets the host run ahead on a TPU), counted from the `lag`-th dispatch
+# after an epoch's start, where a callback may have drained the queue,
+# and only at dispatches whose input is staged already
+# (`BatchFeed.at_hand`): a loop that waits for its input finds the
+# device idle as well, and K steps a dispatch buy it nothing.
+# A fit that probes waits for its first dispatch, once: a program's
+# first run loads it onto the chip (tens of ms), the host meanwhile
+# issues all the throttle lets it, and every answer of a probe would be
+# about that backlog (2 of 32 read ready on that cell without the wait).
+# HOST_PACED_SHARE of them finding the device through say the host sets
+# the pace: a host-paced loop reads 100%, a device-paced one reads the
+# few dispatches before its queue has filled or after something drained
+# it in mid-epoch (a snapshot), and between the two a second step
+# program is not worth its compile: fusing K steps buys at most the
+# share of the step the device waited.
+PROBE_DISPATCHES = 32
+HOST_PACED_SHARE = 0.9
+
+
+class _Pace:
+    """The probe's count for one batch shape (`FFModel._pace`, by the
+    step's `_exec_key`) and, once PROBE_DISPATCHES answers are in, its
+    verdict; kept on the model, so a shape is probed once."""
+
+    __slots__ = ("batch_size", "asked", "idle")
+
+    def __init__(self, batch_size: int):
+        self.batch_size, self.asked, self.idle = int(batch_size), 0, 0
+
+    def note(self, idle: bool) -> bool:
+        """Count one answer; True when it completed the probe."""
+        self.asked += 1
+        self.idle += bool(idle)
+        return self.asked == PROBE_DISPATCHES
+
+    @property
+    def share(self) -> Optional[float]:
+        """Share of the dispatches asked that found the device idle."""
+        return self.idle / self.asked if self.asked else None
+
+    @property
+    def host_paced(self) -> Optional[bool]:
+        """The verdict; None while the probe runs."""
+        if self.asked < PROBE_DISPATCHES:
+            return None
+        return self.idle >= HOST_PACED_SHARE * self.asked
+
 
 class FFModel:
     def __init__(self, config: Optional[FFConfig] = None):
@@ -1056,6 +1133,9 @@ class FFModel:
         self._train_step_execs = {}
         self._superstep_execs = {}
         self._eval_step_execs = OrderedDict()
+        # fit()'s pace probe, by the step's shape key: a new step
+        # function or a new mesh is asked again
+        self._pace: Dict[tuple, _Pace] = {}
         policy = getattr(self.config, "anomaly_policy", "none") or "none"
         if policy not in ("none", "skip_step", "rollback", "raise"):
             raise ValueError(
@@ -2002,21 +2082,24 @@ class FFModel:
 
     # --- fused supersteps ---------------------------------------------
     def resolve_superstep(self, batch_size: Optional[int] = None) -> int:
-        """The superstep K this model actually trains with.
+        """The superstep K this model's fit() would train with NOW, an
+        epoch's length and `save_every` aside (`_auto_superstep`).
 
-        FFConfig.superstep: 1 = the exact legacy per-step dispatch; an
-        int K>1 fuses K steps per dispatch; "auto" picks the largest
-        power-of-two K <= 16 whose stacked megabatch fits the staging
-        budget (5% of per-chip HBM on TPU — the megabatch lives beside
-        params/opt state/activations — or a 128 MB host-RAM cap
-        elsewhere). Host-resident-table models always resolve to 1 with
-        a one-time warning: their per-step host gather/scatter cannot
-        run inside the fused scan yet."""
+        FFConfig.superstep: 1 = the exact per-step dispatch; an int K>1
+        fuses K steps per dispatch; "auto" (the default) fuses only a
+        batch shape whose pace the host sets, as fit()'s own probe found
+        (`_Pace`): the staging rule's K (`_staging_superstep`) once a
+        verdict says so, 1 before and otherwise. The search prices the
+        dispatch floor over this K (search/simulator.py). Host-resident-
+        table models always resolve to 1, with a one-time warning when a
+        K was asked for: their per-step host gather/scatter cannot run
+        inside the fused scan yet."""
         raw = getattr(self.config, "superstep", 1)
         if raw in (None, "", 1, "1"):
             return 1
         if getattr(self, "_host_resident_list", None):
-            if not getattr(self, "_superstep_host_warned", False):
+            if raw != "auto" and not getattr(
+                    self, "_superstep_host_warned", False):
                 self._superstep_host_warned = True
                 log_model.warning(
                     "superstep=%s requested, but ops %s keep their "
@@ -2031,6 +2114,16 @@ class FFModel:
                 raise ValueError(f"superstep must be >= 1, got {raw!r}")
             return k
         bs = int(batch_size or self.config.batch_size)
+        if not any(p.batch_size == bs and p.host_paced
+                   for p in getattr(self, "_pace", {}).values()):
+            return 1
+        return self._staging_superstep(bs)
+
+    def _staging_superstep(self, bs: int) -> int:
+        """The largest power-of-two K <= 16 whose stacked megabatch fits
+        the staging budget (5% of per-chip HBM on TPU — the megabatch
+        lives beside params/opt state/activations — or a 128 MB host-RAM
+        cap elsewhere)."""
         scale = bs / max(self.config.batch_size, 1)
         tensors = list(self.input_tensors)
         if self.label_tensor is not None:
@@ -2047,6 +2140,36 @@ class FFModel:
         while k > 1 and k * per_batch > budget:
             k //= 2
         return k
+
+    def _auto_superstep(self, bs: int, num_batches: int, save_every: int,
+                        step0: int = 0, epoch_steps: int = 0) -> int:
+        """The K a host-paced verdict fuses THIS fit to under "auto": the
+        staging rule's, halved until an epoch holds the probe and one
+        group of K (a shorter epoch never fuses, probing or not: the same
+        shape and epoch give the same K, so a later fit builds nothing).
+
+        With `save_every` set, snapshots land where per-step training
+        puts them, all of them: the feed aligns groups on the batch
+        index, the snapshot test reads `_step`, and a fused dispatch
+        that starts off a multiple of K steps over multiples of
+        `save_every`. So K is halved further until it divides
+        `save_every`, the step at which this fit's first epoch has its
+        batch 0 (`step0`: an earlier fit, a resume) and the steps of an
+        epoch (`epoch_steps`: the full batches and the remainder; 0 when
+        one epoch is left); 1 where no K does."""
+        k = self._staging_superstep(bs)
+        while k > 1 and (PROBE_DISPATCHES + k > num_batches
+                         or (save_every and (save_every % k or step0 % k
+                                             or epoch_steps % k))):
+            k //= 2
+        return k
+
+    def _idle_at_dispatch(self, vector) -> bool:
+        """Had the device already finished the step issued
+        `_Throttle.lag` dispatches ago (`vector`: its metrics vector) as
+        fit() issues the next? Asked without waiting. The pace probe's
+        one reading, and the one seam a test answers in its place."""
+        return vector.is_ready()
 
     def _superstep_sharding(self, sh: NamedSharding) -> NamedSharding:
         """Input sharding for a stacked [K, batch, ...] megabatch: the
@@ -2786,15 +2909,14 @@ class FFModel:
         return max(0.0, 0.7 * TPUSpec.detect().hbm_capacity_bytes
                    - resident), split
 
-    def _warm_up(self, feed) -> None:
-        """AOT-compile the train step — and the fused scan under a
-        superstep — against the first batch as the feed stages it, so the
-        loop starts warm without consuming a real optimizer step (the
-        reference warms its Legion trace during epoch 0 instead,
-        dlrm.cc:178-185). The executables are cached under the SAME keys
-        the loop's dispatches ask for, so its first step builds nothing.
-        A fit(batch_size=) the graph cannot take fails here, with the
-        reason."""
+    def _warm_up(self, feed) -> tuple:
+        """AOT-compile the train step against the first batch as the
+        feed stages it, so the loop starts warm without consuming a real
+        optimizer step (the reference warms its Legion trace during epoch
+        0 instead, dlrm.cc:178-185). The executable is cached under the
+        SAME key the loop's dispatches ask for, so its first step builds
+        nothing; returns that key, the step's shape. A fit(batch_size=)
+        the graph cannot take fails here, with the reason."""
         def refuse(e, cannot):
             if feed.bs == self.config.batch_size:
                 raise e
@@ -2811,19 +2933,22 @@ class FFModel:
         args = self._step_args(item.device_batch)
         if item.host_idx is not None:
             args = args + (self._host_emb_forward(item.host_idx),)
+        key = self._exec_key(item.device_batch)
         try:
-            self._executable("train", self._train_step_execs,
-                             self._exec_key(item.device_batch),
+            self._executable("train", self._train_step_execs, key,
                              self._train_step, args)
         except Exception as e:
             refuse(e, "compile against this graph (an op bakes the "
                       "compile-time batch {} into its shape)")
-        if feed.k > 1:
-            sbatch = self._stage_superstep(
-                feed.host_slice(0, feed.k)).device_batch
-            self._executable("superstep", self._superstep_execs,
-                             (feed.k,) + self._exec_key(sbatch),
-                             self._superstep_fn, self._step_args(sbatch))
+        return key
+
+    def _warm_up_superstep(self, feed, k: int) -> None:
+        """The same for the fused scan of `k` steps: before the loop, or
+        where fit()'s probe switches to it in mid-epoch."""
+        sbatch = self._stage_superstep(feed.host_slice(0, k)).device_batch
+        self._executable("superstep", self._superstep_execs,
+                         (k,) + self._exec_key(sbatch),
+                         self._superstep_fn, self._step_args(sbatch))
 
     def _drift_monitor(self, name: str):
         """--obs on: process-wide metrics + span tracing + the drift
@@ -2899,19 +3024,18 @@ class FFModel:
         # --- fused supersteps -------------------------------------------
         # K full batches train as ONE dispatch (lax.scan executable);
         # what cannot align to a K boundary falls back to exact K=1 steps
-        # (the feed's schedule). K=1 IS the legacy path, bitwise.
-        k_super = self.resolve_superstep(bs)
+        # (the feed's schedule). K=1 IS the legacy path, bitwise. Under
+        # "auto" a shape starts there and the loop below asks who sets
+        # its pace (`_Pace`): `auto_k`, taken once the resume has said
+        # where this fit starts, is what a host-paced verdict fuses it
+        # to, and it never refuses a `save_every`.
+        auto = getattr(self.config, "superstep", 1) == "auto"
+        k_super = 1 if auto else self.resolve_superstep(bs)
         if k_super > num_batches:
-            if getattr(self.config, "superstep", 1) == "auto":
-                # auto picked more lookahead than one epoch holds:
-                # shrink to the largest power of two that fits
-                while k_super > num_batches:
-                    k_super //= 2
-            else:
-                log_model.warning(
-                    "superstep K=%d exceeds the %d batches per epoch; "
-                    "running per-step (K=1)", k_super, num_batches)
-                k_super = 1
+            log_model.warning(
+                "superstep K=%d exceeds the %d batches per epoch; "
+                "running per-step (K=1)", k_super, num_batches)
+            k_super = 1
         if k_super > 1 and save_every and save_every % k_super != 0:
             raise ValueError(
                 f"save_every={save_every} is not a multiple of the "
@@ -2953,6 +3077,8 @@ class FFModel:
                 return {"elapsed": 0.0, "throughput": 0.0,
                         "num_samples": 0, "rollbacks": 0,
                         "recoveries": 0, "expansions": 0,
+                        "superstep": 1, "fused_steps": 0,
+                        "idle_at_dispatch_share": None,
                         "metrics": self.perf.report()}
             if (self._anomaly_policy == "rollback"
                     or getattr(self.config, "elastic", "off") == "resume") \
@@ -2970,6 +3096,14 @@ class FFModel:
                 "mesh degradation mid-run will have no snapshot to "
                 "resume from and will re-raise")
 
+        # the first epoch's batch 0 is (or would have been) step
+        # `_step - start_batch`; more than one epoch from here, and K
+        # divides an epoch's steps too
+        auto_k = self._auto_superstep(
+            bs, num_batches, save_every, self._step - start_batch,
+            (num_batches + (n > num_batches * bs)
+             if epochs - start_epoch > 1 else 0)) if auto else 1
+
         # --stage-dataset: "never" forces the streamed feed
         # (bench_pipeline compares the two); "always" trusts the caller
         # on capacity. The feed drains (and re-stages,
@@ -2986,7 +3120,21 @@ class FFModel:
                           or 0), 0),
             deadline_s=self._worker_deadline_s() or None,
             on_close=self._host_prefetch_invalidate)
-        self._warm_up(feed)
+        key = self._warm_up(feed)
+        # the probe's count for this shape: kept on the model, so a
+        # verdict reached in an earlier fit holds and this one starts
+        # fused (both programs cached: nothing is built) or stays per
+        # step without asking. Host-resident tables cannot run inside the
+        # scan, and several processes would each ask their own device
+        # and could disagree on the program to run: neither is probed.
+        pace = None
+        if (auto and jax.process_count() == 1
+                and not getattr(self, "_host_resident_list", None)):
+            pace = self._pace.setdefault(key, _Pace(bs))
+            if pace.host_paced:
+                feed.k = auto_k
+        if feed.k > 1:
+            self._warm_up_superstep(feed, feed.k)
         if self.config.profiling:
             # per-op timing report (reference --profiling cudaEvent prints,
             # linear.cu:499-531)
@@ -3006,6 +3154,12 @@ class FFModel:
         start = time.time()
         mets = None
         num_samples = 0
+        fused_steps = single_steps = 0
+        # the probe runs where this fit can ask it anything; its first
+        # dispatch is waited for, once (`settled`)
+        probing = (pace is not None and pace.host_paced is None
+                   and num_batches > throttled.lag)
+        settled = not probing
         rollbacks = 0
         max_rollbacks = getattr(self.config, "max_rollbacks", 3)
         recoveries = 0
@@ -3035,6 +3189,42 @@ class FFModel:
                                       else ((epoch, ent.b), self._step))
                         t_drift = (time.perf_counter()
                                    if drift_mon is not None else 0.0)
+                        if (ent.k > 1 and auto and save_every
+                                and self._step % ent.k):
+                            # something moved `_step` off the groups'
+                            # boundaries (a remainder that was dropped, a
+                            # rollback to another run's snapshot): a fused
+                            # dispatch would step over a snapshot's place
+                            log_model.warning(
+                                "step %d is no multiple of the superstep "
+                                "K=%d; going on per step, so that a "
+                                "snapshot lands every save_every=%d steps",
+                                self._step, ent.k, save_every)
+                            feed.replan(1, epoch, ent.b)
+                            continue
+                        # the probe: was the device waiting for this
+                        # dispatch? Full batches on the per-step path
+                        # whose item the feed has at hand (a loop that
+                        # waits for its input reads idle too, and fusing
+                        # buys it nothing), until the shape has its
+                        # verdict
+                        if (probing and not last and feed.at_hand()
+                                and ent.b - b0 >= throttled.lag
+                                and (behind := throttled.behind())
+                                is not None):
+                            probing = not pace.note(
+                                self._idle_at_dispatch(behind))
+                            if (not probing and pace.host_paced
+                                    and auto_k > 1):
+                                # the host sets this shape's pace: build
+                                # the fused program and go on in
+                                # supersteps from this batch, or the next
+                                # K-aligned one (the schedule keeps the
+                                # batches before it, the tail and the
+                                # remainder single steps)
+                                self._warm_up_superstep(feed, auto_k)
+                                feed.replan(auto_k, epoch, ent.b)
+                                continue
                         try:
                             mets = throttled(self.train_batch_staged(
                                 feed.get(), next_host_idx=peek_idx))
@@ -3050,7 +3240,20 @@ class FFModel:
                             feed.drop_remainder(e)
                             feed.rewind(*nxt)
                             break
+                        if not settled:
+                            # a program's first run loads it onto the
+                            # chip, behind whatever staging queued:
+                            # tens of ms in which the host would issue
+                            # every dispatch of the probe and each would
+                            # find the device busy. The probe starts
+                            # from an empty queue
+                            settled = True
+                            jax.block_until_ready(mets.vector)
                         num_samples += feed.rem if last else bs * ent.k
+                        if ent.k > 1:
+                            fused_steps += ent.k
+                        else:
+                            single_steps += 1
                         if drift_mon is not None and not last:
                             # per-step wall clock the dispatch loop
                             # observed (async pipelining amortized by
@@ -3173,10 +3376,28 @@ class FFModel:
             # same report format intent as reference dlrm.cc:197-198
             print(f"ELAPSED TIME = {elapsed:.4f}s, "
                   f"THROUGHPUT = {throughput:.2f} samples/s")
+        share = pace.share if pace is not None else None
         out = {"elapsed": elapsed, "throughput": throughput,
                "num_samples": num_samples, "rollbacks": rollbacks,
                "recoveries": recoveries, "expansions": expansions,
+               "superstep": feed.k, "fused_steps": fused_steps,
+               "idle_at_dispatch_share": share,
                "metrics": self.perf.report()}
+        if obsmetrics.enabled():
+            steps = obsmetrics.counter(
+                "ff_fit_steps_total", "optimizer steps fit() trained, by "
+                "the dispatch that carried them", ("path",))
+            steps.inc(fused_steps, path="fused")
+            steps.inc(single_steps, path="single")
+            obsmetrics.gauge(
+                "ff_fit_superstep_k", "steps a dispatch of the newest "
+                "fit() of this batch shape", ("shape",)).set(
+                    feed.k, shape=str(bs))
+            if share is not None:
+                obsmetrics.gauge(
+                    "ff_fit_idle_at_dispatch_share", "share of the pace "
+                    "probe's dispatches that found the device idle",
+                    ("shape",)).set(share, shape=str(bs))
         if drift_mon is not None:
             out["drift"] = drift_mon.report()
             obstrace.export_to_dir()   # no-op without --obs-trace-dir

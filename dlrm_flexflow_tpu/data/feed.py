@@ -14,7 +14,7 @@ under batch N's compute — or, at depth 0, each entry is staged as it is
 asked for). Staging is deterministic, so the three hand out equal arrays
 and training is bit-identical (tests/test_feed.py, tests/test_prefetch.py).
 Whatever invalidates staged work goes through the feed: ``rewind``,
-``restage``, ``drop_remainder``, ``close``. The model is not imported
+``restage``, ``replan``, ``drop_remainder``, ``close``. The model is not imported
 here: its stagers (``_stage_step``, ``_stage_superstep``) come in as
 callables.
 """
@@ -162,6 +162,15 @@ class BatchFeed:
                 num_items=len(sched), name="fit",
                 deadline_s=self._deadline_s)
 
+    def replan(self, k: int, epoch: int, b: int) -> None:
+        """Go on in groups of ``k`` from batch ``b`` of ``epoch``: fit()'s
+        pace probe found the host setting the pace in mid-schedule. The
+        resident items are staged again as groups; the batches before the
+        next boundary of ``k`` stay single dispatches."""
+        self.k = int(k)
+        self.restage()
+        self.rewind(epoch, b)
+
     def drop_remainder(self, why: BaseException) -> None:
         """The remainder's shape cannot stage or train: take it out of
         the schedule, loudly (the reference loop silently trains only
@@ -199,6 +208,13 @@ class BatchFeed:
             if ent.epoch == epoch:
                 return ent
         return None
+
+    def at_hand(self) -> bool:
+        """Would :meth:`get` return without waiting for the staging
+        thread? True for a resident feed and for one that stages in
+        :meth:`get` itself; with a ring, when an item waits in it."""
+        return (self._pipe is None or self._ahead is not None
+                or self._pipe.ready())
 
     def get(self):
         """The staged item of the next entry. A staging error surfaces

@@ -222,6 +222,11 @@ class PrefetchPipeline:
             self._cond.notify_all()
         return item
 
+    def ready(self) -> bool:
+        """Does the ring hold an item, so that :meth:`get` returns at
+        once? Asked without the lock (one `len` of a deque)."""
+        return bool(self._buf)
+
     def close(self, join_timeout_s: float = 10.0):
         """Stop the producer, discard staged items, join the thread.
         Never raises — pending staging errors die with the pipeline

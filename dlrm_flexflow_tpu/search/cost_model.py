@@ -35,7 +35,14 @@ from ..utils.logging import log_sim
 # value (ROADMAP S1). benchmarks/calibrate_sim.py re-measures it per
 # sweep (the K→∞ intercept of the bench_superstep ms/step-vs-1/K line,
 # written to benchmarks/dispatch_floor.json); no such record is in the
-# tree.
+# tree. On the attached v5e one dispatch of the MLPerf DLRM step at batch
+# 128 costs fit()'s host 0.48 ms (`train/dispatch`, PERF.md §5, PR 33's
+# chip run: 86 us of Python, 275 of PJRT_LoadedExecutable_Execute, 114
+# of jax's argument and result handling) beside 0.116 ms of device work:
+# the same order as this value, which stays the calibration's. The
+# simulator divides it by the K fit() would run (under the default
+# `superstep="auto"`: the K of a host-paced verdict of fit()'s own
+# probe, else 1; FFModel.resolve_superstep).
 MEASURED_DISPATCH_FLOOR_S = 5.5e-4
 
 # fraction of a PIPELINED (ParallelConfig.overlap) row-shard exchange

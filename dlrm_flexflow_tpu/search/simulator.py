@@ -137,11 +137,14 @@ class Simulator:
         self.topology = list(topology) if topology is not None else None
 
     def _effective_superstep(self) -> int:
-        """The superstep K this model's fit() would actually run
-        (FFModel.resolve_superstep handles "auto" and the host-resident-
-        table K=1 fallback), so the simulated dispatch floor amortizes
-        exactly like the runtime's. Models without the resolver (config
-        stubs in older tests) price the legacy K=1 floor."""
+        """The superstep K this model's fit() would actually run NOW
+        (FFModel.resolve_superstep: an explicit K; under "auto" the K of
+        a host-paced verdict fit()'s own probe has reached for the
+        compile-time batch, else 1; the host-resident-table K=1
+        fallback), so the simulated dispatch floor amortizes exactly
+        like the runtime's: a model that never trained is priced at the
+        whole floor. Models without the resolver (config stubs in older
+        tests) price the legacy K=1 floor."""
         resolve = getattr(self.model, "resolve_superstep", None)
         if resolve is None:
             return 1

@@ -145,10 +145,11 @@ def main(argv=None):
     model.train_batch_device(next_batch())
     jax.block_until_ready(model.params)
 
-    # fused supersteps (--superstep K / auto): the synthetic loop
-    # dispatches K steps per host→device call, amortizing the dispatch
-    # floor exactly like fit() does (loader-fed runs stay per-step here;
-    # use fit() for the full staged/prefetched superstep pipeline)
+    # fused supersteps (--superstep K): the synthetic loop dispatches K
+    # steps per host→device call, amortizing the dispatch floor exactly
+    # like fit() does (loader-fed runs stay per-step here; use fit() for
+    # the full staged/prefetched superstep pipeline, and for the default
+    # "auto": the pace probe is fit()'s, so this loop resolves it to 1)
     k_super = 1
     sstaged = None
     if not multiproc and data_path is None:

@@ -12,7 +12,22 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from dlrm_flexflow_tpu.utils.testing import ensure_cpu_devices  # noqa: E402
 
+import pytest  # noqa: E402
+
 ensure_cpu_devices(8)
+
+
+@pytest.fixture(autouse=True)
+def _fit_finds_the_device_busy(monkeypatch):
+    """`FFConfig.superstep` defaults to "auto": fit() asks at 32
+    dispatches of a batch shape whether the device was idle, and fuses
+    steps where it mostly was. On the CPU backend that answer is a matter
+    of timing, so the suite pins it to "busy" (device-paced: the per-step
+    path, one step program); the tests of the probe answer in its place
+    themselves (tests/test_superstep.py::TestAuto)."""
+    from dlrm_flexflow_tpu.core.model import FFModel
+    monkeypatch.setattr(FFModel, "_idle_at_dispatch",
+                        lambda self, vector: False)
 
 
 def pytest_sessionfinish(session, exitstatus):

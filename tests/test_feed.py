@@ -195,3 +195,72 @@ def test_a_remainder_that_cannot_stage_is_dropped_and_closing_tells():
     feed.rewind(0, 0)
     assert len(closed) == 1
     assert [e.b for e in feed.schedule()] == [0, 1, 2] * 2
+
+
+@pytest.mark.parametrize("kw", [dict(mode="always"),
+                                dict(mode="never", depth=3),
+                                dict(mode="never", depth=0)],
+                         ids=["resident", "streamed", "synchronous"])
+@pytest.mark.parametrize("k,at", [(4, 0), (4, 5), (2, 7), (8, 3), (4, 14)])
+def test_replan_in_mid_schedule_goes_on_in_groups(kw, k, at):
+    """fit()'s pace probe re-plans a feed that began per step: from batch
+    `at` of epoch 0 on, the feed hands out what a feed of groups of `k`
+    from the start hands out from there, and covers every batch once."""
+    n = BS * 14 + 3
+
+    def walk(feed, epochs):
+        out = []
+        for epoch in epochs:
+            while (ent := feed.peek(epoch)) is not None:
+                out.append((ent, feed.get()))
+        return out
+
+    st = _Stagers()
+    with _feed(n, 1, st=st, **kw) as feed:
+        feed.restage()
+        feed.rewind(0, 0)
+        before = [feed.get() for _ in range(at)]
+        assert all(it[0] == "step" for it in before)
+        feed.replan(k, 0, at)
+        after = walk(feed, (0, 1))
+    with _feed(n, k, **kw) as planned:
+        planned.restage()
+        planned.rewind(0, at)
+        want = walk(planned, (0, 1))
+    assert [e for e, _ in after] == [e for e, _ in want]
+    assert any(e.k == k for e, _ in after)
+    first = [e for e, _ in after if e.epoch == 0]
+    assert _covered(first, 14) == list(range(at, 15))
+    # single steps up to the next boundary of k, the tail and the remainder
+    assert all(e.k == 1 for e in first if e.b < -(-at // k) * k)
+    for (ea, a), (_, b) in zip(after, want):
+        assert a[0] == b[0] == ("super" if ea.k > 1 else "step")
+        for name in a[2]:
+            np.testing.assert_array_equal(a[2][name], b[2][name])
+
+
+def test_at_hand_says_whether_get_would_wait_for_the_stager():
+    """fit()'s pace probe asks nothing at a dispatch whose input is not
+    staged yet: a resident feed and a synchronous one always have theirs
+    at hand, a ring when an item waits in it."""
+    import threading
+    import time
+    n = BS * 6
+    for kw in (dict(mode="always"), dict(mode="never", depth=0)):
+        with _feed(n, 1, **kw) as feed:
+            feed.restage()
+            feed.rewind(0, 0)
+            assert feed.at_hand()
+    gate = threading.Event()
+    st = _Stagers()
+    step = st.step
+    st.step = lambda batch: (gate.wait(30), step(batch))[1]
+    with _feed(n, 1, st=st, mode="never", depth=2) as feed:
+        feed.rewind(0, 0)
+        assert not feed.at_hand()       # the stager has not come through
+        gate.set()
+        feed.get()                      # waits for the first item
+        deadline = time.monotonic() + 30
+        while not feed.at_hand() and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert feed.at_hand()           # the second waits in the ring

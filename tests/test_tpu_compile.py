@@ -315,6 +315,71 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
             assert re.search(rf"ff\.l0_mamba\)/{sub}/", text), sub
 
 
+def test_fused_terabyte_step_compiles_for_v5e(one_chip, no_compile_cache,
+                                              monkeypatch):
+    """`dlrm_terabyte.b128_local`'s fused program, K = 16 (what fit()'s
+    pace probe switches that cell to, ISSUE 35), built as the cell's family
+    builds the model: the Pallas kernels run inside the scan's `while`,
+    and the loop carries the 6 GB table in place."""
+    import dlrm_flexflow_tpu as ff
+    from dlrm_flexflow_tpu.models.dlrm import (DLRMConfig, build_dlrm,
+                                               dlrm_strategy)
+    from dlrm_flexflow_tpu.obs import trace as obstrace
+    from dlrm_flexflow_tpu.ops import embedding
+    from dlrm_flexflow_tpu.parallel.mesh import make_mesh
+    from perfbench import manifest as mf
+    monkeypatch.setattr(
+        embedding, "_pallas_common",
+        lambda model, op_name, width_ok: bool(width_ok)
+        and model.config.use_pallas)
+    config = mf.load_config(mf.load(), "dlrm_terabyte")
+    family = mf.load_family(config["family"])
+    rows = family.held_table_rows(config, 1)
+    batch, k = 128, 16
+    dcfg = DLRMConfig(
+        embedding_size=list(rows),
+        embedding_bag_size=int(config["bag_size"]),
+        sparse_feature_size=int(config["embedding_dim"]),
+        mlp_bot=list(config["mlp_bot"]), mlp_top=list(config["mlp_top"]),
+        arch_interaction_op=config["interaction"])
+    model = ff.FFModel(ff.FFConfig.parse_args(
+        ["-b", str(batch), "--compute-dtype", config["compute_dtype"]]))
+    build_dlrm(model, dcfg)
+    model.compile(ff.SGDOptimizer(lr=0.01), config["loss"], ["mse"],
+                  mesh=make_mesh(devices=[one_chip._device]),
+                  strategies=dlrm_strategy(model, dcfg, 1, row_shard=False))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = {op.name: {n: sds(d.shape, d.dtype)
+                        for n, d in op.param_defs().items()}
+              for op in model.ops if op.param_defs()}
+    assert (11_739_136, 128) in [tuple(a.shape) for sub in params.values()
+                                 for a in sub.values()]
+    opt_state = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(model.optimizer.init_state, params))
+    sbatch = {t.name: sds((k,) + tuple(t.shape), t.dtype)
+              for t in model.input_tensors}
+    sbatch["label"] = sds((k,) + tuple(model.label_tensor.shape),
+                          model.label_tensor.dtype)
+    compiled = model._superstep_fn.lower(
+        params, opt_state, {},
+        {n: sds((), jnp.float32) for n in model._msums_keys}, sbatch,
+        sds((), jnp.int32)).compile()
+    memory = obstrace.program_memory(compiled)
+    text = compiled.as_text()
+    # no second copy of the table: 2,128,896 B of temporaries when this
+    # was written, against 2,161,152 for the single step
+    assert memory["temp"] < 16e6, memory
+    assert memory["alias"] > 6_000_000_000, memory
+    assert obstrace.fresh_outputs(compiled) == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert len(re.findall(r" while\(", text)) == 1
+    assert not re.search(r"= f32\[11739136,128\]\{[^}]*\} copy\(", text)
+
+
 # (heads, query length, key length, q/k width, v width, causal): the three
 # width pairs of ISSUE 30 at a short sequence; then ISSUE 31's: both
 # language-model cells' shapes, a sequence the largest block does not
