@@ -19,12 +19,18 @@ per head (RMS, plain scale), gated by SiLU(z) and projected back.
 `gated_delta_rule_chunked` computes the recurrence a chunk of 64 positions
 at a time (the WY / UT form): inside a chunk the u_t solve one unit
 lower-triangular system, (I + M) U = V_beta - (K_beta * Gamma) S_0, done
-for every chunk of a span at once; only the hand-over of S from chunk to
-chunk is a sequential `lax.scan`. A long sequence is walked a span of
-`SPAN` positions at a time, each span recomputed in the backward, so what
-the per-chunk matrices take is a span's and not the sequence's. The state
-and every accumulation are fp32; the operands of the large products are in
-the compute dtype. The backward is autodiff's through the same scans.
+for every chunk of a span at once (scope `prep`); only the hand-over of S
+from chunk to chunk is sequential (scope `hand_over`). On one TPU with
+tile-aligned heads it is one Pallas kernel a span with S resident in VMEM
+and that kernel's reverse for the backward
+(`ops/pallas/delta_kernel.py`: `delta_hand_over_fwd`, `_bwd`), which also
+form the decayed copies of q and k and the masked scores on the tiles they
+load; everywhere else those are arrays and the hand-over a `lax.scan` that
+autodiff differentiates. A long sequence is walked
+a span of `SPAN` positions at a time, each span recomputed in the
+backward, so what the per-chunk matrices and the kernel's entering states
+take is a span's and not the sequence's. The state and every accumulation
+are fp32; the operands of the large products are in the compute dtype.
 `gated_delta_rule_stepwise` is the recurrence as written above, for tests.
 """
 
@@ -40,6 +46,7 @@ from ..core.initializers import (ConstantInitializer, DEFAULT_KERNEL_INIT,
                                  Initializer, UniformInitializer)
 from ..core.op import Op, ParamDef
 from .norm import rms_norm
+from .pallas import delta_kernel
 
 CHUNK = 64
 SPAN = 1024     # positions whose chunks are worked on together
@@ -95,11 +102,14 @@ def gated_delta_rule_stepwise(q, k, v, g, beta):
 
 
 def gated_delta_rule_chunked(q, k, v, g, beta, chunk: int = CHUNK,
-                             compute_dtype=jnp.float32, span: int = SPAN):
+                             compute_dtype=jnp.float32, span: int = SPAN,
+                             resident: bool = False):
     """The same result, a chunk at a time. Shapes as the stepwise form; the
     sequence is padded to a whole number of chunks (of spans, where it is
     longer than one) with steps that leave the state as it is (k = v = 0,
-    g = 0). Returns fp32 (b, s, h, dv)."""
+    g = 0). `resident`: the hand-over as the Pallas kernel
+    (`delta_kernel.resident_hand_over_ok` says where). Returns fp32
+    (b, s, h, dv)."""
     b, s, h, dk = q.shape
     dv = v.shape[-1]
     span = -(-min(span, s) // chunk) * chunk
@@ -111,7 +121,7 @@ def gated_delta_rule_chunked(q, k, v, g, beta, chunk: int = CHUNK,
 
     @jax.checkpoint
     def one_span(S, xs):
-        o, S = _delta_rule_span(*xs, S, chunk, compute_dtype)
+        o, S = _delta_rule_span(*xs, S, chunk, compute_dtype, resident)
         return S, o
 
     xs = tuple(jnp.moveaxis(t.reshape((b, -1, span) + t.shape[2:]), 1, 0)
@@ -140,7 +150,8 @@ def _unit_lower_inverse(m):
     return inv
 
 
-def _delta_rule_span(q, k, v, g, beta, S0, chunk, compute_dtype):
+def _delta_rule_span(q, k, v, g, beta, S0, chunk, compute_dtype,
+                     resident=False):
     """One span (a whole number of chunks) from state S0 (b, h, dk, dv):
     (o (b, s, h, dv) fp32, the state after it)."""
     b, s, h, dk = q.shape
@@ -152,33 +163,49 @@ def _delta_rule_span(q, k, v, g, beta, S0, chunk, compute_dtype):
         t = jnp.moveaxis(t, 2, 1)
         return t.reshape((b, h, n, chunk) + t.shape[3:])
 
-    q, k, v = chunks(q), chunks(k), chunks(v)
-    g, beta = chunks(g.astype(f32)), chunks(beta.astype(f32))
-
     def mm(x, y, spec):
         return jnp.einsum(spec, x.astype(cdt), y.astype(cdt),
                           preferred_element_type=f32)
 
-    gc = jnp.cumsum(g, axis=-1)                         # (b, h, n, C)
-    diff = gc[..., :, None] - gc[..., None, :]          # gc_i - gc_j
-    tri = jnp.tril(jnp.ones((chunk, chunk), bool))
-    # exp only where i >= j: above the diagonal the difference is positive
-    # and may overflow, and a masked inf would still poison the gradient
-    decay = jnp.where(tri, jnp.exp(jnp.where(tri, diff, 0.0)), 0.0)
-    kb = k.astype(f32) * beta[..., None]
-    vb = v.astype(f32) * beta[..., None]
-    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
-    m = jnp.where(strict, mm(kb, k, "bhnik,bhnjk->bhnij") * decay, 0.0)
-    # (I + M) X = [V_beta | K_beta * Gamma]: one unit lower-triangular
-    # system a chunk, every chunk at once, in fp32
-    rhs = jnp.concatenate([vb, kb * jnp.exp(gc)[..., None]], axis=-1)
-    sol = jnp.einsum("bhnij,bhnjv->bhniv", _unit_lower_inverse(m), rhs,
-                     precision=lax.Precision.HIGHEST)
-    value, k_cumdecay = sol[..., :dv], sol[..., dv:]
-    qk = mm(q, k, "bhnik,bhnjk->bhnij") * decay         # diagonal included
-    q_dec = q.astype(f32) * jnp.exp(gc)[..., None]
-    k_dec = k.astype(f32) * jnp.exp(gc[..., -1:] - gc)[..., None]
-    last = jnp.exp(gc[..., -1])                         # (b, h, n)
+    with jax.named_scope("prep"):
+        q, k, v = chunks(q), chunks(k), chunks(v)
+        g, beta = chunks(g.astype(f32)), chunks(beta.astype(f32))
+        gc = jnp.cumsum(g, axis=-1)                     # (b, h, n, C)
+        diff = gc[..., :, None] - gc[..., None, :]      # gc_i - gc_j
+        tri = jnp.tril(jnp.ones((chunk, chunk), bool))
+        # exp only where i >= j: above the diagonal the difference is
+        # positive and may overflow, and a masked inf would still poison
+        # the gradient
+        decay = jnp.where(tri, jnp.exp(jnp.where(tri, diff, 0.0)), 0.0)
+        kb = k.astype(f32) * beta[..., None]
+        vb = v.astype(f32) * beta[..., None]
+        strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+        m = jnp.where(strict, mm(kb, k, "bhnik,bhnjk->bhnij") * decay, 0.0)
+        # (I + M) X = [V_beta | K_beta * Gamma]: one unit lower-triangular
+        # system a chunk, every chunk at once, in fp32
+        rhs = jnp.concatenate([vb, kb * jnp.exp(gc)[..., None]], axis=-1)
+        sol = jnp.einsum("bhnij,bhnjv->bhniv", _unit_lower_inverse(m), rhs,
+                         precision=lax.Precision.HIGHEST)
+        value, k_cumdecay = sol[..., :dv], sol[..., dv:]
+
+    if resident:
+        # the kernel forms q exp(gc), k exp(gc_last - gc) and the masked,
+        # decayed scores on the tiles it loads; heads and batch are one
+        # axis to it
+        with jax.named_scope("hand_over"):
+            def flat(t):
+                return t.reshape((b * h,) + t.shape[2:])
+            o, S = delta_kernel.hand_over(
+                *(flat(t.astype(cdt)) for t in (q, k, k_cumdecay)),
+                flat(value), flat(gc)[:, :, None, :], flat(S0))
+            o, S = o.reshape(b, h, n * chunk, dv), S.reshape(b, h, dk, dv)
+        return jnp.moveaxis(o, 1, 2), S
+
+    with jax.named_scope("prep"):
+        qk = mm(q, k, "bhnik,bhnjk->bhnij") * decay     # diagonal included
+        q_dec = q.astype(f32) * jnp.exp(gc)[..., None]
+        k_dec = k.astype(f32) * jnp.exp(gc[..., -1:] - gc)[..., None]
+        last = jnp.exp(gc[..., -1])                     # (b, h, n)
 
     def hand_over(S, x):
         qk_i, q_i, k_i, kcd_i, val_i, last_i = x
@@ -189,10 +216,11 @@ def _delta_rule_span(q, k, v, g, beta, S0, chunk, compute_dtype):
              + mm(k_i, v_new, "bhck,bhcv->bhkv"))
         return S, o_i
 
-    xs = tuple(jnp.moveaxis(t, 2, 0)
-               for t in (qk, q_dec, k_dec, k_cumdecay, value, last))
-    S, o = lax.scan(hand_over, S0, xs)
-    o = jnp.moveaxis(o, 0, 2).reshape(b, h, n * chunk, dv)
+    with jax.named_scope("hand_over"):
+        xs = tuple(jnp.moveaxis(t, 2, 0)
+                   for t in (qk, q_dec, k_dec, k_cumdecay, value, last))
+        S, o = lax.scan(hand_over, S0, xs)
+        o = jnp.moveaxis(o, 0, 2).reshape(b, h, n * chunk, dv)
     return jnp.moveaxis(o, 1, 2), S
 
 
@@ -262,15 +290,21 @@ class GatedDeltaNet(Op):
         with jax.named_scope("conv"):
             qkv = jax.nn.silu(causal_depthwise_conv(qkv, params["conv"]))
         with jax.named_scope("scan"):
-            rep = hv // hk
-            q = l2_normalize(qkv[..., :kd].reshape(b, s, hk, dk)) * dk ** -0.5
-            k = l2_normalize(qkv[..., kd:2 * kd].reshape(b, s, hk, dk))
-            q, k = (jnp.repeat(t.astype(cdt), rep, axis=2) for t in (q, k))
-            v = qkv[..., 2 * kd:].reshape(b, s, hv, dv).astype(cdt)
-            beta = jax.nn.sigmoid(ba[..., :hv])
-            g = (-jnp.exp(params["A_log"].astype(f32))
-                 * jax.nn.softplus(ba[..., hv:] + params["dt_bias"]))
-            o = gated_delta_rule_chunked(q, k, v, g, beta, CHUNK, cdt)
+            with jax.named_scope("prep"):
+                rep = hv // hk
+                q = (l2_normalize(qkv[..., :kd].reshape(b, s, hk, dk))
+                     * dk ** -0.5)
+                k = l2_normalize(qkv[..., kd:2 * kd].reshape(b, s, hk, dk))
+                q, k = (jnp.repeat(t.astype(cdt), rep, axis=2)
+                        for t in (q, k))
+                v = qkv[..., 2 * kd:].reshape(b, s, hv, dv).astype(cdt)
+                beta = jax.nn.sigmoid(ba[..., :hv])
+                g = (-jnp.exp(params["A_log"].astype(f32))
+                     * jax.nn.softplus(ba[..., hv:] + params["dt_bias"]))
+            o = gated_delta_rule_chunked(
+                q, k, v, g, beta, CHUNK, cdt,
+                resident=delta_kernel.resident_hand_over_ok(
+                    self.model, CHUNK, dk, dv))
         with jax.named_scope("gate_norm"):
             o = (rms_norm(o, params["norm"], self.eps, False)
                  * jax.nn.silu(z.reshape(b, s, hv, dv)))
@@ -288,3 +322,19 @@ class GatedDeltaNet(Op):
 
     def sequential_steps(self, pc=None, vmem_bytes: int = 0) -> int:
         return -(-self.outputs[0].shape[1] // CHUNK)
+
+    def scan_param_stream_bytes(self) -> int:
+        # what the `lax.scan` streams every chunk is no weight but the
+        # state: three reads and a write of (hv, dk, dv) fp32 a sample
+        return 4 * self.outputs[0].shape[0] * self.hv * self.dk * self.dv * 4
+
+    def scan_weights_resident(self, pc=None, vmem_bytes: int = 0) -> bool:
+        """Whether the state stays in VMEM from chunk to chunk (the Pallas
+        hand-over). With `pc`, for that candidate on the TPU target: one
+        part, since a direct Pallas call cannot run under GSPMD."""
+        if pc is None:
+            return delta_kernel.resident_hand_over_ok(
+                self.model, CHUNK, self.dk, self.dv)
+        itemsize = jnp.dtype(self.model.compute_dtype).itemsize
+        return pc.num_parts == 1 and delta_kernel.shapes_fit(
+            CHUNK, self.dk, self.dv, itemsize)

@@ -94,8 +94,9 @@ def _census():
         if "ff." not in path and opcodes[name] not in PLUMBING
         # a reduction's or a scatter's combiner: its own tiny computation,
         # named after the primitive (inside a recomputed block:
-        # `checkpoint/<primitive>`), never an event of the trace
-        and "/" in path.removeprefix("checkpoint/")}
+        # `checkpoint/<primitive>`; inside a scope of a recomputed span:
+        # `checkpoint/prep/<primitive>`), never an event of the trace
+        and "/" in re.sub(r"^(checkpoint/((prep|hand_over)/)?)?", "", path)}
     assert set(unscoped.values()) <= {STEP_COUNTER}, unscoped
     return scopes
 
@@ -268,7 +269,8 @@ def test_every_pallas_call_is_named_and_scoped():
                 assert isinstance(kw.get("name"), ast.Constant), where
                 assert scoped.get(id(node)) == kw["name"].value, where
                 names.append(kw["name"].value)
-    assert sorted(names) == ["emb_gather", "emb_run_sum", "emb_scatter_add",
+    assert sorted(names) == ["delta_hand_over_bwd", "delta_hand_over_fwd",
+                             "emb_gather", "emb_run_sum", "emb_scatter_add",
                              "emb_scatter_write", "interaction_fused",
                              "lstm_bwd", "lstm_fwd", "topk"]
 

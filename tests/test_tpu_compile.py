@@ -174,9 +174,14 @@ def test_expert_walk_compiles_for_v5e(one_chip, no_compile_cache):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 
-def test_chunked_delta_rule_compiles_for_v5e(one_chip, no_compile_cache):
+@pytest.mark.parametrize("resident", [False, True])
+def test_chunked_delta_rule_compiles_for_v5e(one_chip, no_compile_cache,
+                                             resident):
     """The gated delta rule's chunked scan at Qwen3-Next's head sizes, two
-    spans of the sequence, forward and backward."""
+    spans of the sequence, forward and backward; with the hand-over as the
+    `lax.scan` and as the Pallas kernel (ISSUE 36). The kernel's route
+    holds no `while` but the scan over spans and its reverse: the sixteen
+    chunks of a span are one call's grid."""
     from dlrm_flexflow_tpu.ops import delta_net
     b, s, h, d = 1, 2 * delta_net.SPAN, 32, 128
 
@@ -185,12 +190,37 @@ def test_chunked_delta_rule_compiles_for_v5e(one_chip, no_compile_cache):
 
     def loss(q, k, v, g, beta):
         return jnp.sum(delta_net.gated_delta_rule_chunked(
-            q, k, v, g, beta, compute_dtype=jnp.bfloat16))
+            q, k, v, g, beta, compute_dtype=jnp.bfloat16,
+            resident=resident))
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         sds((b, s, h, d)), sds((b, s, h, d)), sds((b, s, h, d)),
         sds((b, s, h), jnp.float32), sds((b, s, h), jnp.float32)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+    text = compiled.as_text()
+    whiles = len(re.findall(r" while\(", text))
+    # the forward, the span's forward again with the entering states, and
+    # the reverse, each under its own name (what `tracereduce` prints)
+    names = re.findall(r"%(delta_hand_over_\w+?)[.\d]* = ", text)
+    if resident:
+        assert whiles == 2, whiles
+        assert sorted(names) == ["delta_hand_over_bwd"] + 2 * [
+            "delta_hand_over_fwd"]
+        assert "/hand_over/delta_hand_over_fwd/" in text
+        assert "/hand_over/delta_hand_over_bwd/" in text
+        assert "/prep/" in text
+        # `perfbench/tracereduce.py` files an op under `mosaic` by the
+        # first 600 characters after the instruction's name, and the
+        # backward's eight operands and six results come first
+        from perfbench import tracereduce
+        for line in text.splitlines():
+            if re.match(r"\s*%delta_hand_over_\w+ = ", line):
+                detail = tracereduce.split_hlo(line.strip())[1]
+                assert "tpu_custom_call" in detail[
+                    :tracereduce.DETAIL_CHARS], line
+    else:
+        assert whiles > 2 and not names
+        assert "tpu_custom_call" not in text
 
 
 # configuration -> (the most the compiler may count for the whole step, in
@@ -215,9 +245,14 @@ def test_chunked_delta_rule_compiles_for_v5e(one_chip, no_compile_cache):
 # 30,644 -> 30,656), GLM's bytes +30,720 (13,859,009,024 before), and the
 # compiler's schedule of the Qwen3-Next step came out 242 MB of temporaries
 # smaller (14,474,101,248 before); PERF.md, PR 33, has the chip's reading.
+# ISSUE 36 took the delta rule's hand-over, and the decayed copies of q and
+# k that only fed it, out of XLA's hands: the Qwen3-Next step is
+# 126,281,216 bytes of temporaries and 3,284 instructions smaller
+# (14,231,714,304 and 30,656 before) and holds twelve kernel calls more,
+# four a delta layer; GLM's pin did not move.
 LM_STEPS = {"glm_4_7_flash": (13_859_039_744, 24, 26_875),
             "nemotron_3_nano_30b_a3b": (14_000_000_000, 4, None),
-            "qwen3_next_80b_a3b": (14_231_714_304, 4, 30_656)}
+            "qwen3_next_80b_a3b": (14_105_433_088, 16, 27_372)}
 
 
 @pytest.mark.parametrize("name", sorted(LM_STEPS))
@@ -231,6 +266,7 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
     import numpy as np
     import dlrm_flexflow_tpu as ff
     from dlrm_flexflow_tpu.ops import attention, embedding
+    from dlrm_flexflow_tpu.ops.pallas import delta_kernel
     from dlrm_flexflow_tpu.parallel.mesh import make_mesh
     from perfbench import manifest as mf
     monkeypatch.setattr(
@@ -238,6 +274,9 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
         lambda model, op_name, width_ok: bool(width_ok)
         and model.config.use_pallas)
     monkeypatch.setattr(attention, "_hbm_bytes", lambda: 15.75 * 2**30)
+    monkeypatch.setattr(
+        delta_kernel, "resident_hand_over_ok",
+        lambda model, chunk, dk, dv: delta_kernel.shapes_fit(chunk, dk, dv))
     config = mf.load_config(mf.load(), name)
     family = mf.load_family(config["family"])
     mcfg = family.model_config(config, family.held_table_rows(config, 1)[0])
