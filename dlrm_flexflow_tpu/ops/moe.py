@@ -49,9 +49,25 @@ scatter anywhere: a chunk's rows past its expert's last pair are computed
 and masked, and where they lie over the next expert's rows the next chunk
 writes over them.
 
+On one TPU, where a whole expert's matrices fit the chip's VMEM beside their
+gradient and the walk's plan its scalar memory
+(`ops/pallas/moe_kernel.py:grid_walk_ok`: Qwen3-Next's 2,048 x 512 x 3 do,
+GLM-4.7's 2,048 x 1,536 x 3 and Nemotron's F = 1,856 do not), the walk is
+not an XLA loop but the grid of a Pallas kernel, one call a pass
+(`_routed_grid`): a grid step is a trip of `moe_kernel.ROWS` rows, fetches
+its rows' tokens a DMA a row and its expert's matrices once an expert, and
+writes its rows into its own block of a buffer in which an expert's stretch
+starts at a multiple of the trip; the backward keeps an expert's weight
+gradient in VMEM across that expert's trips. The sort, the plan's
+arithmetic, `combine`, the dtypes of every product and the counters are the
+same; everywhere else the loop below, compiled to what it was.
+
 Counters (cumulative, in `op_state`, never read back by the step):
-`tokens` seen, `pairs` routed to each held expert, `rows` the chunks
-computed. rows - sum(pairs) carried no pair.
+`tokens` seen, `pairs` routed to each held expert, `rows` the FORWARD's
+trips computed (`chunk_rows` a trip of the loop, `moe_kernel.ROWS` of the
+kernel). rows - sum(pairs) carried no pair. The loop's backward walks the
+same trips; the kernel's gives a held expert with no pair one trip more
+(its gradient block must be written, as zeros), which `rows` leaves out.
 
 With `balance_rate` gamma > 0 the op also keeps, in `op_state`, the
 router's correction `bias` (num_experts,) and a cumulative `load`, the
@@ -74,6 +90,7 @@ from jax import lax
 
 from ..core.initializers import DEFAULT_KERNEL_INIT, ZeroInitializer
 from ..core.op import Op, ParamDef
+from .pallas import moe_kernel
 
 # Rows of one expert's sorted pairs a trip computes. A trip's cost is mostly
 # fixed (on the v5e at Qwen3-Next's widths ~0.6 ms over forward, recomputation
@@ -227,6 +244,47 @@ def _routed_bwd(rows, top_k, cdt, act, res, ct):
 _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _routed_grid(rows, top_k, cdt, interpret, xt, ws, pair_w, order, counts,
+                 held_pair):
+    """`_routed` with the walk as the grid of a Pallas kernel
+    (`ops/pallas/moe_kernel.py`; `moe_kernel.grid_walk_ok` says where):
+    the same arguments but for the form, which the number of matrices
+    says, and the same result. A trip writes ITS block of the sorted-rows
+    buffer, so an expert's stretch starts at a multiple of `rows` there
+    and `combine` gathers by the aligned position."""
+    with jax.named_scope("dispatch"):
+        pos = _sorted_position(order, held_pair.size)
+    buf, plan = moe_kernel.experts_fwd(rows, top_k, cdt, xt, ws, pair_w,
+                                       order, pos, counts, interpret)
+    with jax.named_scope("combine"):
+        return _sum_pairs(buf, moe_kernel.aligned(plan, pos), held_pair,
+                          top_k)
+
+
+def _routed_grid_fwd(rows, top_k, cdt, interpret, xt, ws, pair_w, order,
+                     counts, held_pair):
+    out = _routed_grid(rows, top_k, cdt, interpret, xt, ws, pair_w, order,
+                       counts, held_pair)
+    return out, (xt, ws, pair_w, order, counts, held_pair)
+
+
+def _routed_grid_bwd(rows, top_k, cdt, interpret, res, ct):
+    xt, ws, pair_w, order, counts, held_pair = res
+    with jax.named_scope("dispatch"):
+        pos = _sorted_position(order, held_pair.size)
+    dx_buf, dw_buf, dws, plan = moe_kernel.experts_bwd(
+        rows, top_k, cdt, xt, ws, pair_w, order, pos, counts, ct, interpret)
+    with jax.named_scope("combine"):
+        pos = moe_kernel.aligned(plan, pos)
+        dxt = _sum_pairs(dx_buf, pos, held_pair, top_k).astype(xt.dtype)
+        dpair_w = jnp.where(held_pair, jnp.take(dw_buf, pos), 0.0)
+    return dxt, dws, dpair_w, None, None, None
+
+
+_routed_grid.defvjp(_routed_grid_fwd, _routed_grid_bwd)
+
+
 class MoE(Op):
     type_name = "MoE"
     recompute = True     # the backward recomputes the block's insides
@@ -340,9 +398,18 @@ class MoE(Op):
             order = jnp.pad(order, (0, self.chunk_rows))
             counts = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
         names = FORMS[self.activation]
-        routed = _routed(self.chunk_rows, self.top_k, cdt, self.activation,
-                         xt, tuple(params[f"w_{n}"] for n in names),
-                         pair_w.reshape(-1), order, counts, key < held)
+        ws = tuple(params[f"w_{n}"] for n in names)
+        # the walk as a kernel's grid where the chip and the shapes allow
+        # it, else as the XLA loop: one rule of what can be observed here
+        grid = moe_kernel.grid_walk_ok(self.model, xt, ws, order)
+        rows = moe_kernel.ROWS if grid else self.chunk_rows
+        if grid:
+            routed = _routed_grid(rows, self.top_k, cdt, False, xt, ws,
+                                  pair_w.reshape(-1), order, counts,
+                                  key < held)
+        else:
+            routed = _routed(rows, self.top_k, cdt, self.activation, xt, ws,
+                             pair_w.reshape(-1), order, counts, key < held)
         with jax.named_scope("shared"):
             shared = _ffn(cdt, self.activation, xt.astype(cdt),
                           tuple(params[f"shared_{n}"] for n in names))
@@ -353,8 +420,8 @@ class MoE(Op):
                 shared = gate * shared
         new_state = {"tokens": state["tokens"] + xt.shape[0],
                      "pairs": state["pairs"] + counts,
-                     "rows": state["rows"] + self.chunk_rows * _walk_plan(
-                         self.chunk_rows, counts)[2]}
+                     "rows": state["rows"] + rows * _walk_plan(
+                         rows, counts)[2]}
         if self.balance_rate:
             with jax.named_scope("balance"):
                 load = jnp.sum(pair_e.reshape(-1, 1) == jnp.arange(

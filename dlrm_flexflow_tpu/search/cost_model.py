@@ -101,6 +101,9 @@ class TPUSpec:
     kernel_launch_s: float = 2e-6     # per-HLO overhead (XLA fused ≈ small)
     hbm_capacity_bytes: float = 16e9  # v5e HBM per chip
     vmem_bytes: int = 128 * 1024 * 1024  # per-core VMEM (v4+ generations)
+    # scalar memory, where a kernel's prefetched operands live: what the
+    # v5e's compiler says it has when one does not fit (PR 37)
+    smem_bytes: int = 1024 * 1024
     # RANDOM HBM row-access model (embedding gather/scatter): fixed setup
     # plus per-row sustained cost. RE-PINNED round 5 (the round-2 numbers
     # were poisoned by the dynamic-roll bottleneck that sat in the same

@@ -272,7 +272,8 @@ def test_every_pallas_call_is_named_and_scoped():
     assert sorted(names) == ["delta_hand_over_bwd", "delta_hand_over_fwd",
                              "emb_gather", "emb_run_sum", "emb_scatter_add",
                              "emb_scatter_write", "interaction_fused",
-                             "lstm_bwd", "lstm_fwd", "topk"]
+                             "lstm_bwd", "lstm_fwd", "moe_experts_bwd",
+                             "moe_experts_fwd", "topk"]
 
 
 def test_a_loaded_executable_gives_the_same_scope_map(tmp_path):

@@ -149,29 +149,64 @@ def test_sparse_update_holds_no_xla_scatter(sds, no_compile_cache, form):
     assert "S(1)" not in made.group(1), made.group(0)
 
 
-def test_expert_walk_compiles_for_v5e(one_chip, no_compile_cache):
+@pytest.mark.parametrize("route", ["walk", "kernel"])
+def test_expert_walk_compiles_for_v5e(one_chip, no_compile_cache, route):
     """The expert op's walk over its sorted pairs at Qwen3-Next's widths,
-    forward and backward: loops with no static trip count, and a chunk's
-    rows, not the worst case's 81,920, set the size of the products. (The
+    forward and backward. As the XLA loop: loops with no static trip
+    count, and a chunk's rows, not the worst case's 81,920, set the size of
+    the products. As the Pallas kernel's grid (ISSUE 37): no `while` at
+    all in the routed path, the two kernels under their names. (The
     two-matrix form walks inside the Nemotron step, below.)"""
     from dlrm_flexflow_tpu.ops import moe
+    from dlrm_flexflow_tpu.ops.pallas import moe_kernel
     T, D, F, held, k = 8192, 2048, 512, 32, 10
+    assert moe_kernel.shapes_fit(D, F, 3)
 
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def loss(xt, wg, wu, wd, pair_w, order, counts, held_pair):
-        return jnp.sum(moe._routed(moe.CHUNK_ROWS, k, jnp.bfloat16, "swiglu",
-                                   xt, (wg, wu, wd), pair_w, order, counts,
-                                   held_pair))
+    def routed(xt, wg, wu, wd, pair_w, order, counts, held_pair):
+        if route == "kernel":
+            return moe._routed_grid(moe_kernel.ROWS, k, jnp.bfloat16, False,
+                                    xt, (wg, wu, wd), pair_w, order, counts,
+                                    held_pair)
+        return moe._routed(moe.CHUNK_ROWS, k, jnp.bfloat16, "swiglu", xt,
+                           (wg, wu, wd), pair_w, order, counts, held_pair)
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+    # the value too: a gradient alone leaves the forward kernel out
+    compiled = jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(routed(*a)), argnums=(0, 1, 2, 3, 4))).lower(
         sds((T, D)), sds((held, D, F)), sds((held, D, F)),
         sds((held, F, D)), sds((T * k,)),
         sds((T * k + moe.CHUNK_ROWS,), jnp.int32), sds((held,), jnp.int32),
         sds((T * k,), jnp.bool_)).compile()
-    assert "while" in compiled.as_text()
+    text = compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+    names = re.findall(r"%(moe_experts_\w+?)[.\d]* = ", text)
+    if route == "walk":
+        assert "while" in text and not names
+        assert "tpu_custom_call" not in text
+        return
+    assert not re.search(r" while\(", text)
+    assert sorted(names) == ["moe_experts_bwd", "moe_experts_fwd"]
+    # under the op's `experts` scope, as autodiff wraps it
+    assert re.search(r"[(/]experts\)*/moe_experts_fwd/", text)
+    assert re.search(r"[(/]experts\)*/moe_experts_bwd/", text)
+    # `perfbench/tracereduce.py` files an op under `mosaic` by the first
+    # 600 characters after the instruction's name, and the profiler's text
+    # spells every operand's type out before its name (the first form of
+    # these kernels, twelve and seventeen operands, was filed under `xla`:
+    # PERF.md, PR 37): the results' types, then the operands with theirs
+    from perfbench import tracereduce
+    made = dict(re.findall(r"^\s*%?(\S+) = (\S+) ", text, re.M))
+    for line in text.splitlines():
+        if re.match(r"\s*%moe_experts_\w+ = ", line):
+            detail = tracereduce.split_hlo(line.strip())[1]
+            operands = re.search(r"custom-call\(([^)]*)\)", detail).group(1)
+            typed = sum(len(made[name.lstrip("%")]) + 1
+                        for name in operands.split(", "))
+            assert detail.find("tpu_custom_call") + typed < \
+                tracereduce.DETAIL_CHARS, line
 
 
 @pytest.mark.parametrize("resident", [False, True])
@@ -249,10 +284,24 @@ def test_chunked_delta_rule_compiles_for_v5e(one_chip, no_compile_cache,
 # k that only fed it, out of XLA's hands: the Qwen3-Next step is
 # 126,281,216 bytes of temporaries and 3,284 instructions smaller
 # (14,231,714,304 and 30,656 before) and holds twelve kernel calls more,
-# four a delta layer; GLM's pin did not move.
+# four a delta layer; GLM's pin did not move. ISSUE 37 made the expert walk
+# the grid of a Pallas kernel where a whole expert fits VMEM beside its
+# gradient, which of the three only Qwen3-Next's does: its step is
+# 2,676,308,992 bytes of temporaries and 1,605 instructions smaller
+# (14,105,433,088 and 27,372 before: the walks' zero-filled buffers, their
+# fp32 accumulators in the loops' carries and XLA's copies of them; 192 of
+# the instructions went when a trip's block and window left the prefetched
+# plan for the index maps, 24 a kernel call, the bytes the same), holds
+# eight kernel calls more (a layer's forward and backward: the block's
+# recomputation needs the router's choice again, not the routed rows) and
+# of its 25 `while`s the nine span scans are left; held to the walk
+# (`.../walk`, the gate left as the CPU finds it) it is the step it was, to
+# the byte and the instruction, as are GLM's (F = 1,536: 183 MB of VMEM)
+# and Nemotron's (F = 1,856 is no multiple of 128), whose pins did not move.
 LM_STEPS = {"glm_4_7_flash": (13_859_039_744, 24, 26_875),
             "nemotron_3_nano_30b_a3b": (14_000_000_000, 4, None),
-            "qwen3_next_80b_a3b": (14_105_433_088, 16, 27_372)}
+            "qwen3_next_80b_a3b": (11_429_124_096, 24, 25_767),
+            "qwen3_next_80b_a3b/walk": (14_105_433_088, 16, 27_372)}
 
 
 @pytest.mark.parametrize("name", sorted(LM_STEPS))
@@ -266,7 +315,7 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
     import numpy as np
     import dlrm_flexflow_tpu as ff
     from dlrm_flexflow_tpu.ops import attention, embedding
-    from dlrm_flexflow_tpu.ops.pallas import delta_kernel
+    from dlrm_flexflow_tpu.ops.pallas import delta_kernel, moe_kernel
     from dlrm_flexflow_tpu.parallel.mesh import make_mesh
     from perfbench import manifest as mf
     monkeypatch.setattr(
@@ -277,6 +326,14 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
     monkeypatch.setattr(
         delta_kernel, "resident_hand_over_ok",
         lambda model, chunk, dk, dv: delta_kernel.shapes_fit(chunk, dk, dv))
+    name, _, walk = name.partition("/")
+    if not walk:
+        monkeypatch.setattr(
+            moe_kernel, "grid_walk_ok",
+            lambda model, xt, ws, order: xt.dtype == jnp.float32
+            and moe_kernel.shapes_fit(*ws[0].shape[1:], len(ws),
+                                      entries=order.size,
+                                      held=ws[0].shape[0]))
     config = mf.load_config(mf.load(), name)
     family = mf.load_family(config["family"])
     mcfg = family.model_config(config, family.held_table_rows(config, 1)[0])
@@ -326,7 +383,7 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
         {k: sds((), jnp.float32) for k in model._msums_keys}, batch,
         sds((), jnp.int32)).compile()
     from dlrm_flexflow_tpu.obs import trace as obstrace
-    most, kernels, instructions = LM_STEPS[name]
+    most, kernels, instructions = LM_STEPS[name + ("/walk" if walk else "")]
     counted = obstrace.program_memory(compiled)["counted"]
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == kernels
@@ -346,6 +403,13 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
     scope = attention._flash_blocks(
         1, seq, seq, mcfg.get("v_head_dim") or mcfg["head_dim"])[1]
     assert f"/attend/{scope}/jit(flash_attention)/pallas_call" in text
+    # the expert walk is two kernels a layer where the gate sends it there,
+    # and then the only `while`s left are the delta rule's span scans
+    walks = len(re.findall(r"%moe_experts_(?:fwd|bwd)[.\d]* = ", text))
+    assert walks == (8 if name == "qwen3_next_80b_a3b" and not walk else 0)
+    if walks:
+        whiles = [line for line in text.splitlines() if " while(" in line]
+        assert len(whiles) == 9 and not any("_moe" in w for w in whiles)
     if name == "nemotron_3_nano_30b_a3b":
         # the recurrence is batched products and no loop: the only `while`s
         # of the step are the expert walks'
