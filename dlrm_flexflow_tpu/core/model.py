@@ -1609,11 +1609,16 @@ class FFModel:
         return self
 
     def expert_stats(self) -> Dict[str, Dict[str, np.ndarray]]:
-        """{expert op: {"tokens", "pairs" (experts held,), "rows"}}: the
-        expert ops' cumulative counters (ops/moe.py), read from the op
-        state; for an op with a balance bias also "load" (all experts,)
-        and the "bias" itself. The step reads back the bias alone."""
-        return {name: {k: np.asarray(v) for k, v in st.items()}
+        """{expert op: {"tokens", "pairs" (experts held,), "rows",
+        "combine_kernel"}}: the expert ops' cumulative counters
+        (ops/moe.py), read from the op state; for an op with a balance bias
+        also "load" (all experts,) and the "bias" itself. The step reads
+        back the bias alone. "combine_kernel" is 1 where the newest trace
+        of the op summed its pairs with the gather-sum kernel, else 0."""
+        ops = {op.name: op for op in self.ops}
+        return {name: dict({k: np.asarray(v) for k, v in st.items()},
+                           combine_kernel=np.asarray(int(getattr(
+                               ops.get(name), "combine_kernel", False))))
                 for name, st in (self.op_state or {}).items()
                 if "pairs" in st}
 
@@ -1622,6 +1627,8 @@ class FFModel:
         for name, st in self.expert_stats().items():
             yield "ff_moe_tokens_total", {"op": name}, int(st["tokens"])
             yield "ff_moe_rows_total", {"op": name}, int(st["rows"])
+            yield ("ff_moe_combine_kernel", {"op": name},
+                   int(st["combine_kernel"]))
             for e, n in enumerate(st["pairs"]):
                 yield ("ff_moe_pairs_total",
                        {"op": name, "expert": str(e)}, int(n))

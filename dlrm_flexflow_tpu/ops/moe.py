@@ -63,6 +63,15 @@ gradient in VMEM across that expert's trips. The sort, the plan's
 arithmetic, `combine`, the dtypes of every product and the counters are the
 same; everywhere else the loop below, compiled to what it was.
 
+`combine`, in both passes of both walks, is likewise a Pallas gather-sum
+where the rule allows it (`ops/pallas/combine_kernel.py:combine_ok`: one
+TPU, bfloat16 rows of whole lane tiles, the landing buffers in the chip's
+VMEM and the plan in its scalar memory): a token's held pairs' rows fetched by DMA, the pairs
+held elsewhere not at all, and summed in VMEM in the order the XLA form adds
+them, to the bit but for the sign of a zero; everywhere else the k gathers.
+The op records the route as the step is traced (`combine_kernel`, in
+`FFModel.expert_stats()`).
+
 Counters (cumulative, in `op_state`, never read back by the step):
 `tokens` seen, `pairs` routed to each held expert, `rows` the FORWARD's
 trips computed (`chunk_rows` a trip of the loop, `moe_kernel.ROWS` of the
@@ -91,7 +100,7 @@ from jax import lax
 
 from ..core.initializers import DEFAULT_KERNEL_INIT, ZeroInitializer
 from ..core.op import Op, ParamDef
-from .pallas import moe_kernel
+from .pallas import combine_kernel, moe_kernel
 
 # Rows of one expert's sorted pairs a trip computes. A trip's cost is mostly
 # fixed (on the v5e at Qwen3-Next's widths ~0.6 ms over forward, recomputation
@@ -152,10 +161,17 @@ def _expert_ffn(cdt, act, xs, ws, w_row):
     return _ffn(cdt, act, xs, ws) * w_row[:, None]
 
 
-def _sum_pairs(rows_buf, pos, held_pair, top_k):
+def _sum_pairs(rows_buf, pos, held_pair, top_k, kernel=False,
+               interpret=False):
     """Each token's sum over the rows of its held pairs, fp32: rows_buf
     (cap, D) is indexed by sorted position, pos and held_pair (T * k,) by
-    pair. One choice of the k at a time: a gather of T rows and an add."""
+    pair. One choice of the k at a time: a gather of T rows and an add; with
+    `kernel` (`combine_kernel.combine_ok` says where) one Pallas gather-sum
+    that fetches the held pairs' rows alone, the same sum bit for bit but
+    for the sign of a zero."""
+    if kernel:
+        return combine_kernel.gather_sum(rows_buf, pos, held_pair, top_k,
+                                         interpret)
     pos, held = pos.reshape(-1, top_k), held_pair.reshape(-1, top_k)
     out = 0.0
     for j in range(top_k):
@@ -178,14 +194,15 @@ def _add_slab(acc, e, g):
     return lax.dynamic_update_slice(acc, slab + g[None], at)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 10))
 def _routed(rows, top_k, cdt, act, xt, ws, pair_w, order, counts,
-            held_pair):
+            held_pair, combine=False):
     """The held experts' part of the result. xt (T, D); ws the experts'
     matrices as `_ffn` takes them, each (held, ...) fp32; pair_w (T * k,)
     fp32; order (T * k + rows,) pair indices sorted by held expert, the
     pairs of experts held elsewhere last, then padding; counts (held,)
-    pairs a held expert; held_pair (T * k,) bool. -> (T, D) fp32."""
+    pairs a held expert; held_pair (T * k,) bool; `combine` whether both
+    passes' `combine` is the kernel. -> (T, D) fp32."""
     plan = _walk_plan(rows, counts)
 
     def trip(j, buf):
@@ -201,17 +218,17 @@ def _routed(rows, top_k, cdt, act, xt, ws, pair_w, order, counts,
                         jnp.zeros((order.size, xt.shape[1]), cdt))
     with jax.named_scope("combine"):
         return _sum_pairs(buf, _sorted_position(order, held_pair.size),
-                          held_pair, top_k)
+                          held_pair, top_k, combine)
 
 
 def _routed_fwd(rows, top_k, cdt, act, xt, ws, pair_w, order, counts,
-                held_pair):
+                held_pair, combine=False):
     out = _routed(rows, top_k, cdt, act, xt, ws, pair_w, order, counts,
-                  held_pair)
+                  held_pair, combine)
     return out, (xt, ws, pair_w, order, counts, held_pair)
 
 
-def _routed_bwd(rows, top_k, cdt, act, res, ct):
+def _routed_bwd(rows, top_k, cdt, act, combine, res, ct):
     xt, ws, pair_w, order, counts, held_pair = res
     plan = _walk_plan(rows, counts)
 
@@ -237,7 +254,8 @@ def _routed_bwd(rows, top_k, cdt, act, res, ct):
          jnp.zeros((order.size,), jnp.float32)))
     with jax.named_scope("combine"):
         pos = _sorted_position(order, held_pair.size)
-        dxt = _sum_pairs(dx_buf, pos, held_pair, top_k).astype(xt.dtype)
+        dxt = _sum_pairs(dx_buf, pos, held_pair, top_k,
+                         combine).astype(xt.dtype)
         dpair_w = jnp.where(held_pair, jnp.take(dw_buf, pos), 0.0)
     return dxt, dws, dpair_w, None, None, None
 
@@ -245,9 +263,9 @@ def _routed_bwd(rows, top_k, cdt, act, res, ct):
 _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 10))
 def _routed_grid(rows, top_k, cdt, interpret, xt, ws, pair_w, order, counts,
-                 held_pair):
+                 held_pair, combine=False):
     """`_routed` with the walk as the grid of a Pallas kernel
     (`ops/pallas/moe_kernel.py`; `moe_kernel.grid_walk_ok` says where):
     the same arguments but for the form, which the number of matrices
@@ -260,17 +278,17 @@ def _routed_grid(rows, top_k, cdt, interpret, xt, ws, pair_w, order, counts,
                                        order, pos, counts, interpret)
     with jax.named_scope("combine"):
         return _sum_pairs(buf, moe_kernel.aligned(plan, pos), held_pair,
-                          top_k)
+                          top_k, combine, interpret)
 
 
 def _routed_grid_fwd(rows, top_k, cdt, interpret, xt, ws, pair_w, order,
-                     counts, held_pair):
+                     counts, held_pair, combine=False):
     out = _routed_grid(rows, top_k, cdt, interpret, xt, ws, pair_w, order,
-                       counts, held_pair)
+                       counts, held_pair, combine)
     return out, (xt, ws, pair_w, order, counts, held_pair)
 
 
-def _routed_grid_bwd(rows, top_k, cdt, interpret, res, ct):
+def _routed_grid_bwd(rows, top_k, cdt, interpret, combine, res, ct):
     xt, ws, pair_w, order, counts, held_pair = res
     with jax.named_scope("dispatch"):
         pos = _sorted_position(order, held_pair.size)
@@ -278,7 +296,8 @@ def _routed_grid_bwd(rows, top_k, cdt, interpret, res, ct):
         rows, top_k, cdt, xt, ws, pair_w, order, pos, counts, ct, interpret)
     with jax.named_scope("combine"):
         pos = moe_kernel.aligned(plan, pos)
-        dxt = _sum_pairs(dx_buf, pos, held_pair, top_k).astype(xt.dtype)
+        dxt = _sum_pairs(dx_buf, pos, held_pair, top_k, combine,
+                         interpret).astype(xt.dtype)
         dpair_w = jnp.where(held_pair, jnp.take(dw_buf, pos), 0.0)
     return dxt, dws, dpair_w, None, None, None
 
@@ -325,6 +344,7 @@ class MoE(Op):
         for n in x.shape[:-1]:
             self.tokens *= int(n)
         self.chunk_rows = CHUNK_ROWS
+        self.combine_kernel = False     # set as the step is traced
         self.outputs = [self._make_output(x.shape, x.dtype)]
 
     def param_defs(self) -> Dict[str, ParamDef]:
@@ -406,13 +426,18 @@ class MoE(Op):
         # it, else as the XLA loop: one rule of what can be observed here
         grid = moe_kernel.grid_walk_ok(self.model, xt, ws, order)
         rows = moe_kernel.ROWS if grid else self.chunk_rows
+        # and `combine` as the gather-sum kernel, by the same kind of rule;
+        # the route is recorded as the step is traced (`expert_stats`)
+        self.combine_kernel = combine_kernel.combine_ok(
+            self.model, xt.shape[0], self.top_k, d, cdt)
         if grid:
             routed = _routed_grid(rows, self.top_k, cdt, False, xt, ws,
                                   pair_w.reshape(-1), order, counts,
-                                  key < held)
+                                  key < held, self.combine_kernel)
         else:
             routed = _routed(rows, self.top_k, cdt, self.activation, xt, ws,
-                             pair_w.reshape(-1), order, counts, key < held)
+                             pair_w.reshape(-1), order, counts, key < held,
+                             self.combine_kernel)
         if self.shared_dim:
             with jax.named_scope("shared"):
                 shared = _ffn(cdt, self.activation, xt.astype(cdt),

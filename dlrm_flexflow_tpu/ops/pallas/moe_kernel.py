@@ -363,10 +363,14 @@ def _frame(rows, top_k, cdt, ws, pair_w, order, pos, counts, acts, backward,
     of them): the plan and each sorted row's token. Operands: the pair
     weights in sorted order as (1, 2 rows) lane vectors, a window and its
     neighbour (a trip's rows start anywhere in the first), the activations
-    `acts` as slabs in HBM, the experts' matrices a block an expert.
-    Results: the trip's (rows, D) block of the aligned buffer and, with
-    `backward`, a (1, rows) lane vector a trip and a block an expert as
-    the matrices'; every held expert then has a trip."""
+    `acts` as slabs in HBM, the experts' matrices a block an expert, each
+    (held, a, b) seen as (held a, b): a bitcast of its tiled layout, whose
+    name and type the profiler spells shorter than the parameter's (the
+    backward call's text reached the cut with them as they are: PERF.md
+    7.6). Results: the trip's (rows, D) block of the aligned buffer and,
+    with `backward`, a (1, rows) lane vector a trip and a block an expert
+    as the matrices', (held a, b) too; every held expert then has a
+    trip."""
     assert rows <= order.size - pair_w.size, "a trip reads into the padding"
     with jax.named_scope("dispatch"):
         plan = trip_plan(rows, counts, pair_w.size, every_expert=backward)
@@ -389,13 +393,14 @@ def _frame(rows, top_k, cdt, ws, pair_w, order, pos, counts, acts, backward,
     def expert(plan, j):
         return plan.get("expert", j)
 
-    mats = [pl.BlockSpec((None,) + w.shape[1:], at(expert, 2)) for w in ws]
+    mats = [pl.BlockSpec(w.shape[1:], at(expert, 1)) for w in ws]
+    flat = [w.reshape(-1, w.shape[-1]) for w in ws]
     out_specs = [pl.BlockSpec((rows, d), at(_Plan.block, 1))]
     out_shape = [jax.ShapeDtypeStruct((steps * rows, d), cdt)]
     if backward:
         out_specs += [pl.BlockSpec((None, 1, rows), at(_Plan.block, 2))] + mats
         out_shape += [jax.ShapeDtypeStruct((steps, 1, rows), _F32)] + [
-            jax.ShapeDtypeStruct(w.shape, _F32) for w in ws]
+            jax.ShapeDtypeStruct(w.shape, _F32) for w in flat]
     else:
         # one result, not a tuple of one: what reads a tuple's element reads
         # `%pallas_call.N`, and `tracereduce` files it under `mosaic` too
@@ -416,7 +421,7 @@ def _frame(rows, top_k, cdt, ws, pair_w, order, pos, counts, acts, backward,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_budgets()[0], disable_bounds_checks=True))
-    return frame, (packed, w_sorted, *slabs, *ws), plan
+    return frame, (packed, w_sorted, *slabs, *flat), plan
 
 
 # (jitted: a model's expert layers of one shape then share one trace and
@@ -456,7 +461,8 @@ def experts_bwd(rows, top_k, cdt, xt, ws, pair_w, order, pos, counts, ct,
             dx, dw_row, *dws = pl.pallas_call(
                 functools.partial(_bwd_kernel, len(ws)),
                 name="moe_experts_bwd", **frame)(*operands)
-    return dx, dw_row.reshape(-1), tuple(dws), plan
+    return (dx, dw_row.reshape(-1),
+            tuple(g.reshape(w.shape) for g, w in zip(dws, ws)), plan)
 
 
 def _budgets():

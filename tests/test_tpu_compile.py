@@ -209,6 +209,67 @@ def test_expert_walk_compiles_for_v5e(one_chip, no_compile_cache, route):
                 tracereduce.DETAIL_CHARS, line
 
 
+# characters of the cut the count leaves to the chip's own text: the walk's
+# backward counted 593 in the Qwen3-Next step and was filed under `mosaic`
+# on the v5e, and 599 and was not
+_ROOM = 32
+
+
+def _kernel_calls_within_the_cut(text, prefix):
+    """`perfbench/tracereduce.py` files an op under `mosaic` by the first
+    600 characters after the instruction's name, and the profiler's text
+    spells every operand's type out before its name (PERF.md 7.6): each
+    call named `prefix...` keeps `tpu_custom_call` inside that, its
+    operands' types counted (and the `/*index=5*/` the text puts before
+    every fifth operand). -> the calls' names."""
+    from perfbench import tracereduce
+    made = dict(re.findall(r"^\s*%?(\S+) = (\S+) ", text, re.M))
+    names = []
+    for line in text.splitlines():
+        line = line.strip().removeprefix("ROOT ")
+        if re.match(rf"%{prefix}[.\w]* = ", line):
+            name, detail = tracereduce.split_hlo(line)
+            operands = re.search(r"custom-call\(([^)]*)\)", detail).group(1)
+            typed = sum(len(made[re.sub(r"/\*.*?\*/", "", o).lstrip("%")])
+                        + 1 for o in operands.split(", "))
+            assert detail.find("tpu_custom_call") + typed < \
+                tracereduce.DETAIL_CHARS - _ROOM, line
+            names.append(name.lstrip("%").split(".")[0])
+    return names
+
+
+# (tokens, top-k, hidden, the walk's sorted rows): the language-model
+# cells' expert ops, one pass: Qwen3-Next's (the grid walk's buffer, 352
+# trips of 256 rows), GLM's and LFM2's, Nemotron's (the XLA walk's)
+@pytest.mark.parametrize("tokens,k,d,rows", [
+    (8192, 10, 2048, 352 * 256), (8192, 4, 2048, 8192 * 4 + 512),
+    (8192, 6, 2688, 8192 * 6 + 512)])
+def test_moe_combine_compiles_for_v5e(one_chip, no_compile_cache, tokens, k,
+                                      d, rows):
+    """`combine`'s two kernels at the cells' widths: Mosaic takes the
+    one-DMA fetch of a packed row (one sublane of each of its planes) and
+    the landing buffers; the bf16 rows reach the gather through the pack
+    alone (no relayout of the whole buffer by XLA); both calls file under
+    `mosaic`."""
+    import numpy as np
+    from dlrm_flexflow_tpu.ops.pallas import combine_kernel
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(
+        lambda r, p, h: combine_kernel.gather_sum(r * 2, p, h, k)
+    ).lower(sds((rows, d), jnp.bfloat16), sds((tokens * k,), jnp.int32),
+            sds((tokens * k,), jnp.bool_)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert sorted(_kernel_calls_within_the_cut(text, "moe_combine")) == [
+        "moe_combine", "moe_combine_pack"]
+    relayouts = [int(np.prod([int(n) for n in dims.split(",")]))
+                 for dims in re.findall(
+                     r" = \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", text)]
+    assert all(n < 1 << 20 for n in relayouts), relayouts
+
+
 @pytest.mark.parametrize("resident", [False, True])
 def test_chunked_delta_rule_compiles_for_v5e(one_chip, no_compile_cache,
                                              resident):
@@ -302,10 +363,27 @@ def test_chunked_delta_rule_compiles_for_v5e(one_chip, no_compile_cache,
 # GB: four gated short convolutions all XLA, one attention layer through
 # the flash route at heads of 64, GLM's expert shapes on the XLA walk) and
 # pinned Nemotron's step to what it compiled to before and after that edit.
-LM_STEPS = {"glm_4_7_flash": (13_859_039_744, 24, 26_875),
-            "lfm2_24b_a2b": (9_494_668_800, 4, 16_110),
-            "nemotron_3_nano_30b_a3b": (13_356_620_288, 4, 22_836),
-            "qwen3_next_80b_a3b": (11_429_124_096, 24, 25_767),
+# Then each expert op's `combine` became two Pallas calls a pass where
+# the gate allows it, which it does in all four steps: the pack and the
+# gather-sum, sixteen kernel calls more (twenty in GLM's, whose MTP module
+# has a fifth expert op); the k XLA gathers of T rows a pass and their
+# selects went, 1,480-4,558 instructions. Bytes: Qwen3-Next's 11,429,124,096
+# -> 11,416,656,384, GLM's 13,859,039,744 -> 13,655,703,552, LFM2's
+# 9,494,668,800 -> 9,485,839,872, Nemotron's 13,356,620,288 ->
+# 13,900,067,328 (the heap XLA packs its temporaries into grows 543 MB,
+# the buffers live at that heap's peak 129 MB, in a Mamba layer's
+# backward and none of them the new kernels': PERF.md §6).
+# The walk's kernels then took the experts' matrices as (held a, b)
+# bitcasts, their text kept inside the profiler's cut: 36 bitcasts more in
+# the Qwen3-Next step, the same bytes.
+# Held to the gathers (`.../combine_xla`, the gate as the CPU finds it)
+# the LFM2 step is the parent's to the byte and the instruction, as the
+# Qwen3-Next step is with both gates closed (`.../walk`).
+LM_STEPS = {"glm_4_7_flash": (13_655_703_552, 44, 24_788),
+            "lfm2_24b_a2b": (9_485_839_872, 20, 14_630),
+            "lfm2_24b_a2b/combine_xla": (9_494_668_800, 4, 16_110),
+            "nemotron_3_nano_30b_a3b": (13_900_067_328, 20, 20_308),
+            "qwen3_next_80b_a3b": (11_416_656_384, 40, 21_245),
             "qwen3_next_80b_a3b/walk": (14_105_433_088, 16, 27_372)}
 
 
@@ -320,7 +398,8 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
     import numpy as np
     import dlrm_flexflow_tpu as ff
     from dlrm_flexflow_tpu.ops import attention, embedding
-    from dlrm_flexflow_tpu.ops.pallas import delta_kernel, moe_kernel
+    from dlrm_flexflow_tpu.ops.pallas import (combine_kernel, delta_kernel,
+                                              moe_kernel)
     from dlrm_flexflow_tpu.parallel.mesh import make_mesh
     from perfbench import manifest as mf
     monkeypatch.setattr(
@@ -331,7 +410,8 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
     monkeypatch.setattr(
         delta_kernel, "resident_hand_over_ok",
         lambda model, chunk, dk, dv: delta_kernel.shapes_fit(chunk, dk, dv))
-    name, _, walk = name.partition("/")
+    name, _, held_to = name.partition("/")
+    walk = held_to == "walk"
     if not walk:
         monkeypatch.setattr(
             moe_kernel, "grid_walk_ok",
@@ -339,6 +419,11 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
             and moe_kernel.shapes_fit(*ws[0].shape[1:], len(ws),
                                       entries=order.size,
                                       held=ws[0].shape[0]))
+    if not held_to:
+        monkeypatch.setattr(
+            combine_kernel, "combine_ok",
+            lambda model, tokens, k, d, dtype: combine_kernel.shapes_fit(
+                tokens, k, d, dtype))
     config = mf.load_config(mf.load(), name)
     family = mf.load_family(config["family"])
     mcfg = family.model_config(config, family.held_table_rows(config, 1)[0])
@@ -392,7 +477,8 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
         {k: sds((), jnp.float32) for k in model._msums_keys}, batch,
         sds((), jnp.int32)).compile()
     from dlrm_flexflow_tpu.obs import trace as obstrace
-    most, kernels, instructions = LM_STEPS[name + ("/walk" if walk else "")]
+    most, kernels, instructions = LM_STEPS[
+        name + (f"/{held_to}" if held_to else "")]
     counted = obstrace.program_memory(compiled)["counted"]
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == kernels
@@ -417,6 +503,20 @@ def test_language_model_step_compiles_for_v5e(one_chip, no_compile_cache,
     # and then the only `while`s left are the delta rule's span scans
     walks = len(re.findall(r"%moe_experts_(?:fwd|bwd)[.\d]* = ", text))
     assert walks == (8 if name == "qwen3_next_80b_a3b" and not walk else 0)
+    # each of them, and each `combine` kernel, filed under `mosaic` by the
+    # profiler's text as the step compiles it: its operands' names carry the
+    # step's instruction numbers, longer than the kernel's alone gives them
+    # (the walk's backward reached the cut once in this step and not alone)
+    assert len(_kernel_calls_within_the_cut(text, "moe_experts")) == walks
+    # and `combine` two kernels a pass, forward and backward, where its gate
+    # is open: the pack and the gather-sum; GLM's MTP module has
+    # a fifth expert op
+    combines = [len(re.findall(rf"%{kernel}[.\d]* = ", text))
+                for kernel in ("moe_combine", "moe_combine_pack")]
+    assert combines == 2 * [0 if held_to else
+                            10 if name == "glm_4_7_flash" else 8]
+    assert len(_kernel_calls_within_the_cut(text, "moe_combine")) == sum(
+        combines)
     if walks:
         whiles = [line for line in text.splitlines() if " while(" in line]
         assert len(whiles) == 9 and not any("_moe" in w for w in whiles)
